@@ -34,7 +34,7 @@ Protocol for High Data Contention Database Environments" (IJDMS 2016).
   protocol, jit).
 * ``kernel_*``: Pallas kernel wall time.  On non-TPU backends the rows
   are interpret-mode (correctness-path) timings and labelled as such;
-  a compiled-path row is emitted only when a real accelerator backs the
+  a compiled-path row is emitted only when a TPU backs the
   kernel.
 
 Output: ``name,us_per_call,derived`` CSV per line.
@@ -459,14 +459,14 @@ def kernel_conflict(args):
     body runs op-by-op in Python) — they are NOT device performance and
     the fused kernel is *expected* to read slower there because it also
     emits WW + degrees per grid step (DESIGN.md §3).  A compiled-path
-    row is added only when a real accelerator executes the kernel."""
+    row is added only when a TPU executes the kernel."""
     import jax
     out = _kernel_conflict_us(interpret=True)
     for name, us in out.items():
         _row(f"kernel_conflict_{name}_interpret", us,
              f"pairs={512 * 512} note=interpret-mode-correctness-path"
              "-not-device-perf")
-    if jax.default_backend() in ("tpu", "gpu"):
+    if jax.default_backend() == "tpu":
         out = _kernel_conflict_us(interpret=False)
         for name, us in out.items():
             _row(f"kernel_conflict_{name}_compiled", us,
@@ -1046,10 +1046,11 @@ def telemetry(args):
          f"wrote={trace_path} events={n_events}")
 
     # device-level profiler capture of one warm fleet execution —
-    # optional (profiler availability varies by backend/build), and
     # bounded: a long-horizon fleet run produces a multi-GB host trace
     # (measured ~70 GB RSS at horizon 20k), so only short smokes
-    # capture one
+    # capture one.  Off the TPU the profiler may be missing from the
+    # build and its absence is recorded; on the TPU a failed capture
+    # fails the run
     prof_dir = tempfile.mkdtemp(prefix="telemetry_jaxprof_")
     if horizon > 2_000.0:
         profiler_status = (f"skipped: horizon {horizon:g} too long for "
@@ -1060,7 +1061,9 @@ def telemetry(args):
             jax.profiler.start_trace(prof_dir)
             jax.block_until_ready(fleet_on(MPL_GRID, seeds))
             jax.profiler.stop_trace()
-        except Exception as e:  # profiler missing/unsupported: go on
+        except Exception as e:
+            if jax.default_backend() == "tpu":
+                raise
             profiler_status = f"unavailable: {type(e).__name__}: {e}"
     _row("telemetry_profiler", 0.0,
          f"status={profiler_status.split(':')[0]} dir={prof_dir}")
@@ -1160,6 +1163,8 @@ def main() -> None:
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count="
             f"{args.host_devices}").strip()
+    from repro import compile_cache
+    compile_cache.enable()
     # the default figure path is the single-executable `figs` grid;
     # per-figure benches (fig5..fig16) stay reachable via --only.
     # `engine` / `sweep` / `one_exec` / `telemetry` run full grids and

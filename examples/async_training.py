@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.sched import txstore
 from repro.sched.txstore import TxBatch
 
@@ -33,6 +34,7 @@ def main() -> None:
     ap.add_argument("--pages", type=int, default=32)
     ap.add_argument("--updates", type=int, default=200)
     args = ap.parse_args()
+    compile_cache.enable()
 
     rng = np.random.default_rng(0)
     k, pages, width = args.replicas, args.pages, 8

@@ -17,6 +17,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.core import jaxsim
 from repro.core.types import SimParams
 
@@ -27,6 +28,7 @@ def main() -> None:
     ap.add_argument("--horizon", type=float, default=5_000.0)
     ap.add_argument("--mpl", type=int, default=16)
     args = ap.parse_args()
+    compile_cache.enable()
 
     for wp in (0.2, 0.5):
         p = SimParams(db_size=100, txn_size_mean=8, write_prob=wp,

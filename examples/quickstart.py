@@ -17,6 +17,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
+
+compile_cache.enable()
+
 # --- 1. the paper: protocol comparison under high data contention -------
 from repro.core.pysim import simulate
 from repro.core.types import SimParams

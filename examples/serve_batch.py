@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.models import LM
 from repro.launch import steps as steps_mod
 from repro.obs import metrics as obs_metrics
@@ -36,6 +36,7 @@ def main() -> None:
                     choices=["ppcc", "2pl", "occ"])
     ap.add_argument("--arch", default="qwen3_0p6b")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = configs.get_smoke(args.arch)
     lm = LM(cfg)

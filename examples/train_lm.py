@@ -13,6 +13,7 @@ import sys
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT / "src"))
 
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
@@ -28,4 +29,8 @@ if __name__ == "__main__":
     import os
     env.update({k: v for k, v in os.environ.items()
                 if k not in env})
+    # the child compiles: hand it the checkout's persistent cache
+    # unless the caller placed one
+    from repro.compile_cache import CACHE_DIR
+    env.setdefault("JAX_COMPILATION_CACHE_DIR", str(CACHE_DIR))
     raise SystemExit(subprocess.call(cmd, env=env))

@@ -1300,16 +1300,16 @@ def engine_parts(p: SimParams, protocol: str, max_iters: int = 400_000,
     ``n_slots`` pads the slot axis beyond ``p.mpl`` (the padded-lane
     engine); ``init(seed, mpl=None)`` then takes the number of active
     slots as a runtime value (default ``p.mpl``).  ``megakernel=None``
-    auto-gates the Pallas cohort-step megakernel to real accelerators
-    (on CPU the jnp twin inside ``ppcc.cohort_step_fused`` is both the
-    fast and the correct path; interpret-mode Pallas inside the engine
-    loop would be pure overhead)."""
+    auto-gates the Pallas cohort-step megakernel to the TPU, the one
+    backend it is compiled for (elsewhere the kernel would run in
+    interpret mode inside the loop, so the jnp twin inside
+    ``ppcc.cohort_step_fused`` is both the fast and the correct path)."""
     if step_mode not in ("cohort", "event"):
         raise ValueError(f"unknown step_mode: {step_mode!r}")
     if telemetry and step_mode != "cohort":
         raise ValueError("telemetry requires step_mode='cohort'")
     if megakernel is None:
-        megakernel = jax.default_backend() in ("tpu", "gpu")
+        megakernel = jax.default_backend() == "tpu"
     if cohort_dt is None:
         cohort_dt = default_cohort_dt(p)
     if n_slots is None:

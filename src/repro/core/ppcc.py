@@ -515,8 +515,8 @@ def cohort_step_fused(s: PPCCState, item: jax.Array, is_write: jax.Array,
     ``kernels.ops.megastep_relations(...)`` returns (its trailing
     ``dirty_hit`` is ignored here) — in place of the inline jnp joins;
     both are bit-identical (``tests/test_megastep.py``).  The compiled
-    megakernel path is for real accelerators; on CPU the inline twin is
-    the fast path.
+    megakernel path is for the TPU; elsewhere the inline twin is the
+    fast path.
     """
     n = s.n
     idx = jnp.arange(n, dtype=jnp.int32)

@@ -166,13 +166,12 @@ class Fleet:
 
             runner = jax.vmap(run_one)
             if mesh is not None:
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
                 from ..parallel.sharding import data_axes
                 lane = P(data_axes(mesh))
-                runner = shard_map(
+                runner = jax.shard_map(
                     runner, mesh=mesh, in_specs=(lane, lane, lane),
-                    out_specs=lane, check_rep=False)
+                    out_specs=lane, check_vma=False)
             return runner
 
         runners = {proto: lane_runner(proto) for proto in self.protocols}

@@ -7,8 +7,9 @@ The hot spot is the pairwise conflict matrix
     raw[i, j] = any(read[i] & write[j])      (i reads what j wrote)
 
 (and its transpose for WAR).  This kernel tiles [bi, bj] transaction
-pairs into VMEM and reduces over the word dimension in chunks; the
-bitwise AND + OR-reduce runs on the VPU.
+pairs into VMEM and reduces over the word dimension one word at a
+time against the transposed right-hand side (``uint32[W, N]``, one
+word per sublane row); the bitwise AND + OR-reduce runs on the VPU.
 
 VMEM per step: (bi + bj) x W x 4B + bi x bj x 4B accumulator; with
 bi = bj = 256 and W <= 1024 (32k items) this is ~2.3 MiB.
@@ -66,20 +67,24 @@ def rowslab(read_bits: jax.Array, write_bits: jax.Array,
     return dep_rows & v, ww_rows & v, wat_rows & v, rat_rows & v
 
 
-def _conflict_kernel(a_ref, b_ref, o_ref, *, words: int, chunk: int):
-    acc = jnp.zeros(o_ref.shape, jnp.bool_)
-    for w0 in range(0, words, chunk):
-        w1 = min(w0 + chunk, words)
-        a = a_ref[:, w0:w1]                     # [bi, c] uint32
-        b = b_ref[:, w0:w1]                     # [bj, c] uint32
-        hits = (a[:, None, :] & b[None, :, :]) != 0
-        acc = acc | hits.any(axis=-1)
-    o_ref[...] = acc
+def _overlap(a, bt, words: int):
+    """uint32[bi, W] x uint32[W, bj] -> bool[bi, bj]: any shared bit.
+    A static loop over the words of broadcast AND — the right-hand
+    side arrives transposed, so each word is one sublane row and no
+    3-D intermediate is formed."""
+    acc = jnp.zeros((a.shape[0], bt.shape[1]), jnp.bool_)
+    for w in range(words):
+        acc = acc | ((a[:, w:w + 1] & bt[w:w + 1, :]) != 0)
+    return acc
+
+
+def _conflict_kernel(a_ref, bt_ref, o_ref, *, words: int):
+    o_ref[...] = _overlap(a_ref[...], bt_ref[...], words)
 
 
 def conflict_matrix(read_bits: jax.Array, write_bits: jax.Array, *,
-                    block: int = 256, word_chunk: int = 128,
-                    interpret: bool = False) -> jax.Array:
+                    block: int = 256, interpret: bool = False
+                    ) -> jax.Array:
     """read_bits/write_bits uint32[N, W] -> bool[N, N] where
     out[i, j] = read set of i intersects write set of j."""
     n, w = read_bits.shape
@@ -87,38 +92,30 @@ def conflict_matrix(read_bits: jax.Array, write_bits: jax.Array, *,
     bi = min(block, n)
     assert n % bi == 0, (n, bi)
     grid = (n // bi, n // bi)
-    kernel = functools.partial(_conflict_kernel, words=w, chunk=word_chunk)
+    kernel = functools.partial(_conflict_kernel, words=w)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bi, w), lambda i, j: (i, 0)),
-            pl.BlockSpec((bi, w), lambda i, j: (j, 0)),
+            pl.BlockSpec((w, bi), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bi, bi), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n, n), jnp.bool_),
         interpret=interpret,
-    )(read_bits, write_bits)
+    )(read_bits, write_bits.T)
 
 
 def _conflict_fused_kernel(r_ref, wi_ref, wj_ref, raw_ref, ww_ref,
-                           rdeg_ref, wdeg_ref, *, words: int, chunk: int):
+                           rdeg_ref, wdeg_ref, *, words: int):
     """One pass over the word dimension emits BOTH conflict relations —
     raw[i, j] = any(read[i] & write[j]) and ww[i, j] = any(write[i] &
     write[j]) — plus per-row popcount degrees, accumulated across the j
     grid dimension (same output block revisited; j iterates fastest)."""
     j = pl.program_id(1)
-    raw_acc = jnp.zeros(raw_ref.shape, jnp.bool_)
-    ww_acc = jnp.zeros(ww_ref.shape, jnp.bool_)
-    for w0 in range(0, words, chunk):
-        w1 = min(w0 + chunk, words)
-        r = r_ref[:, w0:w1]                     # [bi, c] uint32
-        wi = wi_ref[:, w0:w1]                   # [bi, c]
-        wj = wj_ref[:, w0:w1]                   # [bj, c]
-        raw_acc = raw_acc | ((r[:, None, :] & wj[None, :, :]) != 0
-                             ).any(axis=-1)
-        ww_acc = ww_acc | ((wi[:, None, :] & wj[None, :, :]) != 0
-                           ).any(axis=-1)
+    wj = wj_ref[...]                            # uint32[W, bj] (write^T)
+    raw_acc = _overlap(r_ref[...], wj, words)
+    ww_acc = _overlap(wi_ref[...], wj, words)
     raw_ref[...] = raw_acc
     ww_ref[...] = ww_acc
 
@@ -132,8 +129,7 @@ def _conflict_fused_kernel(r_ref, wi_ref, wj_ref, raw_ref, ww_ref,
 
 
 def conflict_fused(read_bits: jax.Array, write_bits: jax.Array, *,
-                   block: int = 256, word_chunk: int = 128,
-                   interpret: bool = False):
+                   block: int = 256, interpret: bool = False):
     """Single-launch fusion of ``conflict_matrix(rb, wb)`` and
     ``conflict_matrix(wb, wb)``.
 
@@ -148,15 +144,14 @@ def conflict_fused(read_bits: jax.Array, write_bits: jax.Array, *,
     bi = min(block, n)
     assert n % bi == 0, (n, bi)
     grid = (n // bi, n // bi)
-    kernel = functools.partial(_conflict_fused_kernel, words=w,
-                               chunk=word_chunk)
+    kernel = functools.partial(_conflict_fused_kernel, words=w)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bi, w), lambda i, j: (i, 0)),
             pl.BlockSpec((bi, w), lambda i, j: (i, 0)),
-            pl.BlockSpec((bi, w), lambda i, j: (j, 0)),
+            pl.BlockSpec((w, bi), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((bi, bi), lambda i, j: (i, j)),
@@ -171,30 +166,23 @@ def conflict_fused(read_bits: jax.Array, write_bits: jax.Array, *,
             jax.ShapeDtypeStruct((n,), jnp.int32),
         ],
         interpret=interpret,
-    )(read_bits, write_bits, write_bits)
+    )(read_bits, write_bits, write_bits.T)
 
 
 def _conflict_fused_full_kernel(r_ref, wi_ref, wj_ref, raw_ref, ww_ref,
                                 rdeg_ref, cdeg_ref, wdeg_ref, dr_ref,
-                                dw_ref, *, words: int, chunk: int):
+                                dw_ref, *, words: int):
     """``conflict_fused`` plus the WAR *column* degrees and the two
     diagonals — everything degree-ordered admission consumes, one
     launch.  Row accumulators (rdeg/wdeg/diagonals) are revisited along
     the fastest-varying ``j`` dimension and initialised at ``j == 0``;
     the column accumulator (cdeg) is revisited along ``i`` and
-    initialised at ``i == 0``."""
+    initialised at ``i == 0``.  The diagonals are masked row reductions
+    of the ``i == j`` tile, emitted as int32 0/1."""
     i, j = pl.program_id(0), pl.program_id(1)
-    raw_acc = jnp.zeros(raw_ref.shape, jnp.bool_)
-    ww_acc = jnp.zeros(ww_ref.shape, jnp.bool_)
-    for w0 in range(0, words, chunk):
-        w1 = min(w0 + chunk, words)
-        r = r_ref[:, w0:w1]
-        wi = wi_ref[:, w0:w1]
-        wj = wj_ref[:, w0:w1]
-        raw_acc = raw_acc | ((r[:, None, :] & wj[None, :, :]) != 0
-                             ).any(axis=-1)
-        ww_acc = ww_acc | ((wi[:, None, :] & wj[None, :, :]) != 0
-                           ).any(axis=-1)
+    wj = wj_ref[...]                            # uint32[W, bj] (write^T)
+    raw_acc = _overlap(r_ref[...], wj, words)
+    ww_acc = _overlap(wi_ref[...], wj, words)
     raw_ref[...] = raw_acc
     ww_ref[...] = ww_acc
 
@@ -202,8 +190,8 @@ def _conflict_fused_full_kernel(r_ref, wi_ref, wj_ref, raw_ref, ww_ref,
     def _init_rows():
         rdeg_ref[...] = jnp.zeros(rdeg_ref.shape, jnp.int32)
         wdeg_ref[...] = jnp.zeros(wdeg_ref.shape, jnp.int32)
-        dr_ref[...] = jnp.zeros(dr_ref.shape, jnp.bool_)
-        dw_ref[...] = jnp.zeros(dw_ref.shape, jnp.bool_)
+        dr_ref[...] = jnp.zeros(dr_ref.shape, jnp.int32)
+        dw_ref[...] = jnp.zeros(dw_ref.shape, jnp.int32)
 
     @pl.when(i == 0)
     def _init_cols():
@@ -215,13 +203,15 @@ def _conflict_fused_full_kernel(r_ref, wi_ref, wj_ref, raw_ref, ww_ref,
 
     @pl.when(i == j)
     def _diag():
-        dr_ref[...] = jnp.diagonal(raw_acc)
-        dw_ref[...] = jnp.diagonal(ww_acc)
+        shape = raw_acc.shape
+        eye = jax.lax.broadcasted_iota(jnp.int32, shape, 0) == \
+            jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        dr_ref[...] = jnp.where(raw_acc & eye, 1, 0).max(axis=1)
+        dw_ref[...] = jnp.where(ww_acc & eye, 1, 0).max(axis=1)
 
 
 def conflict_fused_full(read_bits: jax.Array, write_bits: jax.Array, *,
-                        block: int = 256, word_chunk: int = 128,
-                        interpret: bool = False):
+                        block: int = 256, interpret: bool = False):
     """Single launch → (raw, ww, raw_deg, war_deg, ww_deg, diag_raw,
     diag_ww); bit-identical to ``ref.conflict_fused_full_ref``.  The
     extra column-degree and diagonal outputs make degree-ordered
@@ -233,15 +223,14 @@ def conflict_fused_full(read_bits: jax.Array, write_bits: jax.Array, *,
     bi = min(block, n)
     assert n % bi == 0, (n, bi)
     grid = (n // bi, n // bi)
-    kernel = functools.partial(_conflict_fused_full_kernel, words=w,
-                               chunk=word_chunk)
-    return pl.pallas_call(
+    kernel = functools.partial(_conflict_fused_full_kernel, words=w)
+    *out, dr, dw = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bi, w), lambda i, j: (i, 0)),
             pl.BlockSpec((bi, w), lambda i, j: (i, 0)),
-            pl.BlockSpec((bi, w), lambda i, j: (j, 0)),
+            pl.BlockSpec((w, bi), lambda i, j: (0, j)),
         ],
         out_specs=[
             pl.BlockSpec((bi, bi), lambda i, j: (i, j)),
@@ -258,8 +247,9 @@ def conflict_fused_full(read_bits: jax.Array, write_bits: jax.Array, *,
             jax.ShapeDtypeStruct((n,), jnp.int32),
             jax.ShapeDtypeStruct((n,), jnp.int32),
             jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((n,), jnp.bool_),
-            jax.ShapeDtypeStruct((n,), jnp.bool_),
+            jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n,), jnp.int32),
         ],
         interpret=interpret,
-    )(read_bits, write_bits, write_bits)
+    )(read_bits, write_bits, write_bits.T)
+    return (*out, dr != 0, dw != 0)

@@ -7,24 +7,32 @@ the per-op conflict degrees, the write-write join (wait-to-commit
 feasibility), the current-holder hit vector, and the op membership
 tables that feed the verdict phase.  Computed separately these re-read
 the packed ``uint32[n, W]`` set words once per relation; this kernel
-keeps the read/write/dirty words (and the per-slot op metadata)
-*resident in VMEM across the whole grid* — their BlockSpec index maps
-are constant, so at the paper scale (n=160, d=500 → 160x16 words ≈
-10 KiB per array) every phase reuses the same on-chip copy — and tiles
-the ``(n, n)`` pair space, with the per-row accumulators (degree,
-lock-hit, dirty-hit) riding the same grid: degree blocks are revisited
-across the fastest-varying ``j`` dimension and initialised at
-``j == 0``, exactly like ``conflict_fused``.
+keeps the words and the per-slot op metadata resident in VMEM and
+emits every relation from them in one pass.
 
-The compiled path is gated to real accelerators
-(``ops.megastep_relations``); on CPU the kernel runs in interpret mode
+Layout (chosen so Mosaic lowers it on the TPU):
+
+* one program per call (one per lane under ``vmap``) holds every array
+  whole — at the paper scale (n=160 slots, 16 words) the (n, n)
+  intermediates are ~100 KiB each, far inside VMEM; whole-array blocks
+  also sidestep the (8, 128) tiling rule for any ``n``;
+* the words arrive twice, row-major ``[n, W]`` and transposed
+  ``[W, n]`` (one word per sublane row), so every item membership test
+  is a static loop over the ``W`` words of compare-and-select — no
+  in-kernel gather, no dynamic slice;
+* per-slot metadata arrives as int32 columns ``[n, c]`` and rows
+  ``[r, n]`` (no 1-D or bool refs, no select between bools); the party
+  join is a bf16 0/1 matmul with f32 accumulation (exact: sums are at
+  most ``n``);
+* pairwise outputs are int8 and the per-row vectors one int32
+  ``[n, 3]`` block; the wrapper converts them back to bool/int32.
+
+The word axis is exact by the packed zero-pad-bit invariant
+(``core.bitset``).  On CPU the kernel runs in interpret mode
 — the correctness twin that ``tests/test_megastep.py`` holds bit-equal
 to the ``ref.megastep_ref`` oracle and to the jnp single-pass twin
-inside ``ppcc.cohort_step_fused``.  ``n`` and ``d`` need not be
-multiples of the tile/lane width: rows pad with inert slots (inactive,
-not ready, no locks, zero words) that provably contribute to no
-relation, and the word axis is exact by the packed zero-pad-bit
-invariant (``core.bitset``).
+inside ``ppcc.cohort_step_fused``; ``tests/test_tpu_compile.py`` lowers
+it for a v5e chip.
 """
 from __future__ import annotations
 
@@ -34,216 +42,98 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-
-def _megastep_kernel(read_ref, write_ref, dirty_ref, opw_ref, opb_ref,
-                     isw_ref, act_ref, rdy_ref, hl_ref,
-                     dep_ref, ww_ref, wat_ref, rat_ref,
-                     deg_ref, lockhit_ref, dirtyhit_ref, *,
-                     n: int, bi: int, bj: int):
-    i, j = pl.program_id(0), pl.program_id(1)
-    gi = i * bi + jnp.arange(bi)                     # global row slot ids
-    gj = j * bj + jnp.arange(bj)
-
-    # resident packed words + op metadata (full arrays, constant blocks)
-    read_w = read_ref[...]                           # uint32[n, W]
-    write_w = write_ref[...]                         # uint32[n, W]
-    opw = opw_ref[...]                               # int32[n] item word
-    opb = opb_ref[...]                               # uint32[n] item bit
-    isw = isw_ref[...]                               # bool[n]
-    act = act_ref[...]                               # bool[n]
-    rdy = rdy_ref[...]                               # bool[n]
-    hl = hl_ref[...]                                 # bool[n]
-
-    def tile(vec, g0, b):
-        return jax.lax.dynamic_slice_in_dim(vec, g0, b)
-
-    opw_i, opb_i, isw_i = tile(opw, i * bi, bi), tile(opb, i * bi, bi), \
-        tile(isw, i * bi, bi)
-    opw_j, opb_j, isw_j = tile(opw, j * bj, bj), tile(opb, j * bj, bj), \
-        tile(isw, j * bj, bj)
-
-    def memb(words, w_idx, b_idx):
-        """[n, m]: item (w_idx, b_idx)[x] present in words row k."""
-        cols = jnp.take(words, w_idx, axis=1)        # [n, m] uint32
-        return ((cols >> b_idx[None, :]) & 1).astype(bool)
-
-    # op membership tables over ALL slots (phase: conflict/party matrix)
-    w_at_i = memb(write_w, opw_i, opb_i)             # [n, bi]
-    r_at_i = memb(read_w, opw_i, opb_i)
-    w_at_j = memb(write_w, opw_j, opb_j)             # [n, bj]
-    r_at_j = memb(read_w, opw_j, opb_j)
-
-    def party(w_at, r_at, is_w, g):
-        others = jnp.where(is_w[None, :], r_at, w_at)
-        self_k = jnp.arange(n)[:, None] == g[None, :]
-        return (others & act[:, None] & ~self_k) | self_k
-
-    p_i = party(w_at_i, r_at_i, isw_i, gi)           # [n, bi]
-    p_j = party(w_at_j, r_at_j, isw_j, gj)           # [n, bj]
-    join = (p_i.astype(jnp.int32).T @ p_j.astype(jnp.int32)) > 0
-    same_item = (opw_i[:, None] == opw_j[None, :]) & \
-        (opb_i[:, None] == opb_j[None, :])
-    either_w = isw_i[:, None] | isw_j[None, :]
-    eye = gi[:, None] == gj[None, :]
-    dep = (join | (same_item & either_w)) & ~eye
-    dep_ref[...] = dep
-
-    # write-write join straight off the resident words (wc feasibility)
-    wi = jax.lax.dynamic_slice_in_dim(write_w, i * bi, bi)   # [bi, W]
-    wj = jax.lax.dynamic_slice_in_dim(write_w, j * bj, bj)   # [bj, W]
-    ww = ((wi[:, None, :] & wj[None, :, :]) != 0).any(axis=-1) & ~eye
-    ww_ref[...] = ww
-
-    # verdict-phase op tables: {write,read}_set[k=col, item[row]]
-    wat_ref[...] = jax.lax.dynamic_slice_in_dim(w_at_i.T, j * bj, bj,
-                                                axis=1)
-    rat_ref[...] = jax.lax.dynamic_slice_in_dim(r_at_i.T, j * bj, bj,
-                                                axis=1)
-
-    # per-row accumulators ride the j grid dim (init on first visit)
-    @pl.when(j == 0)
-    def _init():
-        deg_ref[...] = jnp.zeros(deg_ref.shape, jnp.int32)
-        lockhit_ref[...] = jnp.zeros(lockhit_ref.shape, jnp.bool_)
-        di = jax.lax.dynamic_slice_in_dim(dirty_ref[...], i * bi, bi)
-        dirtyhit_ref[...] = ((jax.lax.dynamic_slice_in_dim(
-            read_w, i * bi, bi) & di) != 0).any(axis=-1)
-
-    rdy_j = tile(rdy, j * bj, bj)
-    hl_j = tile(hl, j * bj, bj)
-    deg_ref[...] += (dep & rdy_j[None, :]).sum(axis=1).astype(jnp.int32)
-    lockhit_ref[...] |= (ww & hl_j[None, :]).any(axis=1)
+_NT = (((1,), (1,)), ((), ()))          # dot_general: a @ b.T
 
 
-def _rowslab_kernel(read_ref, write_ref, wat_ref, rat_ref, opw_ref,
-                    opb_ref, isw_ref, act_ref, sl_ref, valid_ref,
-                    dep_ref, ww_ref, watr_ref, ratr_ref, *,
-                    n: int, k: int, bj: int):
-    j = pl.program_id(0)
-    gj = j * bj + jnp.arange(bj)
+def _op_tables(c, rt, wt, words: int):
+    """Membership of each op's item in every slot's sets.
 
-    # resident packed words, carried op tables, op metadata
-    read_w = read_ref[...]                           # uint32[n, W]
-    write_w = write_ref[...]                         # uint32[n, W]
-    wat = wat_ref[...]                               # bool[n, n] carried
-    rat = rat_ref[...]
-    opw = opw_ref[...]
-    opb = opb_ref[...]
-    isw = isw_ref[...]
-    act = act_ref[...]
-    sl = sl_ref[...]                                 # int32[k] clamped ids
-    valid = valid_ref[...]                           # bool[k]
-
-    def memb(words, w_idx, b_idx):
-        cols = jnp.take(words, w_idx, axis=1)        # [n, m] uint32
-        return ((cols >> b_idx[None, :]) & 1).astype(bool)
-
-    opw_s = jnp.take(opw, sl)
-    opb_s = jnp.take(opb, sl)
-    isw_s = jnp.take(isw, sl)
-    w_at_s = memb(write_w, opw_s, opb_s)             # [n, k] fresh tables
-    r_at_s = memb(read_w, opw_s, opb_s)
-
-    # party rows of the slab slots, straight from the fresh tables
-    others_s = jnp.where(isw_s[None, :], r_at_s, w_at_s)
-    self_s = jnp.arange(n)[:, None] == sl[None, :]
-    p_s = ((others_s & act[:, None] & ~self_s) | self_s).T   # [k, n]
-
-    # party rows of the j column tile — carried tables with the slab
-    # rows substituted (sel has at most one hit per row: ids unique)
-    sel = (sl[None, :] == gj[:, None]) & valid[None, :]      # [bj, k]
-    hit = sel.any(axis=1)
-    wat_j = jax.lax.dynamic_slice_in_dim(wat, j * bj, bj)    # [bj, n]
-    rat_j = jax.lax.dynamic_slice_in_dim(rat, j * bj, bj)
-    fresh_w = (sel.astype(jnp.int32) @ w_at_s.T.astype(jnp.int32)) > 0
-    fresh_r = (sel.astype(jnp.int32) @ r_at_s.T.astype(jnp.int32)) > 0
-    wat_j = jnp.where(hit[:, None], fresh_w, wat_j)
-    rat_j = jnp.where(hit[:, None], fresh_r, rat_j)
-    isw_j = jax.lax.dynamic_slice_in_dim(isw, j * bj, bj)
-    others_j = jnp.where(isw_j[:, None], rat_j, wat_j)
-    self_j = gj[:, None] == jnp.arange(n)[None, :]
-    p_j = (others_j & act[None, :] & ~self_j) | self_j       # [bj, n]
-
-    join = (p_s.astype(jnp.int32) @ p_j.astype(jnp.int32).T) > 0
-    opw_j = jax.lax.dynamic_slice_in_dim(opw, j * bj, bj)
-    opb_j = jax.lax.dynamic_slice_in_dim(opb, j * bj, bj)
-    same_item = (opw_s[:, None] == opw_j[None, :]) & \
-        (opb_s[:, None] == opb_j[None, :])
-    either_w = isw_s[:, None] | isw_j[None, :]
-    eye_s = sl[:, None] == gj[None, :]
-    v = valid[:, None]
-    dep_ref[...] = (join | (same_item & either_w)) & ~eye_s & v
-
-    ws = jnp.take(write_w, sl, axis=0)                       # [k, W]
-    wj = jax.lax.dynamic_slice_in_dim(write_w, j * bj, bj)   # [bj, W]
-    ww_ref[...] = ((ws[:, None, :] & wj[None, :, :]) != 0
-                   ).any(axis=-1) & ~eye_s & v
-    watr_ref[...] = jax.lax.dynamic_slice_in_dim(
-        w_at_s.T, j * bj, bj, axis=1) & v
-    ratr_ref[...] = jax.lax.dynamic_slice_in_dim(
-        r_at_s.T, j * bj, bj, axis=1) & v
+    ``c``: int32[m, >=2] op columns (item word, item bit); ``rt``/``wt``:
+    uint32[W, n] transposed read/write words.  Returns bool[m, n]
+    (writers_at, readers_at): [i, k] = item_i in {write,read}_set[k]."""
+    m, n = c.shape[0], rt.shape[1]
+    opw, opb = c[:, 0:1], c[:, 1:2].astype(jnp.uint32)
+    w_acc = jnp.zeros((m, n), jnp.uint32)
+    r_acc = jnp.zeros((m, n), jnp.uint32)
+    for w in range(words):
+        hit = opw == w
+        w_acc = jnp.where(hit, wt[w:w + 1, :], w_acc)
+        r_acc = jnp.where(hit, rt[w:w + 1, :], r_acc)
+    return ((w_acc >> opb) & 1) != 0, ((r_acc >> opb) & 1) != 0
 
 
-def rowslab(read_bits: jax.Array, write_bits: jax.Array,
-            writers_at: jax.Array, readers_at: jax.Array,
-            item: jax.Array, is_write: jax.Array, active: jax.Array,
-            slab: jax.Array, valid: jax.Array, *,
-            block: int = 32, interpret: bool = False):
-    """Pallas variant of the (K, n) dirty-row slab kernel (DESIGN.md
-    §3.2), resident-words layout: the packed read/write words and the
-    carried ``writers_at``/``readers_at`` tables stay in VMEM across the
-    column-tile grid while each program emits one (K, bj) tile of the
-    four relation row blocks.  Bit-identical to ``ref.rowslab_ref`` /
-    the ``conflict.rowslab`` jnp twin; n may be any size (inert-row
-    padding, outputs sliced back)."""
-    n, w = read_bits.shape
-    assert write_bits.shape == (n, w)
-    assert writers_at.shape == (n, n) and readers_at.shape == (n, n)
-    k = slab.shape[0]
-    bj = min(block, max(n, 1))
-    pad = (-n) % bj
-    sl = jnp.clip(slab, 0, n - 1).astype(jnp.int32)
-    if pad:
-        zrow = jnp.zeros((pad, w), jnp.uint32)
-        read_bits = jnp.concatenate([read_bits, zrow])
-        write_bits = jnp.concatenate([write_bits, zrow])
-        writers_at = jnp.pad(writers_at, ((0, pad), (0, pad)))
-        readers_at = jnp.pad(readers_at, ((0, pad), (0, pad)))
-        item = jnp.concatenate([item, jnp.zeros(pad, item.dtype)])
-        zflag = jnp.zeros(pad, bool)
-        is_write = jnp.concatenate([is_write, zflag])
-        active = jnp.concatenate([active, zflag])
-    np_ = n + pad
-    grid = (np_ // bj,)
-    opw = (item >> 5).astype(jnp.int32)
-    opb = (item & 31).astype(jnp.uint32)
-    kernel = functools.partial(_rowslab_kernel, n=np_, k=k, bj=bj)
-    full = lambda *shape: pl.BlockSpec(shape, lambda j: (0,) * len(shape))  # noqa: E731
-    dep, ww, wat, rat = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            full(np_, w), full(np_, w),                     # words
-            full(np_, np_), full(np_, np_),                 # carried tables
-            full(np_), full(np_), full(np_), full(np_),     # op meta/flags
-            full(k), full(k),                               # slab
-        ],
-        out_specs=[pl.BlockSpec((k, bj), lambda j: (0, j))
-                   for _ in range(4)],
-        out_shape=[jax.ShapeDtypeStruct((k, np_), jnp.bool_)
-                   for _ in range(4)],
-        interpret=interpret,
-    )(read_bits, write_bits, writers_at, readers_at, opw, opb, is_write,
-      active, sl, valid)
-    if pad:
-        dep, ww, wat, rat = (m[:, :n] for m in (dep, ww, wat, rat))
-    return dep, ww, wat, rat
+def _party(isw_c, wat, rat, act, self_):
+    """[i, k]: slot k is a party of op i (conflicting access to item_i
+    by an active other slot), or k is i itself."""
+    isw = isw_c != 0
+    others = (isw & rat) | (~isw & wat)              # no select on bools
+    return (others & act & ~self_) | self_
+
+
+def _bf16(x):
+    """bool -> bf16 0/1, an exact MXU operand."""
+    return jnp.where(x, 1.0, 0.0).astype(jnp.bfloat16)
+
+
+def _join(a, b):
+    """bool[m, n] x bool[p, n] -> bool[m, p]: rows share a True column."""
+    return jax.lax.dot_general(_bf16(a), _bf16(b), _NT,
+                               preferred_element_type=jnp.float32) > 0
+
+
+def _ww_rows(wb, wt, words: int):
+    """uint32[m, W] x uint32[W, n] -> bool[m, n] write-set overlap."""
+    acc = jnp.zeros((wb.shape[0], wt.shape[1]), jnp.bool_)
+    for w in range(words):
+        acc = acc | ((wb[:, w:w + 1] & wt[w:w + 1, :]) != 0)
+    return acc
+
+
+def _i8(x):
+    return x.astype(jnp.int8)
+
+
+def _megastep_kernel(cols_ref, rows_ref, rt_ref, wt_ref, w_ref, r_ref,
+                     d_ref, dep_ref, ww_ref, wat_ref, rat_ref, vec_ref, *,
+                     words: int):
+    n = dep_ref.shape[0]
+    rows = rows_ref[...]                             # int32[5, n]
+    item_r, isw_r = rows[0:1, :], rows[1:2, :]
+    act = rows[2:3, :] != 0
+    rdy, hl = rows[3:4, :] != 0, rows[4:5, :] != 0
+    rt, wt = rt_ref[...], wt_ref[...]                # uint32[W, n]
+    c = cols_ref[...]                                # int32[n, 4]
+
+    eye = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) == \
+        jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    wat, rat = _op_tables(c, rt, wt, words)
+    party = _party(c[:, 2:3], wat, rat, act, eye)
+    same_item = c[:, 3:4] == item_r
+    either_w = (c[:, 2:3] != 0) | (isw_r != 0)
+    dep = (_join(party, party) | (same_item & either_w)) & ~eye
+    ww = _ww_rows(w_ref[...], wt, words) & ~eye
+
+    dep_ref[...] = _i8(dep)
+    ww_ref[...] = _i8(ww)
+    wat_ref[...] = _i8(wat)
+    rat_ref[...] = _i8(rat)
+    deg = jnp.where(dep & rdy, 1, 0).sum(axis=1, keepdims=True)
+    lockhit = jnp.where(ww & hl, 1, 0).max(axis=1, keepdims=True)
+    dhit = jnp.where((r_ref[...] & d_ref[...]) != 0, 1, 0).max(
+        axis=1, keepdims=True)
+    vec_ref[:, 0:1] = deg.astype(jnp.int32)
+    vec_ref[:, 1:2] = lockhit.astype(jnp.int32)
+    vec_ref[:, 2:3] = dhit.astype(jnp.int32)
+
+
+def _i32(*vs):
+    return [v.astype(jnp.int32) for v in vs]
 
 
 def megastep(read_bits: jax.Array, write_bits: jax.Array,
              dirty_bits: jax.Array, item: jax.Array, is_write: jax.Array,
              active: jax.Array, ready: jax.Array, haslocks: jax.Array, *,
-             block: int = 32, interpret: bool = False):
+             interpret: bool = False):
     """One launch → every relation of a fused cohort step.
 
     Inputs: packed ``uint32[n, W]`` read/write/dirty words, per-slot op
@@ -259,62 +149,98 @@ def megastep(read_bits: jax.Array, write_bits: jax.Array,
         lockhit   bool[n]     (ww & haslocks).any(axis=1)
         dirty_hit bool[n]     read row intersects dirty row
 
-    bit-for-bit equal to ``ref.megastep_ref``.  ``n`` may be any size:
-    the slot axis pads to the tile width with inert slots and outputs
-    are sliced back.
+    bit-for-bit equal to ``ref.megastep_ref``, for any ``n``.
     """
     n, w = read_bits.shape
     assert write_bits.shape == (n, w) and dirty_bits.shape == (n, w)
-    bi = min(block, max(n, 1))
-    pad = (-n) % bi
-    if pad:
-        zrow = jnp.zeros((pad, w), jnp.uint32)
-        read_bits = jnp.concatenate([read_bits, zrow])
-        write_bits = jnp.concatenate([write_bits, zrow])
-        dirty_bits = jnp.concatenate([dirty_bits, zrow])
-        item = jnp.concatenate([item, jnp.zeros(pad, item.dtype)])
-        zflag = jnp.zeros(pad, bool)
-        is_write = jnp.concatenate([is_write, zflag])
-        active = jnp.concatenate([active, zflag])
-        ready = jnp.concatenate([ready, zflag])
-        haslocks = jnp.concatenate([haslocks, zflag])
-    np_ = n + pad
-    grid = (np_ // bi, np_ // bi)
-    opw = (item >> 5).astype(jnp.int32)
-    opb = (item & 31).astype(jnp.uint32)
-    kernel = functools.partial(_megastep_kernel, n=np_, bi=bi, bj=bi)
-    full = lambda *shape: pl.BlockSpec(shape, lambda i, j: (0,) * len(shape))  # noqa: E731
-    dep, ww, wat, rat, deg, lockhit, dirty_hit = pl.pallas_call(
+    item, is_write, active, ready, haslocks = _i32(
+        item, is_write, active, ready, haslocks)
+    cols = jnp.stack([item >> 5, item & 31, is_write, item], axis=1)
+    rows = jnp.stack([item, is_write, active, ready, haslocks])
+    kernel = functools.partial(_megastep_kernel, words=w)
+    sq = jax.ShapeDtypeStruct((n, n), jnp.int8)
+    dep, ww, wat, rat, vec = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            full(np_, w), full(np_, w), full(np_, w),           # words
-            full(np_), full(np_),                               # opw/opb
-            full(np_), full(np_), full(np_), full(np_),         # flags
-        ],
-        out_specs=[
-            pl.BlockSpec((bi, bi), lambda i, j: (i, j)),
-            pl.BlockSpec((bi, bi), lambda i, j: (i, j)),
-            pl.BlockSpec((bi, bi), lambda i, j: (i, j)),
-            pl.BlockSpec((bi, bi), lambda i, j: (i, j)),
-            pl.BlockSpec((bi,), lambda i, j: (i,)),
-            pl.BlockSpec((bi,), lambda i, j: (i,)),
-            pl.BlockSpec((bi,), lambda i, j: (i,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((np_, np_), jnp.bool_),
-            jax.ShapeDtypeStruct((np_, np_), jnp.bool_),
-            jax.ShapeDtypeStruct((np_, np_), jnp.bool_),
-            jax.ShapeDtypeStruct((np_, np_), jnp.bool_),
-            jax.ShapeDtypeStruct((np_,), jnp.int32),
-            jax.ShapeDtypeStruct((np_,), jnp.bool_),
-            jax.ShapeDtypeStruct((np_,), jnp.bool_),
-        ],
+        out_shape=[sq] * 4 + [jax.ShapeDtypeStruct((n, 3), jnp.int32)],
         interpret=interpret,
-    )(read_bits, write_bits, dirty_bits, opw, opb, is_write, active,
-      ready, haslocks)
-    if pad:
-        dep, ww, wat, rat = (m[:n, :n] for m in (dep, ww, wat, rat))
-        deg, lockhit, dirty_hit = (v[:n] for v in (deg, lockhit,
-                                                   dirty_hit))
-    return dep, ww, wat, rat, deg, lockhit, dirty_hit
+    )(cols, rows, read_bits.T, write_bits.T, write_bits, read_bits,
+      dirty_bits)
+    dep, ww, wat, rat = (m != 0 for m in (dep, ww, wat, rat))
+    return dep, ww, wat, rat, vec[:, 0], vec[:, 1] != 0, vec[:, 2] != 0
+
+
+def _rowslab_kernel(cs_ref, ca_ref, rows_ref, slr_ref, rt_ref, wt_ref,
+                    ws_ref, wat_ref, rat_ref,
+                    dep_ref, ww_ref, watr_ref, ratr_ref, *, words: int):
+    k, n = dep_ref.shape
+    rows = rows_ref[...]                             # int32[3, n]
+    item_r, isw_r = rows[0:1, :], rows[1:2, :]
+    act = rows[2:3, :] != 0
+    rt, wt = rt_ref[...], wt_ref[...]                # uint32[W, n]
+    cs = cs_ref[...]                                 # int32[k, 6] slab
+    sl_c, v = cs[:, 4:5], cs[:, 5:6] != 0
+
+    # fresh op tables and party rows of the slab slots
+    wat_s, rat_s = _op_tables(cs, rt, wt, words)     # [k, n]
+    self_s = sl_c == jax.lax.broadcasted_iota(jnp.int32, (k, n), 1)
+    p_s = _party(cs[:, 2:3], wat_s, rat_s, act, self_s)
+
+    # every slot's party row — carried tables with the slab rows
+    # substituted through a one-hot matmul (ids are unique, so each
+    # row has at most one hit; invalid entries carry id -1)
+    sel = jax.lax.broadcasted_iota(jnp.int32, (n, k), 0) == slr_ref[...]
+    hit = jnp.where(sel, 1, 0).max(axis=1, keepdims=True) != 0
+
+    def substitute(carried, fresh):
+        moved = jnp.dot(_bf16(sel), _bf16(fresh),
+                        preferred_element_type=jnp.float32) > 0
+        return (hit & moved) | (~hit & (carried.astype(jnp.int32) != 0))
+
+    wat_a = substitute(wat_ref[...], wat_s)          # [n, n]
+    rat_a = substitute(rat_ref[...], rat_s)
+    eye = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) == \
+        jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    p_a = _party(ca_ref[...], wat_a, rat_a, act, eye)
+
+    same_item = cs[:, 3:4] == item_r
+    either_w = (cs[:, 2:3] != 0) | (isw_r != 0)
+    dep_ref[...] = _i8((_join(p_s, p_a) | (same_item & either_w))
+                       & ~self_s & v)
+    ww_ref[...] = _i8(_ww_rows(ws_ref[...], wt, words) & ~self_s & v)
+    watr_ref[...] = _i8(wat_s & v)
+    ratr_ref[...] = _i8(rat_s & v)
+
+
+def rowslab(read_bits: jax.Array, write_bits: jax.Array,
+            writers_at: jax.Array, readers_at: jax.Array,
+            item: jax.Array, is_write: jax.Array, active: jax.Array,
+            slab: jax.Array, valid: jax.Array, *,
+            interpret: bool = False):
+    """Pallas variant of the (K, n) dirty-row slab kernel (DESIGN.md
+    §3.2): one program holds the packed words and the carried
+    ``writers_at``/``readers_at`` tables in VMEM and emits the four
+    (K, n) relation row blocks.  The K-entry slab gathers (op metadata
+    and write words of the slab slots) happen outside the kernel.
+    Bit-identical to ``ref.rowslab_ref`` / the ``conflict.rowslab`` jnp
+    twin."""
+    n, w = read_bits.shape
+    assert write_bits.shape == (n, w)
+    assert writers_at.shape == (n, n) and readers_at.shape == (n, n)
+    k = slab.shape[0]
+    sl = jnp.clip(slab, 0, n - 1).astype(jnp.int32)
+    item, is_write, active, valid = _i32(item, is_write, active, valid)
+    it_s = item[sl]
+    cs = jnp.stack([it_s >> 5, it_s & 31, is_write[sl], it_s, sl, valid],
+                   axis=1)
+    ca = is_write[:, None]
+    rows = jnp.stack([item, is_write, active])
+    slr = jnp.where(valid != 0, sl, -1)[None, :]
+    kernel = functools.partial(_rowslab_kernel, words=w)
+    out = jax.ShapeDtypeStruct((k, n), jnp.int8)
+    res = pl.pallas_call(
+        kernel,
+        out_shape=[out] * 4,
+        interpret=interpret,
+    )(cs, ca, rows, slr, read_bits.T, write_bits.T, write_bits[sl],
+      _i8(writers_at), _i8(readers_at))
+    return tuple(m != 0 for m in res)

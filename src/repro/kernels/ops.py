@@ -58,27 +58,26 @@ def conflict_fused_full(read_bits, write_bits, *, block: int = 256):
         interpret=_interpret_default())
 
 
-@functools.partial(jax.jit, static_argnames=("block",))
+@jax.jit
 def megastep_relations(read_bits, write_bits, dirty_bits, item, is_write,
-                       active, ready, haslocks, *, block: int = 32):
+                       active, ready, haslocks):
     """Cohort-step megakernel: one launch -> (dep, ww, writers_at,
     readers_at, deg, lockhit, dirty_hit); see kernels.megastep.
-    Compiled on real accelerators, interpret mode on CPU."""
+    Compiled on the TPU, interpret mode elsewhere."""
     return _megastep.megastep(
         read_bits, write_bits, dirty_bits, item, is_write, active, ready,
-        haslocks, block=block, interpret=_interpret_default())
+        haslocks, interpret=_interpret_default())
 
 
-@functools.partial(jax.jit, static_argnames=("block",))
+@jax.jit
 def rowslab_relations(read_bits, write_bits, writers_at, readers_at,
-                      item, is_write, active, slab, valid, *,
-                      block: int = 32):
+                      item, is_write, active, slab, valid):
     """Dirty-row slab kernel: one launch -> (dep_rows, ww_rows,
     wat_rows, rat_rows), each bool[K, n]; see kernels.megastep.rowslab.
-    Compiled on real accelerators, interpret mode on CPU."""
+    Compiled on the TPU, interpret mode elsewhere."""
     return _megastep.rowslab(
         read_bits, write_bits, writers_at, readers_at, item, is_write,
-        active, slab, valid, block=block, interpret=_interpret_default())
+        active, slab, valid, interpret=_interpret_default())
 
 
 # the protocol-wide packer (repro.core.bitset.pack), jitted; conflict

@@ -32,29 +32,13 @@ MODEL_AXIS = "model"
 
 
 def get_abstract_mesh():
-    """Version-compat shim for ``jax.sharding.get_abstract_mesh``.
-
-    jax >= 0.5 exposes the ambient (context) mesh as an ``AbstractMesh``
-    via ``jax.sharding.get_abstract_mesh``; on 0.4.x the same information
-    lives in the thread-local physical mesh set by ``with mesh:``.
-    Returns an object with ``axis_names`` / ``axis_sizes`` (an
-    ``AbstractMesh`` when available, else the physical ``Mesh``), or
-    ``None`` when no mesh is ambient.
-    """
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        am = get()
-        if am is None or not getattr(am, "axis_names", ()):
-            return None
-        return am
-    try:
-        from jax._src.mesh import thread_resources
-        pm = thread_resources.env.physical_mesh
-    except Exception:           # pragma: no cover - internal API moved
+    """The ambient (context) mesh as an ``AbstractMesh``
+    (``jax.sharding.get_abstract_mesh``), or ``None`` when no mesh is
+    ambient."""
+    am = jax.sharding.get_abstract_mesh()
+    if am is None or not am.axis_names:
         return None
-    if pm is None or pm.empty:
-        return None
-    return getattr(pm, "abstract_mesh", pm)
+    return am
 
 
 def data_axes(mesh: Mesh) -> Tuple[str, ...]:
@@ -86,12 +70,8 @@ def init_distributed(coordinator_address: Optional[str] = None,
     ``init_distributed("localhost:<port>", num_processes=1,
     process_id=0)``.  Returns True when this call performed the init.
     """
-    try:
-        from jax._src import distributed as _dist
-        if getattr(_dist.global_state, "client", None) is not None:
-            return False
-    except Exception:           # pragma: no cover - internal API moved
-        pass
+    if jax.distributed.is_initialized():
+        return False
     jax.distributed.initialize(coordinator_address=coordinator_address,
                                num_processes=num_processes,
                                process_id=process_id, **kw)

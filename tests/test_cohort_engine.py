@@ -187,16 +187,18 @@ GRID = SimParams(db_size=100, txn_size_mean=8, write_prob=0.2, mpl=16,
 
 @pytest.mark.parametrize("protocol", ["ppcc", "2pl", "occ"])
 def test_cohort_commits_aborts_match_event_engine(protocol):
-    """Same model, different batching/RNG: commit and abort counts of
-    the cohort engine must track the one-event engine."""
-    ev = jaxsim.simulate(GRID, protocol, step_mode="event")
-    co = jaxsim.simulate(GRID, protocol, step_mode="cohort")
-    assert co.commits > 0
-    assert 0.7 * ev.commits <= co.commits <= 1.4 * ev.commits, \
-        (co.commits, ev.commits)
+    """Same model, different batching/RNG: mean commit and abort counts
+    of the cohort engine over a few seeds must track the one-event
+    engine's (a single seed's draw swings 2PL by more than the band)."""
+    seeds = [0, 1, 2, 3]
+    ev = jaxsim.simulate_sweep(GRID, protocol, seeds, step_mode="event")
+    co = jaxsim.simulate_sweep(GRID, protocol, seeds, step_mode="cohort")
+    ev_c, co_c = (float(np.mean(r["commits"])) for r in (ev, co))
+    ev_a, co_a = (float(np.mean(r["aborts"])) for r in (ev, co))
+    assert co_c > 0
+    assert 0.7 * ev_c <= co_c <= 1.4 * ev_c, (co_c, ev_c)
     # aborts are rarer; allow a wider band plus slack for tiny counts
-    assert abs(co.aborts - ev.aborts) <= max(10, 0.8 * ev.aborts), \
-        (co.aborts, ev.aborts)
+    assert abs(co_a - ev_a) <= max(10, 0.8 * ev_a), (co_a, ev_a)
 
 
 @pytest.mark.parametrize("protocol", ["ppcc", "2pl", "occ"])

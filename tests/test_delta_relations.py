@@ -58,7 +58,7 @@ def test_rowslab_trio_bit_identical(n, d, k):
             item, is_w, s.active, slab, valid)
     want = ref.rowslab_ref(*args)
     twin = KC.rowslab(*args)
-    pallas = MS.rowslab(*args, block=16, interpret=True)
+    pallas = MS.rowslab(*args, interpret=True)
     names = ("dep_rows", "ww_rows", "wat_rows", "rat_rows")
     for w_, t_, p_, name in zip(want, twin, pallas, names):
         np.testing.assert_array_equal(np.asarray(t_), np.asarray(w_),

@@ -35,27 +35,26 @@ def _random_step_inputs(seed, n, d):
     return s, item, is_w, ready, wc_mask, dirty
 
 
-# n and d deliberately NOT multiples of the tile width / lane width:
-# the kernel pads the slot axis with inert rows and relies on the
-# packed zero-pad-bit invariant along the word axis.
-EDGE_SHAPES = [(12, 30, 8), (33, 100, 32), (7, 31, 32), (40, 64, 16),
-               (160, 500, 32)]
+# n and d deliberately NOT multiples of the (8, 128) tile or of the
+# 32-bit word: the kernel holds whole arrays and relies on the packed
+# zero-pad-bit invariant along the word axis.
+EDGE_SHAPES = [(12, 30), (33, 100), (7, 31), (40, 64), (160, 500)]
 
 
-@pytest.mark.parametrize("n,d,block", EDGE_SHAPES)
-def test_megastep_matches_oracle_at_tile_edges(n, d, block):
+@pytest.mark.parametrize("n,d", EDGE_SHAPES)
+def test_megastep_matches_oracle_at_tile_edges(n, d):
     s, item, is_w, ready, wc_mask, dirty = _random_step_inputs(
         n * 7 + d, n, d)
     args = (s.read_set, s.write_set, dirty, item, is_w, s.active, ready,
             s.haslocks)
-    got = MS.megastep(*args, block=block, interpret=True)
+    got = MS.megastep(*args, interpret=True)
     want = ref.megastep_ref(*args)
     names = ("dep", "ww", "writers_at", "readers_at", "deg", "lockhit",
              "dirty_hit")
     for g, w, name in zip(got, want, names):
         np.testing.assert_array_equal(
             np.asarray(g), np.asarray(w),
-            err_msg=f"{name} n={n} d={d} block={block}")
+            err_msg=f"{name} n={n} d={d}")
 
 
 @pytest.mark.parametrize("seed", range(4))
@@ -66,8 +65,7 @@ def test_fused_step_with_megakernel_relations_equals_jnp_twin(seed, order):
     n, d = 24, 70
     s, item, is_w, ready, wc_mask, dirty = _random_step_inputs(seed, n, d)
     rel = MS.megastep(s.read_set, s.write_set, dirty, item, is_w,
-                      s.active, ready, s.haslocks, block=16,
-                      interpret=True)[:6]
+                      s.active, ready, s.haslocks, interpret=True)[:6]
     a = ppcc.cohort_step_fused(s, item, is_w, ready, wc_mask, order=order)
     b = ppcc.cohort_step_fused(s, item, is_w, ready, wc_mask, order=order,
                                relations=rel)

@@ -12,9 +12,15 @@ from repro.launch import specs as specs_mod
 from repro.models import LM
 from repro.parallel import sharding as shd
 
-# jax 0.4.37 AbstractMesh takes ((name, size), ...) pairs
-MESH1 = AbstractMesh((("data", 16), ("model", 16)))
-MESH2 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+# (axis name, size) layouts of the production meshes; the AbstractMesh
+# objects are built inside each test, never at import
+MESHES = {"pod1": (("data", 16), ("model", 16)),
+          "pod2": (("pod", 2), ("data", 16), ("model", 16))}
+
+
+def abstract_mesh(name):
+    names, sizes = zip(*MESHES[name])
+    return AbstractMesh(sizes, names)
 
 
 def axis_size(mesh, axes):
@@ -39,9 +45,10 @@ def check_divisible(shapes, specs, mesh, where):
                                      is_leaf_with_path=None)
 
 
-@pytest.mark.parametrize("mesh", [MESH1, MESH2], ids=["pod1", "pod2"])
+@pytest.mark.parametrize("mesh", ["pod1", "pod2"])
 @pytest.mark.parametrize("arch", configs.ARCH_NAMES)
 def test_param_specs_divisible(arch, mesh):
+    mesh = abstract_mesh(mesh)
     cfg = configs.get(arch)
     lm = LM(cfg)
     shapes = jax.eval_shape(lm.init, jax.random.PRNGKey(0))
@@ -56,9 +63,10 @@ def test_param_specs_divisible(arch, mesh):
                     (arch, leaf.shape, spec)
 
 
-@pytest.mark.parametrize("mesh", [MESH1, MESH2], ids=["pod1", "pod2"])
+@pytest.mark.parametrize("mesh", ["pod1", "pod2"])
 @pytest.mark.parametrize("arch", configs.ARCH_NAMES)
 def test_cell_specs_divisible(arch, mesh):
+    mesh = abstract_mesh(mesh)
     cfg = configs.get(arch)
     for shape_name in cfg.shapes:
         args, in_specs = specs_mod.cell_specs(cfg, shape_name, mesh)
@@ -78,7 +86,7 @@ def test_tp_weights_actually_sharded():
     cfg = configs.get("yi_34b")
     lm = LM(cfg)
     shapes = jax.eval_shape(lm.init, jax.random.PRNGKey(0))
-    specs = shd.param_specs(cfg, shapes, MESH1)
+    specs = shd.param_specs(cfg, shapes, abstract_mesh("pod1"))
     flat = jax.tree_util.tree_leaves_with_path(shapes)
     specs_flat = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
     n_replicated_big = 0
