@@ -971,9 +971,7 @@ def telemetry(args):
     grid-aggregated percentile/cause summaries, and the compile stats
     land in ``BENCH_sweep.json["telemetry"]``; one mid-grid lane's ring
     buffer per protocol is exported as Perfetto/chrome-trace JSON to
-    ``--trace-out``; a ``jax.profiler`` device trace of one warm fleet
-    execution is captured when the profiler is available."""
-    import tempfile
+    ``--trace-out``."""
     import jax
     from repro.core import sweep as fleet_sweep
     from repro.obs import metrics as obs_metrics
@@ -1045,29 +1043,6 @@ def telemetry(args):
     _row("telemetry_trace_json", 0.0,
          f"wrote={trace_path} events={n_events}")
 
-    # device-level profiler capture of one warm fleet execution —
-    # bounded: a long-horizon fleet run produces a multi-GB host trace
-    # (measured ~70 GB RSS at horizon 20k), so only short smokes
-    # capture one.  Off the TPU the profiler may be missing from the
-    # build and its absence is recorded; on the TPU a failed capture
-    # fails the run
-    prof_dir = tempfile.mkdtemp(prefix="telemetry_jaxprof_")
-    if horizon > 2_000.0:
-        profiler_status = (f"skipped: horizon {horizon:g} too long for "
-                           "a bounded device trace (cap 2000)")
-    else:
-        profiler_status = "ok"
-        try:
-            jax.profiler.start_trace(prof_dir)
-            jax.block_until_ready(fleet_on(MPL_GRID, seeds))
-            jax.profiler.stop_trace()
-        except Exception as e:
-            if jax.default_backend() == "tpu":
-                raise
-            profiler_status = f"unavailable: {type(e).__name__}: {e}"
-    _row("telemetry_profiler", 0.0,
-         f"status={profiler_status.split(':')[0]} dir={prof_dir}")
-
     record = {
         "what": "fig7-grid fleet wall time with the obs layer off vs on "
                 "(EngCfg.telemetry + trace_every=8 ring buffer); "
@@ -1093,7 +1068,6 @@ def telemetry(args):
             on_cold_s / max(off_cold_s, 1e-9) - 1.0, 4),
         "summary": summaries,
         "perfetto_trace": {"path": str(trace_path), "events": n_events},
-        "profiler": {"status": profiler_status, "dir": prof_dir},
     }
     path = Path(args.sweep_json_out)
     _merge_json(path, {"telemetry": record})

@@ -816,293 +816,308 @@ def _delta_update(cfg: EngCfg, s: EngState, ps5, cur_item, cur_w,
 
 
 def _cohort_body(cfg: EngCfg, s: EngState) -> EngState:
-    n = cfg.n
-    idx = jnp.arange(n, dtype=jnp.int32)
-    t0 = s.next_time.min()
-    ready = (s.next_time <= t0 + cfg.cohort_dt) & (s.next_time < 0.5 * INF)
-    te = jnp.where(ready, s.next_time, t0)   # per-slot event time
-    s = s._replace(now=t0, iters=s.iters + 1)
+    """One cohort step.  Each phase runs under a ``jax.named_scope``
+    (``cohort.classify``, ``.relations``, ``.step``, ``.leave_begin``,
+    ``.refill``, ``.reserve``, ``.transitions``); the names reach only
+    the compiled program's ``op_name`` metadata, through which a device
+    trace is attributed to them (``bench/scopes.py``)."""
+    with jax.named_scope("cohort.classify"):
+        n = cfg.n
+        idx = jnp.arange(n, dtype=jnp.int32)
+        t0 = s.next_time.min()
+        ready = (s.next_time <= t0 + cfg.cohort_dt) & (s.next_time < 0.5 * INF)
+        te = jnp.where(ready, s.next_time, t0)   # per-slot event time
+        s = s._replace(now=t0, iters=s.iters + 1)
 
-    # per-iteration randomness (vector draws; streams differ from the
-    # one-event engine — parity is statistical, as with the oracle)
-    key, kc, kd, kr, kt = jax.random.split(s.key, 5)
-    dur_cpu = jax.random.uniform(kc, (n,), minval=cfg.cpu_mean
-                                 - cfg.cpu_spread,
-                                 maxval=cfg.cpu_mean + cfg.cpu_spread)
-    dur_io = jax.random.uniform(kd, (n,), minval=cfg.io_mean
-                                - cfg.io_spread,
-                                maxval=cfg.io_mean + cfg.io_spread)
-    delay = jax.random.uniform(kr, (n,), minval=0.5 * cfg.restart_mean,
-                               maxval=1.5 * cfg.restart_mean)
-    s = s._replace(key=key)
+        # per-iteration randomness (vector draws; streams differ from the
+        # one-event engine — parity is statistical, as with the oracle)
+        key, kc, kd, kr, kt = jax.random.split(s.key, 5)
+        dur_cpu = jax.random.uniform(kc, (n,), minval=cfg.cpu_mean
+                                     - cfg.cpu_spread,
+                                     maxval=cfg.cpu_mean + cfg.cpu_spread)
+        dur_io = jax.random.uniform(kd, (n,), minval=cfg.io_mean
+                                    - cfg.io_spread,
+                                    maxval=cfg.io_mean + cfg.io_spread)
+        delay = jax.random.uniform(kr, (n,), minval=0.5 * cfg.restart_mean,
+                                   maxval=1.5 * cfg.restart_mean)
+        s = s._replace(key=key)
 
-    # ---------------- classification ----------------
-    kind = s.next_kind
-    phase = s.phase
-    n_ops = (s.kinds >= 0).sum(axis=1)
-    done_reading = s.op_idx >= n_ops
-    in_wc = (phase == PH_WC_LOCK) | (phase == PH_WC_PREC)
-    still_wait = (phase == PH_BLOCKED) | (phase == PH_WC_LOCK)
+        # ---------------- classification ----------------
+        kind = s.next_kind
+        phase = s.phase
+        n_ops = (s.kinds >= 0).sum(axis=1)
+        done_reading = s.op_idx >= n_ops
+        in_wc = (phase == PH_WC_LOCK) | (phase == PH_WC_PREC)
+        still_wait = (phase == PH_BLOCKED) | (phase == PH_WC_LOCK)
 
-    is_att = ready & (kind == EV_ATTEMPT)
-    is_disk = ready & (kind == EV_DISK_DONE)
-    is_fl = ready & (kind == EV_FLUSH_DONE)
-    is_to = ready & (kind == EV_TIMEOUT)
-    is_rs = ready & (kind == EV_RESTART)
+        is_att = ready & (kind == EV_ATTEMPT)
+        is_disk = ready & (kind == EV_DISK_DONE)
+        is_fl = ready & (kind == EV_FLUSH_DONE)
+        is_to = ready & (kind == EV_TIMEOUT)
+        is_rs = ready & (kind == EV_RESTART)
 
-    to_expired = is_to & still_wait & (s.deadline <= te)
-    att = is_att | (is_to & ~(still_wait & (s.deadline <= te)))
-    wc_m = att & (done_reading | in_wc)
-    read_m = att & ~(done_reading | in_wc)
+        to_expired = is_to & still_wait & (s.deadline <= te)
+        att = is_att | (is_to & ~(still_wait & (s.deadline <= te)))
+        wc_m = att & (done_reading | in_wc)
+        read_m = att & ~(done_reading | in_wc)
 
-    # ---------------- read-phase + wait-to-commit cohorts --------------
-    op_i = jnp.minimum(s.op_idx, cfg.max_ops - 1)
-    cur_item = s.items[idx, op_i]
-    cur_w = s.kinds[idx, op_i] == jnp.int8(1)
+        # ---------------- read-phase + wait-to-commit cohorts --------------
+        op_i = jnp.minimum(s.op_idx, cfg.max_ops - 1)
+        cur_item = s.items[idx, op_i]
+        cur_w = s.kinds[idx, op_i] == jnp.int8(1)
+    rel = None
     if cfg.protocol == "ppcc" and cfg.fused:
-        # one fused pass over the packed words: conflict/party matrix →
-        # ordered selection → op verdicts + apply → lock winners →
-        # commit test.  read_m and wc_m are disjoint (a slot is in one
-        # phase), which is what licenses the fused step's pre-state
-        # write-write join (see cohort_step_fused).  Bit-identical to
-        # the multipass chain below under order="index".
-        rel = None
-        if cfg.delta:
-            # the carried tables already equal this iteration's full
-            # recompute (the end-of-body delta pass maintains them for
-            # the NEXT cursor) — only the cheap O(n·w) reductions run
-            rel = P.relations_inputs(s.rel, read_m, s.pstate.haslocks)
-        elif cfg.megakernel:
-            from ..kernels import ops as kops
-            rel = kops.megastep_relations(
-                s.pstate.read_set, s.pstate.write_set, s.dirty, cur_item,
-                cur_w, s.pstate.active, read_m, s.pstate.haslocks)
-        fs = P.cohort_step_fused(s.pstate, cur_item, cur_w, read_m, wc_m,
-                                 order=cfg.order, relations=rel)
-        ps1 = ps2 = fs.state
-        verdict, sel, reason = fs.verdict, fs.selected, fs.reason
-        degree = fs.degree
-        flush_m = wc_m & fs.won & fs.can_commit
-        wait_prec_m = wc_m & fs.won & ~fs.can_commit
-        wait_lock_m = wc_m & ~fs.won
-        wc_abort = jnp.zeros(n, bool)
-    else:
-        ps1, verdict, sel, reason = _try_ops_cohort(cfg, s.pstate,
-                                                    cur_item, cur_w,
-                                                    read_m)
-        degree = jnp.zeros(n, jnp.int32)
-        # The lax.cond gates in this body are pure perf guards: each
-        # branch is exact under an all-False mask.  Under vmap (fleet
-        # lanes) a cond decays into computing BOTH branches plus a
-        # full-state select, so fleet bodies run the masked computation
-        # directly instead.
-        if cfg.fleet:
-            ps2, flush_m, wait_lock_m, wait_prec_m, wc_abort = \
-                _wc_cohort(cfg, ps1, s.dirty, wc_m)
+        with jax.named_scope("cohort.relations"):
+            if cfg.delta:
+                # the carried tables already equal this iteration's full
+                # recompute (the end-of-body delta pass maintains them for
+                # the NEXT cursor) — only the cheap O(n·w) reductions run
+                rel = P.relations_inputs(s.rel, read_m, s.pstate.haslocks)
+            elif cfg.megakernel:
+                from ..kernels import ops as kops
+                rel = kops.megastep_relations(
+                    s.pstate.read_set, s.pstate.write_set, s.dirty, cur_item,
+                    cur_w, s.pstate.active, read_m, s.pstate.haslocks)
+    with jax.named_scope("cohort.step"):
+        if cfg.protocol == "ppcc" and cfg.fused:
+            # one fused pass over the packed words: conflict/party matrix →
+            # ordered selection → op verdicts + apply → lock winners →
+            # commit test.  read_m and wc_m are disjoint (a slot is in one
+            # phase), which is what licenses the fused step's pre-state
+            # write-write join (see cohort_step_fused).  Bit-identical to
+            # the multipass chain below under order="index".
+            fs = P.cohort_step_fused(s.pstate, cur_item, cur_w, read_m, wc_m,
+                                     order=cfg.order, relations=rel)
+            ps1 = ps2 = fs.state
+            verdict, sel, reason = fs.verdict, fs.selected, fs.reason
+            degree = fs.degree
+            flush_m = wc_m & fs.won & fs.can_commit
+            wait_prec_m = wc_m & fs.won & ~fs.can_commit
+            wait_lock_m = wc_m & ~fs.won
+            wc_abort = jnp.zeros(n, bool)
         else:
-            ps2, flush_m, wait_lock_m, wait_prec_m, wc_abort = \
-                jax.lax.cond(
-                    wc_m.any(),
-                    lambda ps: _wc_cohort(cfg, ps, s.dirty, wc_m),
-                    lambda ps: (ps, jnp.zeros(n, bool),
-                                jnp.zeros(n, bool), jnp.zeros(n, bool),
-                                jnp.zeros(n, bool)),
-                    ps1)
-    deferred = read_m & ~sel
-    proceed = sel & (verdict == P.PROCEED)
-    v_block = sel & (verdict == P.BLOCK)
-    v_abort = sel & (verdict == P.ABORT)
-    op2 = s.op_idx + proceed
-    was_last = proceed & (op2 >= n_ops)
-    rd_disk = proceed & ~cur_w
-    wr_cpu = proceed & cur_w & ~was_last
-    wr_wc = proceed & cur_w & was_last
-    n_w = B.popcount(ps2.write_set)
-    flush_io = flush_m & (n_w > 0)
-    flush_zero = flush_m & (n_w == 0)
+            ps1, verdict, sel, reason = _try_ops_cohort(cfg, s.pstate,
+                                                        cur_item, cur_w,
+                                                        read_m)
+            degree = jnp.zeros(n, jnp.int32)
+            # The lax.cond gates in this body are pure perf guards: each
+            # branch is exact under an all-False mask.  Under vmap (fleet
+            # lanes) a cond decays into computing BOTH branches plus a
+            # full-state select, so fleet bodies run the masked computation
+            # directly instead.
+            if cfg.fleet:
+                ps2, flush_m, wait_lock_m, wait_prec_m, wc_abort = \
+                    _wc_cohort(cfg, ps1, s.dirty, wc_m)
+            else:
+                ps2, flush_m, wait_lock_m, wait_prec_m, wc_abort = \
+                    jax.lax.cond(
+                        wc_m.any(),
+                        lambda ps: _wc_cohort(cfg, ps, s.dirty, wc_m),
+                        lambda ps: (ps, jnp.zeros(n, bool),
+                                    jnp.zeros(n, bool), jnp.zeros(n, bool),
+                                    jnp.zeros(n, bool)),
+                        ps1)
+        deferred = read_m & ~sel
+        proceed = sel & (verdict == P.PROCEED)
+        v_block = sel & (verdict == P.BLOCK)
+        v_abort = sel & (verdict == P.ABORT)
+        op2 = s.op_idx + proceed
+        was_last = proceed & (op2 >= n_ops)
+        rd_disk = proceed & ~cur_w
+        wr_cpu = proceed & cur_w & ~was_last
+        wr_wc = proceed & cur_w & was_last
+        n_w = B.popcount(ps2.write_set)
+        flush_io = flush_m & (n_w > 0)
+        flush_zero = flush_m & (n_w == 0)
 
-    # ---------------- flush completions ----------------
-    left = s.flush_left - is_fl.astype(jnp.int32)
-    flush_more = is_fl & (left > 0)
-    flush_done = is_fl & (left <= 0)
+        # ---------------- flush completions ----------------
+        left = s.flush_left - is_fl.astype(jnp.int32)
+        flush_more = is_fl & (left > 0)
+        flush_done = is_fl & (left <= 0)
 
-    # ---------------- commits / aborts ----------------
-    commit_pre = flush_zero | flush_done
-    if cfg.protocol == "occ":
-        # close the Kung-Robinson overlap window: re-validate at commit.
-        # Same-iteration committers must also validate against each
-        # other (the event engine broadcasts each commit's writes before
-        # the next commit validates) — a slot-ordered pass over the
-        # accumulated writes of lower surviving committers, taken only
-        # on multi-commit iterations.
-        def occ_validate_multi(_):
-            def vstep(acc, i):
-                fail_i = commit_pre[i] & \
-                    B.overlap_rows(ps2.read_set[i], s.dirty[i] | acc)
-                acc = acc | jnp.where(commit_pre[i] & ~fail_i,
-                                      ps2.write_set[i], jnp.uint32(0))
-                return acc, fail_i
-            _, fails = jax.lax.scan(
-                vstep, jnp.zeros(ps2.words, jnp.uint32), idx)
-            return fails
+        # ---------------- commits / aborts ----------------
+        commit_pre = flush_zero | flush_done
+        if cfg.protocol == "occ":
+            # close the Kung-Robinson overlap window: re-validate at commit.
+            # Same-iteration committers must also validate against each
+            # other (the event engine broadcasts each commit's writes before
+            # the next commit validates) — a slot-ordered pass over the
+            # accumulated writes of lower surviving committers, taken only
+            # on multi-commit iterations.
+            def occ_validate_multi(_):
+                def vstep(acc, i):
+                    fail_i = commit_pre[i] & \
+                        B.overlap_rows(ps2.read_set[i], s.dirty[i] | acc)
+                    acc = acc | jnp.where(commit_pre[i] & ~fail_i,
+                                          ps2.write_set[i], jnp.uint32(0))
+                    return acc, fail_i
+                _, fails = jax.lax.scan(
+                    vstep, jnp.zeros(ps2.words, jnp.uint32), idx)
+                return fails
 
-        if cfg.fleet:
-            occ_fail = occ_validate_multi(None)
+            if cfg.fleet:
+                occ_fail = occ_validate_multi(None)
+            else:
+                occ_fail = jax.lax.cond(
+                    commit_pre.sum() > 1, occ_validate_multi,
+                    lambda _: commit_pre & B.overlap_rows(ps2.read_set,
+                                                          s.dirty),
+                    None)
         else:
-            occ_fail = jax.lax.cond(
-                commit_pre.sum() > 1, occ_validate_multi,
-                lambda _: commit_pre & B.overlap_rows(ps2.read_set,
-                                                      s.dirty),
-                None)
-    else:
-        occ_fail = jnp.zeros(n, bool)
-    commit_now = commit_pre & ~occ_fail
-    abort_now = to_expired | v_abort | wc_abort | occ_fail
+            occ_fail = jnp.zeros(n, bool)
+        commit_now = commit_pre & ~occ_fail
+        abort_now = to_expired | v_abort | wc_abort | occ_fail
 
     # ---------------- leave + re-begin (skipped on quiet iterations) ---
-    begin_m = commit_now | is_rs
+    with jax.named_scope("cohort.leave_begin"):
+        begin_m = commit_now | is_rs
 
-    def leave_and_begin(ps):
-        dirty = s.dirty
-        if cfg.protocol == "occ":
-            union = B.or_reduce(
-                jnp.where(commit_now[:, None], ps.write_set,
-                          jnp.uint32(0)), axis=0)
-            receivers = ps.active & ~commit_now & ~abort_now
-            dirty = jnp.where(receivers[:, None],
-                              dirty | union[None, :], dirty)
-            dirty = B.clear_rows(dirty, commit_now | abort_now)
-        if cfg.protocol == "ppcc":
-            ps = P.commit_many(ps, commit_now)
-            ps = P.abort_many(ps, abort_now)
-            return P.begin_many(ps, begin_m), dirty
-        # 2pl / occ never write prec, class bits or locks — leave/begin
-        # reduce to the read/write-set and active-bit updates
-        gone = commit_now | abort_now
-        return ps._replace(
-            read_set=B.clear_rows(ps.read_set, gone | begin_m),
-            write_set=B.clear_rows(ps.write_set, gone | begin_m),
-            active=(ps.active & ~gone) | begin_m,
-        ), dirty
+        def leave_and_begin(ps):
+            dirty = s.dirty
+            if cfg.protocol == "occ":
+                union = B.or_reduce(
+                    jnp.where(commit_now[:, None], ps.write_set,
+                              jnp.uint32(0)), axis=0)
+                receivers = ps.active & ~commit_now & ~abort_now
+                dirty = jnp.where(receivers[:, None],
+                                  dirty | union[None, :], dirty)
+                dirty = B.clear_rows(dirty, commit_now | abort_now)
+            if cfg.protocol == "ppcc":
+                ps = P.commit_many(ps, commit_now)
+                ps = P.abort_many(ps, abort_now)
+                return P.begin_many(ps, begin_m), dirty
+            # 2pl / occ never write prec, class bits or locks — leave/begin
+            # reduce to the read/write-set and active-bit updates
+            gone = commit_now | abort_now
+            return ps._replace(
+                read_set=B.clear_rows(ps.read_set, gone | begin_m),
+                write_set=B.clear_rows(ps.write_set, gone | begin_m),
+                active=(ps.active & ~gone) | begin_m,
+            ), dirty
 
-    if cfg.fleet:
-        ps5, dirty = leave_and_begin(ps2)
-    else:
-        ps5, dirty = jax.lax.cond(
-            (commit_now | abort_now | begin_m).any(),
-            leave_and_begin, lambda ps: (ps, s.dirty), ps2)
+        if cfg.fleet:
+            ps5, dirty = leave_and_begin(ps2)
+        else:
+            ps5, dirty = jax.lax.cond(
+                (commit_now | abort_now | begin_m).any(),
+                leave_and_begin, lambda ps: (ps, s.dirty), ps2)
 
-    # fresh workloads are only needed on commit iterations — gate the
-    # (vmapped) sampling behind a cond so quiet iterations skip it
-    def do_sample(k):
-        return sample_txns(k, cfg, s.rt, n)
+    with jax.named_scope("cohort.refill"):
+        # fresh workloads are only needed on commit iterations — gate the
+        # (vmapped) sampling behind a cond so quiet iterations skip it
+        def do_sample(k):
+            return sample_txns(k, cfg, s.rt, n)
 
-    def no_sample(k):
-        return (jnp.full((n, cfg.max_ops), -1, jnp.int8),
-                jnp.zeros((n, cfg.max_ops), jnp.int32))
+        def no_sample(k):
+            return (jnp.full((n, cfg.max_ops), -1, jnp.int8),
+                    jnp.zeros((n, cfg.max_ops), jnp.int32))
 
-    pool_next = s.pool_next
-    if cfg.pool:
-        # pop pool rows instead of sampling in-loop: the c-th committing
-        # slot (slot order) takes pool[(pool_next + c) mod P].  Same
-        # workload distribution, drawn once at init; the pool rides the
-        # carry untouched, so XLA hoists it as loop-invariant.
-        rank = jnp.cumsum(commit_now) - 1
-        take = (pool_next + jnp.where(commit_now, rank, 0)) % cfg.pool
-        fresh_kinds = s.pool_kinds[take]
-        fresh_items = s.pool_items[take]
-        pool_next = (pool_next + commit_now.sum()) % cfg.pool
-    elif cfg.fleet:
-        fresh_kinds, fresh_items = do_sample(kt)
-    else:
-        fresh_kinds, fresh_items = jax.lax.cond(commit_now.any(), do_sample,
-                                                no_sample, kt)
-    new_kinds = jnp.where(commit_now[:, None], fresh_kinds, s.kinds)
-    new_items = jnp.where(commit_now[:, None], fresh_items, s.items)
+        pool_next = s.pool_next
+        if cfg.pool:
+            # pop pool rows instead of sampling in-loop: the c-th committing
+            # slot (slot order) takes pool[(pool_next + c) mod P].  Same
+            # workload distribution, drawn once at init; the pool rides the
+            # carry untouched, so XLA hoists it as loop-invariant.
+            rank = jnp.cumsum(commit_now) - 1
+            take = (pool_next + jnp.where(commit_now, rank, 0)) % cfg.pool
+            fresh_kinds = s.pool_kinds[take]
+            fresh_items = s.pool_items[take]
+            pool_next = (pool_next + commit_now.sum()) % cfg.pool
+        elif cfg.fleet:
+            fresh_kinds, fresh_items = do_sample(kt)
+        else:
+            fresh_kinds, fresh_items = jax.lax.cond(
+                commit_now.any(), do_sample, no_sample, kt)
+        new_kinds = jnp.where(commit_now[:, None], fresh_kinds, s.kinds)
+        new_items = jnp.where(commit_now[:, None], fresh_items, s.items)
 
     # ---------------- resource reservations (one fused scan) -----------
-    cpu_req = wr_cpu | (is_disk & ~done_reading) | begin_m
-    disk_req = rd_disk | flush_more | flush_io
-    cpu_free, disk_free, cpu_done, disk_done = _reserve_cohort(
-        s.cpu_free, s.disk_free, te, dur_cpu, dur_io, cpu_req, disk_req)
+    with jax.named_scope("cohort.reserve"):
+        cpu_req = wr_cpu | (is_disk & ~done_reading) | begin_m
+        disk_req = rd_disk | flush_more | flush_io
+        cpu_free, disk_free, cpu_done, disk_done = _reserve_cohort(
+            s.cpu_free, s.disk_free, te, dur_cpu, dur_io, cpu_req, disk_req)
 
     # ---------------- transitions (masks are pairwise disjoint) --------
-    nt, nk = s.next_time, s.next_kind
-    ph, dl, fl = s.phase, s.deadline, left
+    with jax.named_scope("cohort.transitions"):
+        nt, nk = s.next_time, s.next_kind
+        ph, dl, fl = s.phase, s.deadline, left
 
-    def put(m, arr, val):
-        return jnp.where(m, val, arr)
+        def put(m, arr, val):
+            return jnp.where(m, val, arr)
 
-    # deferred read ops: retry next iteration at their own event time
-    nt = put(deferred, nt, te)
-    nk = put(deferred, nk, jnp.int8(EV_ATTEMPT))
-    # read proceeded -> disk read
-    nt = put(rd_disk, nt, disk_done)
-    nk = put(rd_disk, nk, jnp.int8(EV_DISK_DONE))
-    ph = put(rd_disk, ph, jnp.int8(PH_READ))
-    # write proceeded, not last -> next CPU burst
-    nt = put(wr_cpu, nt, cpu_done)
-    nk = put(wr_cpu, nk, jnp.int8(EV_ATTEMPT))
-    ph = put(wr_cpu, ph, jnp.int8(PH_READ))
-    # last write proceeded -> enter wait-to-commit immediately
-    nt = put(wr_wc, nt, te)
-    nk = put(wr_wc, nk, jnp.int8(EV_ATTEMPT))
-    ph = put(wr_wc, ph, jnp.int8(PH_READ))
-    # read-phase block
-    was_blocked = phase == PH_BLOCKED
-    new_dl = jnp.where(was_blocked, s.deadline, te + cfg.block_timeout)
-    dl = put(v_block, dl, new_dl)
-    ph = put(v_block, ph, jnp.int8(PH_BLOCKED))
-    nt = put(v_block, nt, new_dl)
-    nk = put(v_block, nk, jnp.int8(EV_TIMEOUT))
-    # wait-to-commit routing
-    ph = put(flush_m, ph, jnp.int8(PH_FLUSH))
-    fl = jnp.where(flush_m, n_w, fl)
-    nt = put(flush_io, nt, disk_done)
-    nk = put(flush_io, nk, jnp.int8(EV_FLUSH_DONE))
-    first_lock = phase != PH_WC_LOCK
-    lock_dl = jnp.where(first_lock, te + cfg.block_timeout, s.deadline)
-    dl = put(wait_lock_m, dl, lock_dl)
-    ph = put(wait_lock_m, ph, jnp.int8(PH_WC_LOCK))
-    nt = put(wait_lock_m, nt, lock_dl)
-    nk = put(wait_lock_m, nk, jnp.int8(EV_TIMEOUT))
-    ph = put(wait_prec_m, ph, jnp.int8(PH_WC_PREC))
-    nt = put(wait_prec_m, nt, INF)
-    nk = put(wait_prec_m, nk, jnp.int8(EV_ATTEMPT))
-    # disk completions
-    disk_cpu = is_disk & ~done_reading
-    nt = put(disk_cpu, nt, cpu_done)
-    nk = put(disk_cpu, nk, jnp.int8(EV_ATTEMPT))
-    disk_wc = is_disk & done_reading
-    nt = put(disk_wc, nt, te)
-    nk = put(disk_wc, nk, jnp.int8(EV_ATTEMPT))
-    # flush continues
-    nt = put(flush_more, nt, disk_done)
-    nk = put(flush_more, nk, jnp.int8(EV_FLUSH_DONE))
-    # aborts -> restart later
-    ph = put(abort_now, ph, jnp.int8(PH_RESTART))
-    nt = put(abort_now, nt, te + delay)
-    nk = put(abort_now, nk, jnp.int8(EV_RESTART))
-    # begins (fresh after commit / reuse after restart delay)
-    ph = put(begin_m, ph, jnp.int8(PH_READ))
-    fl = jnp.where(begin_m, 0, fl)
-    nt = put(begin_m, nt, cpu_done)
-    nk = put(begin_m, nk, jnp.int8(EV_ATTEMPT))
-    op_new = jnp.where(begin_m, 0, op2)
+        # deferred read ops: retry next iteration at their own event time
+        nt = put(deferred, nt, te)
+        nk = put(deferred, nk, jnp.int8(EV_ATTEMPT))
+        # read proceeded -> disk read
+        nt = put(rd_disk, nt, disk_done)
+        nk = put(rd_disk, nk, jnp.int8(EV_DISK_DONE))
+        ph = put(rd_disk, ph, jnp.int8(PH_READ))
+        # write proceeded, not last -> next CPU burst
+        nt = put(wr_cpu, nt, cpu_done)
+        nk = put(wr_cpu, nk, jnp.int8(EV_ATTEMPT))
+        ph = put(wr_cpu, ph, jnp.int8(PH_READ))
+        # last write proceeded -> enter wait-to-commit immediately
+        nt = put(wr_wc, nt, te)
+        nk = put(wr_wc, nk, jnp.int8(EV_ATTEMPT))
+        ph = put(wr_wc, ph, jnp.int8(PH_READ))
+        # read-phase block
+        was_blocked = phase == PH_BLOCKED
+        new_dl = jnp.where(was_blocked, s.deadline, te + cfg.block_timeout)
+        dl = put(v_block, dl, new_dl)
+        ph = put(v_block, ph, jnp.int8(PH_BLOCKED))
+        nt = put(v_block, nt, new_dl)
+        nk = put(v_block, nk, jnp.int8(EV_TIMEOUT))
+        # wait-to-commit routing
+        ph = put(flush_m, ph, jnp.int8(PH_FLUSH))
+        fl = jnp.where(flush_m, n_w, fl)
+        nt = put(flush_io, nt, disk_done)
+        nk = put(flush_io, nk, jnp.int8(EV_FLUSH_DONE))
+        first_lock = phase != PH_WC_LOCK
+        lock_dl = jnp.where(first_lock, te + cfg.block_timeout, s.deadline)
+        dl = put(wait_lock_m, dl, lock_dl)
+        ph = put(wait_lock_m, ph, jnp.int8(PH_WC_LOCK))
+        nt = put(wait_lock_m, nt, lock_dl)
+        nk = put(wait_lock_m, nk, jnp.int8(EV_TIMEOUT))
+        ph = put(wait_prec_m, ph, jnp.int8(PH_WC_PREC))
+        nt = put(wait_prec_m, nt, INF)
+        nk = put(wait_prec_m, nk, jnp.int8(EV_ATTEMPT))
+        # disk completions
+        disk_cpu = is_disk & ~done_reading
+        nt = put(disk_cpu, nt, cpu_done)
+        nk = put(disk_cpu, nk, jnp.int8(EV_ATTEMPT))
+        disk_wc = is_disk & done_reading
+        nt = put(disk_wc, nt, te)
+        nk = put(disk_wc, nk, jnp.int8(EV_ATTEMPT))
+        # flush continues
+        nt = put(flush_more, nt, disk_done)
+        nk = put(flush_more, nk, jnp.int8(EV_FLUSH_DONE))
+        # aborts -> restart later
+        ph = put(abort_now, ph, jnp.int8(PH_RESTART))
+        nt = put(abort_now, nt, te + delay)
+        nk = put(abort_now, nk, jnp.int8(EV_RESTART))
+        # begins (fresh after commit / reuse after restart delay)
+        ph = put(begin_m, ph, jnp.int8(PH_READ))
+        fl = jnp.where(begin_m, 0, fl)
+        nt = put(begin_m, nt, cpu_done)
+        nk = put(begin_m, nk, jnp.int8(EV_ATTEMPT))
+        op_new = jnp.where(begin_m, 0, op2)
 
-    # wake waiters on any commit/abort
-    any_leave = (commit_now | abort_now).any()
-    waiting = (ph == PH_BLOCKED) | (ph == PH_WC_LOCK) | (ph == PH_WC_PREC)
-    nt = jnp.where(any_leave & waiting, jnp.minimum(nt, t0), nt)
+        # wake waiters on any commit/abort
+        any_leave = (commit_now | abort_now).any()
+        waiting = (ph == PH_BLOCKED) | (ph == PH_WC_LOCK) | (ph == PH_WC_PREC)
+        nt = jnp.where(any_leave & waiting, jnp.minimum(nt, t0), nt)
 
     # ---------------- delta relation maintenance ----------------------
-    if cfg.delta and cfg.protocol == "ppcc" and cfg.fused:
-        rel_c = _delta_update(cfg, s, ps5, cur_item, cur_w,
-                              new_kinds, new_items, op_new)
-    else:
-        rel_c = s.rel
+    with jax.named_scope("cohort.relations"):
+        if cfg.delta and cfg.protocol == "ppcc" and cfg.fused:
+            rel_c = _delta_update(cfg, s, ps5, cur_item, cur_w,
+                                  new_kinds, new_items, op_new)
+        else:
+            rel_c = s.rel
 
-    new_block = v_block & ~was_blocked
+    with jax.named_scope("cohort.transitions"):
+        new_block = v_block & ~was_blocked
 
     # ---------------- telemetry (compiled out when cfg.telemetry off) --
     if cfg.telemetry:
@@ -1184,15 +1199,17 @@ def _cohort_body(cfg: EngCfg, s: EngState) -> EngState:
     else:
         tm = s.tm
 
-    return s._replace(
-        pstate=ps5, dirty=dirty, kinds=new_kinds, items=new_items, rel=rel_c,
-        op_idx=op_new, phase=ph, next_time=nt, next_kind=nk, deadline=dl,
-        flush_left=fl, cpu_free=cpu_free, disk_free=disk_free,
-        commits=s.commits + commit_now.sum(),
-        aborts=s.aborts + abort_now.sum(),
-        blocks=s.blocks + new_block.sum(),
-        ops_done=s.ops_done + proceed.sum(),
-        pool_next=pool_next, tm=tm)
+    with jax.named_scope("cohort.transitions"):
+        return s._replace(
+            pstate=ps5, dirty=dirty, kinds=new_kinds, items=new_items,
+            rel=rel_c, op_idx=op_new, phase=ph, next_time=nt, next_kind=nk,
+            deadline=dl, flush_left=fl, cpu_free=cpu_free,
+            disk_free=disk_free,
+            commits=s.commits + commit_now.sum(),
+            aborts=s.aborts + abort_now.sum(),
+            blocks=s.blocks + new_block.sum(),
+            ops_done=s.ops_done + proceed.sum(),
+            pool_next=pool_next, tm=tm)
 
 
 def default_cohort_dt(p: SimParams) -> float:

@@ -161,8 +161,9 @@ class Fleet:
             init, cond, step = parts[proto]
 
             def run_one(seed, mpl, rt):
-                return jax.lax.while_loop(cond, step,
-                                          init(seed, mpl, rt))
+                with jax.named_scope("fleet.init"):
+                    s0 = init(seed, mpl, rt)
+                return jax.lax.while_loop(cond, step, s0)
 
             runner = jax.vmap(run_one)
             if mesh is not None:
@@ -180,7 +181,8 @@ class Fleet:
             self.traces += 1          # python side effect: counts traces
             out = {}
             for proto in self.protocols:
-                fin = runners[proto](seed_l, mpl_l, rt_l)
+                with jax.named_scope(f"fleet.{proto}"):
+                    fin = runners[proto](seed_l, mpl_l, rt_l)
                 res = {k: getattr(fin, k) for k in METRICS}
                 res["now"] = fin.now
                 if self.telemetry:
@@ -205,15 +207,24 @@ class Fleet:
         ``rts`` leaves are per-lane ``[L]`` vectors; every lane's
         values must fit the fleet's static buckets (``check_rt``).
         Same lane count -> same executable (``traces`` proves it).
+
+        Two host spans label a profiler trace: ``fleet.check`` (the
+        bucket checks, which read values back from the device) and
+        ``fleet.dispatch`` (the jitted call).  Inside the program each
+        protocol's lanes run under the scope ``fleet.<protocol>`` and
+        their set-up under ``fleet.init``; scopes change only the
+        compiled program's ``op_name`` metadata.
         """
         seeds = jnp.asarray(seeds, jnp.int32)
         mpls = jnp.asarray(mpls, jnp.int32)
-        if int(mpls.max()) > self.n_slots:
-            raise ValueError(
-                f"max(mpls)={int(mpls.max())} exceeds "
-                f"n_slots={self.n_slots}")
-        jaxsim.check_rt(self.params, rts)
-        return self._jit(seeds, mpls, rts)
+        with jax.profiler.TraceAnnotation("fleet.check"):
+            if int(mpls.max()) > self.n_slots:
+                raise ValueError(
+                    f"max(mpls)={int(mpls.max())} exceeds "
+                    f"n_slots={self.n_slots}")
+            jaxsim.check_rt(self.params, rts)
+        with jax.profiler.TraceAnnotation("fleet.dispatch"):
+            return self._jit(seeds, mpls, rts)
 
     def __call__(self, mpls, seeds):
         mpls = np.asarray(mpls, np.int32)
