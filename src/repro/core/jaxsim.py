@@ -671,18 +671,24 @@ def _reserve_cohort(cpu_free: jax.Array, disk_free: jax.Array,
     sequential ``argmin(free_at)`` reservation per masked slot, in
     slot-index order (the cohort's tie-break).  A slot requests at most
     one of {cpu, disk}, so both pools ride the same scan.  Returns
-    (cpu_free', disk_free', cpu_done[n], disk_done[n])."""
+    (cpu_free', disk_free', cpu_done[n], disk_done[n]).
+
+    A step never indexes a pool with a traced index: ``min`` reads the
+    first free server's time (``pool[argmin(pool)]``, bit for bit) and
+    a one-hot select writes it back.  Under the fleet's ``vmap`` an
+    indexed read or write becomes a per-lane gather or scatter, which
+    the TPU applies one row at a time (DESIGN.md §2.2)."""
+    def reserve(pool, t, dur, m):
+        done = jnp.maximum(t, jnp.min(pool)) + dur
+        hit = m & (jnp.arange(pool.shape[0]) == jnp.argmin(pool))
+        return jnp.where(hit, done, pool), jnp.where(m, done, INF)
+
     def step(carry, inp):
-        cpu, disk, = carry
+        cpu, disk = carry
         t, cd, dd, cm, dm = inp
-        ci = jnp.argmin(cpu)
-        cdone = jnp.maximum(t, cpu[ci]) + cd
-        cpu2 = jnp.where(cm, cpu.at[ci].set(cdone), cpu)
-        di = jnp.argmin(disk)
-        ddone = jnp.maximum(t, disk[di]) + dd
-        disk2 = jnp.where(dm, disk.at[di].set(ddone), disk)
-        return (cpu2, disk2), (jnp.where(cm, cdone, INF),
-                               jnp.where(dm, ddone, INF))
+        cpu2, cdone = reserve(cpu, t, cd, cm)
+        disk2, ddone = reserve(disk, t, dd, dm)
+        return (cpu2, disk2), (cdone, ddone)
 
     (cpu_free, disk_free), (cpu_done, disk_done) = jax.lax.scan(
         step, (cpu_free, disk_free), (t_req, cpu_dur, io_dur, cpu_m,

@@ -178,6 +178,81 @@ def test_cohort_step_fused_matches_multipass_substeps(seed):
 
 
 # --------------------------------------------------------------------------
+# FCFS reservation scan: min + one-hot select vs the gather/scatter form
+# --------------------------------------------------------------------------
+
+def _reserve_cohort_indexed(cpu_free, disk_free, t_req, cpu_dur, io_dur,
+                            cpu_m, disk_m):
+    """Reference scan with indexed pool access: read ``pool[argmin(pool)]``
+    and write back with ``.at[i].set`` (a per-lane gather and scatter
+    under ``vmap``)."""
+    def step(carry, inp):
+        cpu, disk = carry
+        t, cd, dd, cm, dm = inp
+        ci = jnp.argmin(cpu)
+        cdone = jnp.maximum(t, cpu[ci]) + cd
+        cpu2 = jnp.where(cm, cpu.at[ci].set(cdone), cpu)
+        di = jnp.argmin(disk)
+        ddone = jnp.maximum(t, disk[di]) + dd
+        disk2 = jnp.where(dm, disk.at[di].set(ddone), disk)
+        return (cpu2, disk2), (jnp.where(cm, cdone, jaxsim.INF),
+                               jnp.where(dm, ddone, jaxsim.INF))
+
+    (cpu_free, disk_free), (cpu_done, disk_done) = jax.lax.scan(
+        step, (cpu_free, disk_free), (t_req, cpu_dur, io_dur, cpu_m,
+                                      disk_m))
+    return cpu_free, disk_free, cpu_done, disk_done
+
+
+def _reserve_case(case, rng, lanes=32, n=160, c_pool=16, d_pool=32):
+    """Vmapped inputs of ``_reserve_cohort`` for one named case."""
+    c_live, d_live = (c_pool, d_pool) if case == "full" else (4, 8)
+    cpu = np.full((lanes, c_pool), float(jaxsim.INF), np.float32)
+    disk = np.full((lanes, d_pool), float(jaxsim.INF), np.float32)
+    cpu[:, :c_live] = rng.uniform(0, 50, (lanes, c_live))
+    disk[:, :d_live] = rng.uniform(0, 50, (lanes, d_live))
+    t = np.sort(rng.uniform(0, 60, (lanes, n)), axis=1).astype(np.float32)
+    cd = rng.uniform(1, 15, (lanes, n)).astype(np.float32)
+    dd = rng.uniform(5, 45, (lanes, n)).astype(np.float32)
+    if case == "ties":
+        # several idle servers, and requests whose done times collide
+        cpu[:, :c_live] = np.where(rng.random((lanes, c_live)) < 0.5,
+                                   0.0, np.round(cpu[:, :c_live]))
+        disk[:, :d_live] = np.where(rng.random((lanes, d_live)) < 0.5,
+                                    0.0, np.round(disk[:, :d_live]))
+        t = np.round(t / 10) * 10
+        cd, dd = np.round(cd), np.round(dd)
+    if case == "none":
+        cm = dm = np.zeros((lanes, n), bool)
+    elif case == "all":
+        cm = dm = np.ones((lanes, n), bool)
+    else:
+        pick = rng.integers(0, 3, (lanes, n))      # 0 none, 1 cpu, 2 disk
+        cm, dm = pick == 1, pick == 2
+    return tuple(jnp.asarray(a) for a in (cpu, disk, t, cd, dd, cm, dm))
+
+
+@pytest.mark.parametrize("case", ["padded", "full", "ties", "none", "all"])
+def test_reserve_cohort_matches_indexed_scan(case):
+    """``_reserve_cohort`` (min + one-hot select) equals the gather /
+    scatter scan bit for bit on all four outputs, vmapped over lanes."""
+    args = _reserve_case(case, np.random.default_rng(len(case)))
+    got = jax.jit(jax.vmap(jaxsim._reserve_cohort))(*args)
+    want = jax.jit(jax.vmap(_reserve_cohort_indexed))(*args)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    if case == "ties":
+        # the case reaches both tie-breaks: equal free times before the
+        # scan, equal done times out of it
+        first2 = np.sort(np.asarray(args[0]), axis=1)[:, :2]
+        assert (first2[:, 0] == first2[:, 1]).any()
+        done = np.asarray(got[2])
+        assert any(len(np.unique(r[r < 1e29])) < (r < 1e29).sum()
+                   for r in done)
+
+
+# --------------------------------------------------------------------------
 # engine-level parity (the test_jaxsim_vs_pysim grid)
 # --------------------------------------------------------------------------
 

@@ -13,12 +13,14 @@ only one process may load the TPU library at a time, and every test
 worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core import jaxsim
 from repro.kernels import conflict as KC
 from repro.kernels import megastep as MS
 
@@ -53,7 +55,38 @@ def _compile(fn, sharding, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-U32, I32, BOOL = jnp.uint32, jnp.int32, jnp.bool_
+U32, I32, BOOL, F32 = jnp.uint32, jnp.int32, jnp.bool_, jnp.float32
+FLEET_LANES, CPU_POOL, DISK_POOL = 168, 16, 32
+
+
+def _computations(hlo):
+    """Map each computation's name in HLO text to its instruction lines."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(", line)
+        if head and line.rstrip().endswith("{"):
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _called(comps, root):
+    """Lines of ``root`` and of every computation it calls, transitively."""
+    seen, todo, lines = set(), [root], []
+    while todo:
+        name = todo.pop()
+        if name in seen:
+            continue
+        seen.add(name)
+        for line in comps[name]:
+            lines.append(line)
+            todo += [c for c in re.findall(r"%([\w.\-]+)", line)
+                     if c in comps]
+    return lines
 
 
 def test_megastep_compiles_vmapped_over_lanes(one_chip):
@@ -80,3 +113,20 @@ def test_conflict_kernels_compile(one_chip, kernel):
     shape = ((N_TXN, TXN_WORDS), U32)
     hlo = _compile(fn, one_chip, shape, shape)
     assert "tpu_custom_call" in hlo
+
+
+def test_reserve_scan_body_has_no_gather_or_scatter(one_chip):
+    """The fleet's FCFS reservation scan (``_reserve_cohort`` vmapped
+    over the grid's lanes) reads and writes its pools with ``min`` and
+    a one-hot select: a per-lane gather or scatter in the loop body is
+    applied one row at a time on the TPU, every step."""
+    lanes, n = FLEET_LANES, N_SLOTS
+    hlo = _compile(jax.vmap(jaxsim._reserve_cohort), one_chip,
+                   ((lanes, CPU_POOL), F32), ((lanes, DISK_POOL), F32),
+                   *([((lanes, n), F32)] * 3), *([((lanes, n), BOOL)] * 2))
+    comps = _computations(hlo)
+    bodies = re.findall(r" while\(.*?body=%([\w.\-]+)", hlo)
+    assert bodies, "the scan compiled to no while loop"
+    for body in bodies:
+        ops = " ".join(_called(comps, body))
+        assert "scatter(" not in ops and "gather(" not in ops, body
