@@ -58,6 +58,15 @@ def conflict_fused_full(read_bits, write_bits, *, block: int = 256):
         interpret=_interpret_default())
 
 
+@functools.partial(jax.jit, static_argnames=("block",))
+def conflict_keys(read_keys, write_keys, *, block: int = 256):
+    """Key lists int32[N, k] (negative ids are pads) -> the
+    ``conflict_fused_full`` 7-tuple; see kernels.conflict."""
+    return _conflict.conflict_keys(
+        read_keys, write_keys, block=block,
+        interpret=_interpret_default())
+
+
 @jax.jit
 def megastep_relations(read_bits, write_bits, dirty_bits, item, is_write,
                        active, ready, haslocks):

@@ -56,12 +56,28 @@ def conflict_fused_full_ref(read_bits: jax.Array, write_bits: jax.Array):
     ww_deg, diag_raw, diag_ww).  ``war_deg`` is the COLUMN sum of raw
     (who reads what I write); row/column degrees include the diagonal,
     the diag vectors let callers strip self-conflicts."""
-    raw = conflict_matrix_ref(read_bits, write_bits)
-    ww = conflict_matrix_ref(write_bits, write_bits)
+    return _full(conflict_matrix_ref(read_bits, write_bits),
+                 conflict_matrix_ref(write_bits, write_bits))
+
+
+def _full(raw: jax.Array, ww: jax.Array):
     return (raw, ww, raw.sum(axis=1).astype(jnp.int32),
             raw.sum(axis=0).astype(jnp.int32),
             ww.sum(axis=1).astype(jnp.int32),
             jnp.diagonal(raw), jnp.diagonal(ww))
+
+
+def conflict_keys_ref(read_keys: jax.Array, write_keys: jax.Array):
+    """Oracle for ``conflict_keys``: the ``conflict_fused_full_ref``
+    7-tuple of transactions given as key lists ``int32[N, k]``, where a
+    negative id is a pad and matches nothing."""
+    def overlap(a, b):
+        eq = a[:, None, :, None] == b[None, :, None, :]
+        real = (a >= 0)[:, None, :, None] & (b >= 0)[None, :, None, :]
+        return (eq & real).any(axis=(2, 3))
+
+    return _full(overlap(read_keys, write_keys),
+                 overlap(write_keys, write_keys))
 
 
 def megastep_ref(read_bits: jax.Array, write_bits: jax.Array,

@@ -30,6 +30,13 @@ are live) flows through unchanged.  ``tick(..., words=...)`` pads
 boolean inputs to such a bucket at pack time — ticks of
 different-sized workloads then share one jitted executable, the same
 static-axis bucketing story as ``core.sweep`` (DESIGN.md §2.4).
+
+A keyspace of millions of records has no bitset row worth forming.
+With ``keys=True`` the sets are key-id lists instead — ``int32[n, kr]``
+read keys and ``int32[n, kw]`` write keys, negative ids padding short
+lists — and the relations come from ``conflict_keys``, whose work is
+n² x kr x kw compares whatever the keyspace.  Every policy, both
+orders, the carry and ``tick_stats`` take either form.
 """
 from __future__ import annotations
 
@@ -74,40 +81,76 @@ class TickResult(NamedTuple):
 class TickCarry(NamedTuple):
     """Carried pairwise state for back-to-back ticks.
 
-    Holds the previous tick's packed set words plus the full fused
-    conflict launch output (``conflict_fused_full``'s 7-tuple).  When
-    the next tick's words and valid mask are unchanged — common when
-    the pending batch persists across ticks (blocked actors retrying) —
-    the O(n²·w) launch is skipped and the carried matrices are reused
-    (a ``lax.cond`` guards exactness)."""
-    read_bits: jax.Array      # uint32[n, W]
-    write_bits: jax.Array     # uint32[n, W]
+    Holds the previous tick's set operands (packed words, or key lists)
+    plus the full fused conflict launch output (the 7-tuple of
+    ``conflict_fused_full`` / ``conflict_keys``).  When the next tick's
+    sets and valid mask are unchanged — common when the pending batch
+    persists across ticks (blocked actors retrying) — the O(n²) launch
+    is skipped and the carried matrices are reused (a ``lax.cond``
+    guards exactness)."""
+    read_sets: jax.Array      # uint32[n, W] or int32[n, kr]
+    write_sets: jax.Array     # uint32[n, W] or int32[n, kw]
     valid: jax.Array          # bool[n]
-    rel: Tuple[jax.Array, ...]  # conflict_fused_full output (7-tuple)
+    rel: Tuple[jax.Array, ...]  # the full launch's 7-tuple
 
 
-def _conflict_matrices(read_bits: jax.Array, write_bits: jax.Array,
-                       use_kernel: bool
-                       ) -> Tuple[jax.Array, jax.Array, jax.Array,
-                                  jax.Array]:
-    """(raw[i,j]: i reads what j writes, ww[i,j]: write/write overlap,
-    raw_deg[i], ww_deg[i]: per-row popcount degrees incl. diagonal).
+def _operands(read_sets: jax.Array, write_sets: jax.Array, words: int,
+              keys: bool) -> Tuple[jax.Array, jax.Array]:
+    """The set operands of the conflict launch: packed words (padded to
+    ``words``), or the key lists as they are."""
+    if keys:
+        if words is not None:
+            raise ValueError("words= buckets packed rows; key lists "
+                             "have none")
+        return read_sets.astype(jnp.int32), write_sets.astype(jnp.int32)
+    return _as_bits(read_sets, words), _as_bits(write_sets, words)
+
+
+def _conflict_matrices(read: jax.Array, write: jax.Array,
+                       use_kernel: bool, keys: bool = False,
+                       full: bool = False) -> Tuple[jax.Array, ...]:
+    """The one dispatch of the pairwise launch.  Packed words go to
+    ``conflict_fused`` → (raw[i,j]: i reads what j writes, ww[i,j]:
+    write/write overlap, raw_deg[i], ww_deg[i]: per-row popcount
+    degrees incl. diagonal), or with ``full`` to ``conflict_fused_full``
+    → (raw, ww, raw_deg, war_deg, ww_deg, diag_raw, diag_ww).  Key
+    lists always go to ``conflict_keys`` (the 7-tuple; without ``full``
+    its four-tuple part).  ``use_kernel=False`` takes the jnp oracles.
 
     One fused Pallas launch emits both relations and the degrees; the
     degrees feed the degree-ordered admission heuristic below."""
+    if keys:
+        out = (kops.conflict_keys(read, write) if use_kernel
+               else kops.ref.conflict_keys_ref(read, write))
+        return out if full else (out[0], out[1], out[2], out[4])
+    if full:
+        return (kops.conflict_fused_full(read, write) if use_kernel
+                else kops.ref.conflict_fused_full_ref(read, write))
     if use_kernel:
-        return kops.conflict_fused(read_bits, write_bits)
-    return kops.ref.conflict_fused_ref(read_bits, write_bits)
+        return kops.conflict_fused(read, write)
+    return kops.ref.conflict_fused_ref(read, write)
+
+
+def _prudent(r_i: jax.Array, w_i: jax.Array, preceding: jax.Array,
+             preceded: jax.Array) -> jax.Array:
+    """The Prudent Precedence Rule for one transaction against the
+    admitted set, given its RAW arcs out (``r_i``) and WAR arcs in
+    (``w_i``): it may not become both preceding and preceded, may not
+    precede a preceding transaction, and may not follow a preceded
+    one (the rule's two class tests)."""
+    return (~(r_i.any() & w_i.any()) & ~(r_i & preceding).any()
+            & ~(w_i & preceded).any())
 
 
 def ppcc_tick(read_sets: jax.Array, write_sets: jax.Array,
               valid: jax.Array, use_kernel: bool = True,
               order: str = "priority", words: int = None,
-              carry: TickCarry = None, return_carry: bool = False
-              ) -> TickResult:
+              carry: TickCarry = None, return_carry: bool = False,
+              keys: bool = False) -> TickResult:
     """Admit a batch of single-shot transactions under PPCC.
 
-    read_sets/write_sets: bool[n, d]; valid: bool[n].  Each transaction
+    read_sets/write_sets: bool[n, d], packed words, or with ``keys``
+    key lists; valid: bool[n].  Each transaction
     executes atomically in priority order, reads before writes.  With
     the pairwise conflict matrices precomputed (Pallas kernel), the
     Prudent Precedence Rule for transaction i against the already-
@@ -130,73 +173,76 @@ def ppcc_tick(read_sets: jax.Array, write_sets: jax.Array,
     larger batches under contention at the cost of strict priority.
 
     ``carry`` (a previous tick's ``TickCarry``) skips the fused
-    conflict launch entirely when the packed words and valid mask are
+    conflict launch entirely when the set operands and valid mask are
     unchanged since that tick; pass ``return_carry=True`` to get
     ``(TickResult, TickCarry)`` for the next tick.
+
+    The three parts run under the named scopes ``tick.conflict`` (the
+    pairwise launch and the admission order), ``tick.scan`` (the
+    rule, one step per transaction) and ``tick.commit_order``.
     """
     n = read_sets.shape[0]
-    rb = _as_bits(read_sets, words)
-    wb = _as_bits(write_sets, words)
-    full = None
-    if order == "degree" or carry is not None or return_carry:
-        # One fused launch emits the matrices, all three degrees AND
-        # the diagonals.  With a carry whose inputs are unchanged the
-        # launch is skipped and the carried 7-tuple reused.
-        def launch():
-            return (kops.conflict_fused_full(rb, wb) if use_kernel
-                    else kops.ref.conflict_fused_full_ref(rb, wb))
+    with jax.named_scope("tick.conflict"):
+        rs, ws = _operands(read_sets, write_sets, words, keys)
+        full = None
+        if order == "degree" or carry is not None or return_carry:
+            # One fused launch emits the matrices, all three degrees
+            # AND the diagonals.  With a carry whose inputs are
+            # unchanged the launch is skipped and the carried 7-tuple
+            # reused.
+            def launch():
+                return _conflict_matrices(rs, ws, use_kernel, keys,
+                                          full=True)
 
-        if carry is not None:
-            unchanged = ((carry.read_bits == rb).all()
-                         & (carry.write_bits == wb).all()
-                         & (carry.valid == valid).all())
-            full = jax.lax.cond(unchanged, lambda: carry.rel, launch)
+            if carry is not None:
+                unchanged = ((carry.read_sets == rs).all()
+                             & (carry.write_sets == ws).all()
+                             & (carry.valid == valid).all())
+                full = jax.lax.cond(unchanged, lambda: carry.rel, launch)
+            else:
+                full = launch()
+            raw = full[0]
+        if order == "degree":
+            # total involvement = RAW out-degree + WAR in-degree (the
+            # kernel's column-sum output) + WW degree; kernel degrees
+            # include the diagonal and self-conflicts are not conflicts
+            # here, so strip it everywhere.
+            _, _, raw_deg, war_deg, ww_deg, diag_raw, diag_ww = full
+            self_r = diag_raw.astype(jnp.int32)
+            deg = (raw_deg - self_r + war_deg - self_r
+                   + ww_deg - diag_ww.astype(jnp.int32))
+            seq = jnp.argsort(deg, stable=True).astype(jnp.int32)
         else:
-            full = launch()
-        raw, ww = full[0], full[1]
-    if order == "degree":
-        # total involvement = RAW out-degree + WAR in-degree (the
-        # kernel's column-sum output) + WW degree; kernel degrees
-        # include the diagonal and self-conflicts are not conflicts
-        # here, so strip it everywhere.
-        _, _, raw_deg, war_deg, ww_deg, diag_raw, diag_ww = full
-        self_r = diag_raw.astype(jnp.int32)
-        deg = (raw_deg - self_r + war_deg - self_r
-               + ww_deg - diag_ww.astype(jnp.int32))
-        seq = jnp.argsort(deg, stable=True).astype(jnp.int32)
-    else:
-        if full is None:
-            raw, ww, *_ = _conflict_matrices(rb, wb, use_kernel)
-        seq = jnp.arange(n, dtype=jnp.int32)
-    raw = raw & ~jnp.eye(n, dtype=bool)              # self-RAW is not a conflict
+            if full is None:
+                raw = _conflict_matrices(rs, ws, use_kernel, keys)[0]
+            seq = jnp.arange(n, dtype=jnp.int32)
+        raw = raw & ~jnp.eye(n, dtype=bool)      # self-RAW is not a conflict
 
     def step(carry, i):
         admitted, preceding, preceded, prec = carry
         r_i = raw[i] & admitted                      # i -> j arcs (RAW)
         w_i = raw[:, i] & admitted                   # k -> i arcs (WAR)
-        any_r, any_w = r_i.any(), w_i.any()
-        ok = valid[i]
-        ok &= ~(any_r & any_w)
-        ok &= ~(r_i & preceding).any()
-        ok &= ~(w_i & preceded).any()
+        ok = valid[i] & _prudent(r_i, w_i, preceding, preceded)
         admitted = admitted.at[i].set(ok)
-        preceding = preceding.at[i].set(ok & any_r) | (w_i & ok)
-        preceded = preceded.at[i].set(ok & any_w) | (r_i & ok)
+        preceding = preceding.at[i].set(ok & r_i.any()) | (w_i & ok)
+        preceded = preceded.at[i].set(ok & w_i.any()) | (r_i & ok)
         prec = prec.at[i, :].set(jnp.where(ok, r_i, prec[i, :]))
         prec = prec.at[:, i].set(jnp.where(ok, w_i, prec[:, i]))
         return (admitted, preceding, preceded, prec), ok
 
-    init = (jnp.zeros(n, bool), jnp.zeros(n, bool), jnp.zeros(n, bool),
-            jnp.zeros((n, n), bool))
-    (admitted, preceding, preceded, prec), _ = jax.lax.scan(
-        step, init, seq)
-    # commit order: preceding-class (readers) first
-    rank_key = jnp.where(admitted, preceded.astype(jnp.int32), 2 ** 30)
-    commit_order = jnp.argsort(rank_key, stable=True)
-    commit_rank = jnp.full((n,), -1, jnp.int32)
-    commit_rank = commit_rank.at[commit_order].set(
-        jnp.arange(n, dtype=jnp.int32))
-    commit_rank = jnp.where(admitted, commit_rank, -1)
+    with jax.named_scope("tick.scan"):
+        init = (jnp.zeros(n, bool), jnp.zeros(n, bool), jnp.zeros(n, bool),
+                jnp.zeros((n, n), bool))
+        (admitted, preceding, preceded, prec), _ = jax.lax.scan(
+            step, init, seq)
+    with jax.named_scope("tick.commit_order"):
+        # preceding-class (readers) first
+        rank_key = jnp.where(admitted, preceded.astype(jnp.int32), 2 ** 30)
+        commit_order = jnp.argsort(rank_key, stable=True)
+        commit_rank = jnp.full((n,), -1, jnp.int32)
+        commit_rank = commit_rank.at[commit_order].set(
+            jnp.arange(n, dtype=jnp.int32))
+        commit_rank = jnp.where(admitted, commit_rank, -1)
     s = ppcc.init_state(n, 1)
     s = s._replace(prec=prec, preceding=preceding, preceded=preceded,
                    active=admitted)
@@ -204,19 +250,18 @@ def ppcc_tick(read_sets: jax.Array, write_sets: jax.Array,
                      aborted=jnp.zeros_like(admitted),
                      commit_rank=commit_rank, state=s)
     if return_carry:
-        return res, TickCarry(read_bits=rb, write_bits=wb, valid=valid,
+        return res, TickCarry(read_sets=rs, write_sets=ws, valid=valid,
                               rel=full)
     return res
 
 
 def twopl_tick(read_sets: jax.Array, write_sets: jax.Array,
                valid: jax.Array, use_kernel: bool = True,
-               words: int = None) -> TickResult:
+               words: int = None, keys: bool = False) -> TickResult:
     """Conservative baseline: admit a prefix-greedy conflict-free set."""
     n = read_sets.shape[0]
-    rb = _as_bits(read_sets, words)
-    wb = _as_bits(write_sets, words)
-    raw, ww, *_ = _conflict_matrices(rb, wb, use_kernel)
+    raw, ww, *_ = _conflict_matrices(
+        *_operands(read_sets, write_sets, words, keys), use_kernel, keys)
     conflict = raw | raw.T | ww            # any lock conflict
     conflict = conflict & ~jnp.eye(n, dtype=bool)
 
@@ -234,14 +279,13 @@ def twopl_tick(read_sets: jax.Array, write_sets: jax.Array,
 
 def occ_tick(read_sets: jax.Array, write_sets: jax.Array,
              valid: jax.Array, use_kernel: bool = True,
-             words: int = None) -> TickResult:
+             words: int = None, keys: bool = False) -> TickResult:
     """Optimistic baseline: all run; backward validation in priority
     order — abort if an earlier-priority survivor wrote what you read
     (or wrote)."""
     n = read_sets.shape[0]
-    rb = _as_bits(read_sets, words)
-    wb = _as_bits(write_sets, words)
-    raw, ww, *_ = _conflict_matrices(rb, wb, use_kernel)
+    raw, ww, *_ = _conflict_matrices(
+        *_operands(read_sets, write_sets, words, keys), use_kernel, keys)
     bad = raw | ww                          # i conflicts with j's writes
 
     def step(survivors, i):
@@ -264,15 +308,15 @@ POLICIES = {"ppcc": ppcc_tick, "2pl": twopl_tick, "occ": occ_tick}
 
 def tick_stats(read_sets: jax.Array, write_sets: jax.Array,
                valid: jax.Array, result: TickResult,
-               use_kernel: bool = True, words: int = None) -> dict:
+               use_kernel: bool = True, words: int = None,
+               keys: bool = False) -> dict:
     """Host-side per-tick telemetry: admitted/aborted/pending counts
     plus conflict-degree stats over the valid batch (max / mean rows of
     the symmetric conflict relation ``raw | raw^T | ww``).  Pure
     observation — reads the tick inputs and result, mutates nothing."""
-    rb = _as_bits(read_sets, words)
-    wb = _as_bits(write_sets, words)
-    raw, ww, *_ = _conflict_matrices(rb, wb, use_kernel)
-    n = rb.shape[0]
+    raw, ww, *_ = _conflict_matrices(
+        *_operands(read_sets, write_sets, words, keys), use_kernel, keys)
+    n = raw.shape[0]
     conflict = (raw | raw.T | ww) & ~jnp.eye(n, dtype=bool)
     conflict = conflict & valid[None, :] & valid[:, None]
     deg = np.asarray(conflict.sum(axis=1))[np.asarray(valid)]
@@ -290,22 +334,26 @@ def tick_stats(read_sets: jax.Array, write_sets: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=("policy", "order", "words",
-                                             "return_carry"))
+                                             "return_carry", "keys"))
 def tick(read_sets: jax.Array, write_sets: jax.Array, valid: jax.Array,
          policy: str = "ppcc", order: str = "priority",
          words: int = None, carry: TickCarry = None,
-         return_carry: bool = False) -> TickResult:
-    """One admission tick.  For ppcc, ``carry``/``return_carry`` thread
-    the pairwise conflict state across ticks: the fused O(n²·w) launch
-    is skipped whenever the packed set words and valid mask match the
-    carried tick's (see ``TickCarry``)."""
+         return_carry: bool = False, keys: bool = False) -> TickResult:
+    """One admission tick.  ``keys=True`` takes the sets as key-id
+    lists (``int32[n, kr]`` reads, ``int32[n, kw]`` writes, negative
+    ids are pads) instead of bool masks or packed words.  For ppcc,
+    ``carry``/``return_carry`` thread the pairwise conflict state
+    across ticks: the fused O(n²) launch is skipped whenever the set
+    operands and valid mask match the carried tick's (see
+    ``TickCarry``)."""
     if policy == "ppcc":
         return ppcc_tick(read_sets, write_sets, valid, order=order,
                          words=words, carry=carry,
-                         return_carry=return_carry)
+                         return_carry=return_carry, keys=keys)
     if order != "priority":
         raise ValueError(
             f"order={order!r} is only supported for policy='ppcc'")
     if carry is not None or return_carry:
         raise ValueError("carried conflict state is ppcc-only")
-    return POLICIES[policy](read_sets, write_sets, valid, words=words)
+    return POLICIES[policy](read_sets, write_sets, valid, words=words,
+                            keys=keys)

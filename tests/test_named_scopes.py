@@ -115,3 +115,31 @@ def test_ppcc_loop_runs_under_its_scopes(texts):
                     (name, op_name)
                 phases |= set(re.findall(r"cohort\.[a-z_]+", op_name))
     assert phases == PHASES
+
+
+TICK_SCOPES = {"tick.conflict", "tick.scan", "tick.commit_order"}
+
+
+def tick_text() -> str:
+    """The keyed PPCC tick at 32 rows of 4-key lists, compiled."""
+    from repro.sched import scheduler
+    keys = jax.ShapeDtypeStruct((32, 4), jnp.int32)
+    valid = jax.ShapeDtypeStruct((32,), jnp.bool_)
+    fn = jax.jit(lambda r, w, v: scheduler.ppcc_tick(r, w, v, keys=True))
+    return fn.lower(keys, keys, valid).compile().as_text()
+
+
+def test_tick_scopes_change_only_metadata():
+    """``ppcc_tick``'s three parts run under ``tick.conflict``,
+    ``tick.scan`` (the admission scan's loop) and ``tick.commit_order``;
+    without the scopes the program is the same."""
+    with_scopes = tick_text()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+        without = tick_text()
+    assert strip(with_scopes) == strip(without)
+    names = set(OP_NAME.findall(with_scopes))
+    seen = {s for s in TICK_SCOPES if any(f"/{s}/" in n for n in names)}
+    assert seen == TICK_SCOPES
+    assert any(n.endswith("tick.scan/while") for n in names)
+    assert not any(s in without for s in TICK_SCOPES)

@@ -1,0 +1,134 @@
+"""Transactions as key lists: the ``conflict_keys`` kernel against its
+jnp oracle, and ``tick(..., keys=True)`` against the same sets held as
+dense masks, for every policy, both orders, the carry and
+``tick_stats``.  Interpret mode on the CPU, at small sizes."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.kernels import ops, ref
+from repro.sched import scheduler
+
+
+def key_batch(seed, n, kr=8, kw=6, space=120, p_pad=0.2):
+    """Key lists with duplicate keys across transactions, some pads in
+    both lists and one row of pads only; ``int32[n, kr]``,
+    ``int32[n, kw]``."""
+    rng = np.random.default_rng(seed)
+    rk = rng.integers(0, space, (n, kr)).astype(np.int32)
+    wk = np.where(rng.random((n, kw)) < 0.5, rk[:, :kw],
+                  rng.integers(0, space, (n, kw))).astype(np.int32)
+    rk[rng.random((n, kr)) < p_pad] = -1
+    wk[rng.random((n, kw)) < p_pad] = -7
+    rk[n // 2], wk[n // 2] = -1, -1
+    return rk, wk
+
+
+def dense(keys, space=120):
+    """bool[n, space]: the key lists as set masks (pads dropped)."""
+    out = np.zeros((keys.shape[0], space), bool)
+    for i, row in enumerate(keys):
+        out[i, row[row >= 0]] = True
+    return out
+
+
+@pytest.mark.parametrize("n", [64, 512])
+def test_conflict_keys_matches_oracle(n):
+    rk, wk = key_batch(n, n)
+    got = ops.conflict_keys(jnp.asarray(rk), jnp.asarray(wk))
+    want = ref.conflict_keys_ref(jnp.asarray(rk), jnp.asarray(wk))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    # the same relations as the dense fused kernel on the same sets
+    rb = ops.pack_bitsets(jnp.asarray(dense(rk)))
+    wb = ops.pack_bitsets(jnp.asarray(dense(wk)))
+    for g, w in zip(got, ref.conflict_fused_full_ref(rb, wb)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert 0 < int(got[0].sum()) < n * n
+
+
+def test_pads_match_nothing():
+    """Two transactions of pads only, and pads against real keys: no
+    conflict, no degree, no diagonal, whatever negative id pads."""
+    rk = np.array([[-1, -1], [-1, -3], [5, -1], [-1, 5]], np.int32)
+    wk = np.array([[-1, -1], [-1, -2], [-2, 5], [-1, -1]], np.int32)
+    raw, ww, rdeg, war, wwdeg, dr, dw = (
+        np.asarray(x) for x in ops.conflict_keys(jnp.asarray(rk),
+                                                 jnp.asarray(wk)))
+    expect_raw = np.zeros((4, 4), bool)
+    expect_raw[2, 2] = expect_raw[3, 2] = True
+    np.testing.assert_array_equal(raw, expect_raw)
+    np.testing.assert_array_equal(ww, np.eye(4, dtype=bool) & (
+        np.arange(4) == 2))
+    np.testing.assert_array_equal(rdeg, [0, 0, 1, 1])
+    np.testing.assert_array_equal(war, [0, 0, 2, 0])
+    np.testing.assert_array_equal(wwdeg, [0, 0, 1, 0])
+    np.testing.assert_array_equal(dr, [False, False, True, False])
+    np.testing.assert_array_equal(dw, [False, False, True, False])
+
+
+CASES = [("ppcc", "priority"), ("ppcc", "degree"), ("2pl", "priority"),
+         ("occ", "priority")]
+
+
+def same(a, b):
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("policy,order", CASES)
+@pytest.mark.parametrize("seed", [0, 1])
+def test_keyed_tick_equals_dense_tick(policy, order, seed):
+    n = 64
+    rk, wk = key_batch(seed, n)
+    valid = jnp.asarray(np.random.default_rng(seed).random(n) < 0.9)
+    keyed = scheduler.tick(jnp.asarray(rk), jnp.asarray(wk), valid,
+                           policy=policy, order=order, keys=True)
+    plain = scheduler.tick(jnp.asarray(dense(rk)), jnp.asarray(dense(wk)),
+                           valid, policy=policy, order=order)
+    same(keyed, plain)
+    assert 0 < int(keyed.admitted.sum()) < int(valid.sum())
+    oracle = scheduler.POLICIES[policy]
+    kw = {"order": order} if policy == "ppcc" else {}
+    same(keyed, oracle(jnp.asarray(rk), jnp.asarray(wk), valid,
+                       use_kernel=False, keys=True, **kw))
+
+
+@pytest.mark.parametrize("order", ["priority", "degree"])
+def test_keyed_carry_reuse_and_invalidation(order):
+    n = 64
+    rk, wk = (jnp.asarray(a) for a in key_batch(3, n))
+    v = jnp.ones(n, bool)
+    base = scheduler.tick(rk, wk, v, order=order, keys=True)
+    res1, c1 = scheduler.tick(rk, wk, v, order=order, return_carry=True,
+                              keys=True)
+    res2 = scheduler.tick(rk, wk, v, order=order, carry=c1, keys=True)
+    same(base, res1)
+    same(base, res2)
+    rk3 = rk.at[0].set(rk[5])
+    fresh = scheduler.tick(rk3, wk, v, order=order, keys=True)
+    same(fresh, scheduler.tick(rk3, wk, v, order=order, carry=c1,
+                               keys=True))
+    dense_res = scheduler.tick(jnp.asarray(dense(np.asarray(rk3))),
+                               jnp.asarray(dense(np.asarray(wk))), v,
+                               order=order)
+    same(fresh, dense_res)
+
+
+def test_keyed_tick_stats_equal_dense():
+    n = 64
+    rk, wk = key_batch(4, n)
+    v = jnp.ones(n, bool)
+    r, w = jnp.asarray(dense(rk)), jnp.asarray(dense(wk))
+    res = scheduler.tick(r, w, v)
+    keyed = scheduler.tick_stats(jnp.asarray(rk), jnp.asarray(wk), v, res,
+                                 keys=True)
+    assert keyed == scheduler.tick_stats(r, w, v, res)
+    assert keyed["degree_max"] > 0
+
+
+def test_keys_refuse_a_word_bucket():
+    rk, wk = (jnp.asarray(a) for a in key_batch(0, 8))
+    with pytest.raises(ValueError):
+        scheduler.tick(rk, wk, jnp.ones(8, bool), words=4, keys=True)

@@ -6,7 +6,8 @@ tiling, in-kernel gathers, dynamic slices of values, selects on bools —
 so each kernel is lowered here with ``interpret=False`` at the size the
 main path runs it: the cohort-step megakernel and the dirty-row slab
 kernel at the fleet's 160 slots x 16 words, the scheduler's conflict
-kernels at 256 transactions x 32 words.  Nothing runs; a refusal raises.
+kernels at 256 transactions x 32 words and at a 1 024-row backlog (16
+words, or 16-key lists).  Nothing runs; a refusal raises.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and every test
@@ -26,6 +27,7 @@ from repro.kernels import megastep as MS
 
 N_SLOTS, SLOT_WORDS, LANES, SLAB = 160, 16, 4, 40
 N_TXN, TXN_WORDS = 256, 32
+BACKLOG, KEYS = 1024, 16
 
 
 @pytest.fixture(scope="module")
@@ -113,6 +115,24 @@ def test_conflict_kernels_compile(one_chip, kernel):
     shape = ((N_TXN, TXN_WORDS), U32)
     hlo = _compile(fn, one_chip, shape, shape)
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("kernel,dtype", [("conflict_fused", U32),
+                                          ("conflict_fused_full", U32),
+                                          ("conflict_keys", I32)])
+def test_conflict_kernels_compile_past_one_block(one_chip, kernel, dtype):
+    """A 4 x 4 grid of 256-row blocks: the per-row degree outputs are
+    ``(256, 1)`` blocks of ``int32[N, 1]`` (a ``(256,)`` block clashes
+    with XLA's T(1024) layout of an ``int32[1024]``).  The keyed
+    kernel's custom call carries its name, which the benchmark's trace
+    reduction finds."""
+    fn = lambda r, w: getattr(KC, kernel)(r, w, interpret=False)  # noqa: E731
+    shape = ((BACKLOG, KEYS), dtype)
+    hlo = _compile(fn, one_chip, shape, shape)
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert calls
+    if kernel == "conflict_keys":
+        assert all(ln.strip().startswith("%conflict_keys") for ln in calls)
 
 
 def test_reserve_scan_body_has_no_gather_or_scatter(one_chip):
