@@ -180,6 +180,11 @@ def ppcc_tick(read_sets: jax.Array, write_sets: jax.Array,
     The three parts run under the named scopes ``tick.conflict`` (the
     pairwise launch and the admission order), ``tick.scan`` (the
     rule, one step per transaction) and ``tick.commit_order``.
+
+    The scan carries only the three ``bool[n]`` vectors.  ``state.prec``
+    is formed once after it, as ``raw`` (diagonal off) restricted to
+    admitted pairs.  That is exact: each row is visited once, and an
+    arc is stored only between admitted rows, at the later one's step.
     """
     n = read_sets.shape[0]
     with jax.named_scope("tick.conflict"):
@@ -219,22 +224,21 @@ def ppcc_tick(read_sets: jax.Array, write_sets: jax.Array,
         raw = raw & ~jnp.eye(n, dtype=bool)      # self-RAW is not a conflict
 
     def step(carry, i):
-        admitted, preceding, preceded, prec = carry
+        admitted, preceding, preceded = carry
         r_i = raw[i] & admitted                      # i -> j arcs (RAW)
         w_i = raw[:, i] & admitted                   # k -> i arcs (WAR)
         ok = valid[i] & _prudent(r_i, w_i, preceding, preceded)
         admitted = admitted.at[i].set(ok)
         preceding = preceding.at[i].set(ok & r_i.any()) | (w_i & ok)
         preceded = preceded.at[i].set(ok & w_i.any()) | (r_i & ok)
-        prec = prec.at[i, :].set(jnp.where(ok, r_i, prec[i, :]))
-        prec = prec.at[:, i].set(jnp.where(ok, w_i, prec[:, i]))
-        return (admitted, preceding, preceded, prec), ok
+        return (admitted, preceding, preceded), ok
 
     with jax.named_scope("tick.scan"):
-        init = (jnp.zeros(n, bool), jnp.zeros(n, bool), jnp.zeros(n, bool),
-                jnp.zeros((n, n), bool))
-        (admitted, preceding, preceded, prec), _ = jax.lax.scan(
-            step, init, seq)
+        init = (jnp.zeros(n, bool), jnp.zeros(n, bool), jnp.zeros(n, bool))
+        (admitted, preceding, preceded), _ = jax.lax.scan(step, init, seq)
+        # exact: ``seq`` visits each row once, and a step stores an arc
+        # only between its row, if admitted, and rows admitted before it
+        prec = raw & admitted[:, None] & admitted[None, :]
     with jax.named_scope("tick.commit_order"):
         # preceding-class (readers) first
         rank_key = jnp.where(admitted, preceded.astype(jnp.int32), 2 ** 30)

@@ -1,7 +1,8 @@
 """Transactions as key lists: the ``conflict_keys`` kernel against its
 jnp oracle, and ``tick(..., keys=True)`` against the same sets held as
 dense masks, for every policy, both orders, the carry and
-``tick_stats``.  Interpret mode on the CPU, at small sizes."""
+``tick_stats``; PPCC's tick against its form that carried ``prec``
+through the scan.  Interpret mode on the CPU, at small sizes."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -132,3 +133,83 @@ def test_keys_refuse_a_word_bucket():
     rk, wk = (jnp.asarray(a) for a in key_batch(0, 8))
     with pytest.raises(ValueError):
         scheduler.tick(rk, wk, jnp.ones(8, bool), words=4, keys=True)
+
+
+def carry_form_ppcc(rs, ws, valid, order, keys):
+    """PPCC's tick as it was before ``prec`` left the scan: the n x n
+    matrix carried through every step, written by row and by column.
+    ``(admitted, commit_rank, prec, preceding, preceded)``."""
+    n = rs.shape[0]
+    raw, _, raw_deg, war_deg, ww_deg, diag_raw, diag_ww = (
+        scheduler._conflict_matrices(rs, ws, False, keys, full=True))
+    if order == "degree":
+        self_r = diag_raw.astype(jnp.int32)
+        deg = (raw_deg - self_r + war_deg - self_r
+               + ww_deg - diag_ww.astype(jnp.int32))
+        seq = jnp.argsort(deg, stable=True).astype(jnp.int32)
+    else:
+        seq = jnp.arange(n, dtype=jnp.int32)
+    raw = raw & ~jnp.eye(n, dtype=bool)
+
+    def step(carry, i):
+        admitted, preceding, preceded, prec = carry
+        r_i = raw[i] & admitted
+        w_i = raw[:, i] & admitted
+        ok = valid[i] & scheduler._prudent(r_i, w_i, preceding, preceded)
+        admitted = admitted.at[i].set(ok)
+        preceding = preceding.at[i].set(ok & r_i.any()) | (w_i & ok)
+        preceded = preceded.at[i].set(ok & w_i.any()) | (r_i & ok)
+        prec = prec.at[i, :].set(jnp.where(ok, r_i, prec[i, :]))
+        prec = prec.at[:, i].set(jnp.where(ok, w_i, prec[:, i]))
+        return (admitted, preceding, preceded, prec), ok
+
+    init = (jnp.zeros(n, bool), jnp.zeros(n, bool), jnp.zeros(n, bool),
+            jnp.zeros((n, n), bool))
+    (admitted, preceding, preceded, prec), _ = jax.lax.scan(step, init, seq)
+    rank_key = jnp.where(admitted, preceded.astype(jnp.int32), 2 ** 30)
+    commit_order = jnp.argsort(rank_key, stable=True)
+    rank = jnp.full((n,), -1, jnp.int32).at[commit_order].set(
+        jnp.arange(n, dtype=jnp.int32))
+    rank = jnp.where(admitted, rank, -1)
+    return admitted, rank, prec, preceding, preceded
+
+
+PREC_CASES = [  # order, set form, some rows invalid, through the carry
+    ("priority", "bool", False, False),
+    ("degree", "bool", False, False),
+    ("priority", "keys", False, False),
+    ("degree", "keys", False, False),
+    ("priority", "bool", True, False),
+    ("degree", "keys", True, False),
+    ("priority", "keys", False, True),
+    ("degree", "bool", True, True),
+]
+
+
+@pytest.mark.parametrize("order,form,invalid,carry", PREC_CASES)
+def test_prec_after_scan_equals_carried_prec(order, form, invalid, carry):
+    """``state.prec`` formed once after the scan equals the matrix the
+    scan used to carry, bit for bit, and so do the admissions, the
+    commit ranks and both class vectors."""
+    n, keys = 64, form == "keys"
+    rk, wk = key_batch(11 + invalid + 2 * carry, n)
+    rs, ws = ((jnp.asarray(rk), jnp.asarray(wk)) if keys else
+              (jnp.asarray(dense(rk)), jnp.asarray(dense(wk))))
+    valid = jnp.asarray(np.random.default_rng(n).random(n) < 0.8
+                        if invalid else np.ones(n, bool))
+    want = carry_form_ppcc(*scheduler._operands(rs, ws, None, keys), valid,
+                           order, keys)
+
+    def tick(**kw):
+        return scheduler.ppcc_tick(rs, ws, valid, use_kernel=False,
+                                   order=order, keys=keys, **kw)
+
+    results = [tick()]
+    if carry:
+        first, c = tick(return_carry=True)
+        results += [first, tick(carry=c)]
+    for res in results:
+        s = res.state
+        same((res.admitted, res.commit_rank, s.prec, s.preceding,
+              s.preceded, s.active), want + (want[0],))
+    assert bool(want[2].any()) and 0 < int(want[0].sum()) < int(valid.sum())

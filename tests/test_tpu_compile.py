@@ -7,7 +7,8 @@ so each kernel is lowered here with ``interpret=False`` at the size the
 main path runs it: the cohort-step megakernel and the dirty-row slab
 kernel at the fleet's 160 slots x 16 words, the scheduler's conflict
 kernels at 256 transactions x 32 words and at a 1 024-row backlog (16
-words, or 16-key lists).  Nothing runs; a refusal raises.
+words, or 16-key lists), and PPCC's whole tick on those key lists.
+Nothing runs; a refusal raises.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and every test
@@ -24,6 +25,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import jaxsim
 from repro.kernels import conflict as KC
 from repro.kernels import megastep as MS
+from repro.kernels import ops as kops
+from repro.sched import scheduler
 
 N_SLOTS, SLOT_WORDS, LANES, SLAB = 160, 16, 4, 40
 N_TXN, TXN_WORDS = 256, 32
@@ -150,3 +153,24 @@ def test_reserve_scan_body_has_no_gather_or_scatter(one_chip):
     for body in bodies:
         ops = " ".join(_called(comps, body))
         assert "scatter(" not in ops and "gather(" not in ops, body
+
+
+def test_tick_scan_body_has_no_square_copy_or_update(one_chip,
+                                                     monkeypatch):
+    """PPCC's admission scan (``ppcc_tick`` on 16-key lists over a
+    1 024-row backlog, the keyed kernel compiled as on the chip) carries
+    only ``bool[n]`` vectors: an n x n carry written by row and by
+    column is relaid out and updated whole, every step."""
+    monkeypatch.setattr(kops, "_interpret_default", lambda: False)
+    n = BACKLOG
+    fn = lambda r, w, v: scheduler.ppcc_tick(r, w, v, keys=True)  # noqa: E731
+    hlo = _compile(fn, one_chip, ((n, KEYS), I32), ((n, KEYS), I32),
+                   ((n,), BOOL))
+    comps = _computations(hlo)
+    bodies = re.findall(r" while\(.*?body=%([\w.\-]+).*tick\.scan", hlo)
+    assert bodies, "no while loop under tick.scan"
+    square = f"pred[{n},{n}]"
+    for body in bodies:
+        bad = [ln for ln in _called(comps, body) if square in ln
+               and (" copy(" in ln or " dynamic-update-slice(" in ln)]
+        assert not bad, (body, bad)
