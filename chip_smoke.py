@@ -18,7 +18,7 @@ Phases on one chip:
    fig 6 (100 items) lie within ``ORACLE_BAND`` of the ``pysim``
    event-heap oracle, the band ``tests/test_jaxsim_vs_pysim.py`` uses.
 2. ``megakernel`` — the fig-7 ppcc lanes through
-   ``jaxsim.engine_parts(fleet=True)`` with the megakernel on and off,
+   ``jaxsim.engine_parts`` with the megakernel on and off,
    vmapped as ``Fleet`` runs them: commits, aborts and iterations must
    be equal, and the default body must contain the compiled kernel.
 3. ``scheduler`` — ``scheduler.tick`` at 256 transactions over 1024
@@ -153,8 +153,8 @@ def phase_megakernel(dev: dict, mpls=MPLS, seeds=SEEDS,
                        jaxsim.rt_of(p))
 
     def parts(megakernel):
-        return jaxsim.engine_parts(p, "ppcc", n_slots=n_slots, fleet=True,
-                                   pool=pool, megakernel=megakernel)
+        return jaxsim.engine_parts(p, "ppcc", n_slots=n_slots, pool=pool,
+                                   megakernel=megakernel)
 
     # the default body must carry the kernel exactly where it compiles
     init, _, step = parts(None)

@@ -1,7 +1,7 @@
 """Persistent XLA compilation cache for the repo's entry points.
 
 ``enable()`` is called once by each entry point (``chip_smoke.py``,
-``benchmarks/run.py``, ``examples/*``) before its first compile.  Where
+``bench/run.py``, ``examples/*``) before its first compile.  Where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing is
 set here.  Otherwise the cache lives at a fixed path inside the checkout
 (``.jax_cache/``, git-ignored): the path is part of the cache key, so a

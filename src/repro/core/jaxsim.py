@@ -3,23 +3,19 @@
 The event-heap oracle (``pysim``) is a pointer-chasing CPU artifact; this
 module is the TPU-native reformulation (DESIGN.md §2): the entire
 simulator state is a fixed-shape pytree and a ``lax.while_loop``
-advances it via masked tensor updates.  Two step modes share that state:
-
-* ``cohort`` (default, DESIGN.md §2.3) — each iteration processes the
-  full *cohort* of ready slots: every slot whose ``next_time`` falls
-  inside the current time quantum ``[t_min, t_min + cohort_dt]``.  The
-  cohort is split by event kind and resolved with the batched protocol
-  primitives in ``repro.core.ppcc`` (``try_ops_batched`` over a
-  ``cohort_select``-ed independent subset, ``wc_acquire_many``,
-  ``commit_many`` / ``abort_many`` / ``begin_many``); non-independent
-  ops are deferred one iteration, so progress is guaranteed.
-* ``event`` — the seed engine: one iteration processes exactly one
-  event (``argmin`` over next-event times) via a ``lax.switch``.  Kept
-  as the before/after baseline and the parity target for tests.
+advances it via masked tensor updates (DESIGN.md §2.3).  Each iteration
+processes the full *cohort* of ready slots: every slot whose
+``next_time`` falls inside the current time quantum
+``[t_min, t_min + cohort_dt]``.  The cohort is split by event kind and
+resolved with the batched protocol primitives in ``repro.core.ppcc``
+(PPCC: one ``cohort_step_fused`` over the relations of
+``_relations``; then ``commit_many`` / ``abort_many`` /
+``begin_many``); non-independent ops are deferred one iteration, so
+progress is guaranteed.
 
 FCFS multi-server resource pools become ``free_at`` vectors: a request
 reserves ``argmin(free_at)`` at request time, which reproduces FCFS
-because events are processed in (quantised) time order; cohort mode
+because events are processed in (quantised) time order; the cohort
 reserves for all requesters in one slot-ordered ``lax.scan``.
 
 All three protocols run on the same tensor state:
@@ -52,10 +48,10 @@ exact-shape twin.  ``repro.core.sweep`` builds on this to run a whole
 (protocol × MPL × seed) figure grid — or ALL paper figures at once
 (``run_grid``) — as a single jitted fleet call, optionally
 shard_map-ed over the host (or multi-host pod) mesh.
-Fleet engines (``fleet=True``) drop the quiet-iteration ``lax.cond``
-gates (under vmap they decay to select-both-branches) and draw fresh
-transactions from a pre-sampled pool (``pool > 0``) instead of calling
-``sample_txns`` in-loop.
+The body has no ``lax.cond`` gates (under the fleet's vmap a cond
+decays to computing both branches plus a select), and fleet engines
+draw fresh transactions from a pre-sampled pool (``pool > 0``) instead
+of calling ``sample_txns`` in-loop.
 
 Semantics are validated statistically against the oracle in
 ``tests/test_jaxsim_vs_pysim.py`` (same model, different tie-breaking).
@@ -148,10 +144,6 @@ class EngState(NamedTuple):
     pool_items: jax.Array        # int32[P, L]
     pool_next: jax.Array         # int32 next pool row to hand out
     rt: RtParams                 # runtime workload axes (loop-invariant)
-    rel: P.Relations             # carried (n,n) relation tables when
-                                 # EngCfg.delta (else (0,0) placeholders);
-                                 # invariant: equals compute_relations of
-                                 # pstate + this iteration's op cursor
     tm: M.Telemetry              # telemetry accumulators when
                                  # EngCfg.telemetry (else 0-size
                                  # placeholders, same pytree structure)
@@ -180,34 +172,14 @@ class EngCfg:
     horizon: float
     max_iters: int
     cohort_dt: float = 0.0       # time-quantum width for cohort stepping
-    fleet: bool = False          # body will run under vmap lanes: drop the
-                                 # quiet-iteration lax.cond gates (they decay
-                                 # to full-state selects under batching)
     pool: int = 0                # >0: pre-sample this many transactions at
                                  # init and pop on commit instead of calling
                                  # sample_txns per iteration (fleet hot-path:
                                  # in-loop sampling was ~2/3 of body cost)
-    fused: bool = True           # ppcc: one fused cohort step (conflict →
-                                 # select → verdicts → wc) per iteration
-                                 # instead of the multipass chain; both
-                                 # paths are bit-identical (DESIGN.md §3)
-    order: str = "index"         # fused selection priority: "index" (the
-                                 # multipass-identical default) | "degree"
-    megakernel: bool = False     # fused relations from the Pallas
+    megakernel: bool = False     # ppcc relations from the Pallas
                                  # cohort-step megakernel (one launch per
-                                 # quantum); compiled path — real
-                                 # accelerators only, CPU keeps the
-                                 # bit-identical jnp twin
-    delta: bool = False          # ppcc+fused: carry the (n,n) relation
-                                 # tables in the loop state and update
-                                 # only the dirty rows per iteration via
-                                 # the row-slab kernel (DESIGN.md §3.2);
-                                 # bit-identical to full recompute
-    delta_k: int = 0             # dirty-row slab capacity (static); a
-                                 # non-fleet step falls back to full
-                                 # recompute past it, a fleet step loops
-                                 # K-sized chunks until the dirty set is
-                                 # drained
+                                 # quantum); compiled path — TPU only,
+                                 # elsewhere the bit-identical jnp twin
     telemetry: bool = False      # carry obs.metrics accumulators in the
                                  # loop state (DESIGN.md §8); off keeps
                                  # 0-size placeholder leaves so results
@@ -378,85 +350,15 @@ def _reserve(free: jax.Array, now: jax.Array, dur: jax.Array
     return free.at[idx].set(done), done
 
 
-# --------------------------------------------------------------------------
-# protocol adapters
-# --------------------------------------------------------------------------
-
-def _try_op(cfg: EngCfg, s: EngState, i, x, is_write
-            ) -> Tuple[EngState, jax.Array]:
-    ps = s.pstate
-    if cfg.protocol == "ppcc":
-        ps2, verdict = P.try_op(ps, i, x, is_write)
-        return s._replace(pstate=ps2), verdict
-    if cfg.protocol == "2pl":
-        others = ps.active & (jnp.arange(cfg.n) != i)
-        x_held = (B.get_col(ps.write_set, x) & others).any()
-        s_held = (B.get_col(ps.read_set, x) & others).any()
-        ok = jnp.where(is_write, ~x_held & ~s_held, ~x_held)
-        rs = B.set_bit(ps.read_set, i, x, ok & ~is_write)
-        ws = B.set_bit(ps.write_set, i, x, ok & is_write)
-        verdict = jnp.where(ok, P.PROCEED, P.BLOCK)
-        return s._replace(pstate=ps._replace(read_set=rs, write_set=ws)), \
-            verdict
-    # occ: never blocks
-    rs = B.set_bit(ps.read_set, i, x, ~is_write)
-    ws = B.set_bit(ps.write_set, i, x, is_write)
-    return s._replace(pstate=ps._replace(read_set=rs, write_set=ws)), \
-        jnp.int32(P.PROCEED)
-
-
-def _read_done(cfg: EngCfg, s: EngState, i) -> Tuple[EngState, jax.Array]:
-    """Returns code 0=flush, 1=wait(lock), 2=wait(prec), 3=abort."""
-    ps = s.pstate
-    if cfg.protocol == "ppcc":
-        ps2, got = P.wc_acquire_locks(ps, i)
-        can = P.can_commit(ps2, i)
-        code = jnp.where(~got, 1, jnp.where(can, 0, 2))
-        ps3 = jax.tree.map(lambda a, b: jnp.where(got, a, b), ps2, ps)
-        return s._replace(pstate=ps3), code
-    if cfg.protocol == "2pl":
-        return s, jnp.int32(0)
-    fail = B.overlap_rows(ps.read_set[i], s.dirty[i])
-    return s, jnp.where(fail, 3, 0)
-
-
-def _on_commit(cfg: EngCfg, s: EngState, i) -> EngState:
-    ps = s.pstate
-    if cfg.protocol == "occ":
-        # broadcast write set into every active transaction's dirty map
-        others = ps.active & (jnp.arange(cfg.n) != i)
-        dirty = jnp.where(others[:, None],
-                          s.dirty | ps.write_set[i][None, :], s.dirty)
-        dirty = dirty.at[i].set(jnp.uint32(0))
-        s = s._replace(dirty=dirty)
-    return s._replace(pstate=P.commit(ps, i))
-
-
-def _on_abort(cfg: EngCfg, s: EngState, i) -> EngState:
-    s = s._replace(dirty=s.dirty.at[i].set(jnp.uint32(0)))
-    return s._replace(pstate=P.abort(s.pstate, i))
-
-
-# --------------------------------------------------------------------------
-# engine
-# --------------------------------------------------------------------------
-
-def _wake_waiters(s: EngState) -> EngState:
-    waiting = (s.phase == PH_BLOCKED) | (s.phase == PH_WC_LOCK) | \
-        (s.phase == PH_WC_PREC)
-    return s._replace(next_time=jnp.where(waiting, s.now, s.next_time))
-
-
-def _begin_txn(cfg: EngCfg, s: EngState, i, fresh: jax.Array) -> EngState:
-    """(Re)start slot i: fresh -> sample new ops; else reuse (restart)."""
+def _begin_txn(cfg: EngCfg, s: EngState, i) -> EngState:
+    """Start slot i on a freshly sampled transaction (``init`` only; the
+    cohort body begins slots in bulk)."""
     key, k1, k2 = jax.random.split(s.key, 3)
     kinds_i, items_i = sample_txn(k1, cfg, s.rt)
-    new_kinds = jnp.where(fresh, kinds_i, s.kinds[i])
-    new_items = jnp.where(fresh, items_i, s.items[i])
     s = s._replace(
         key=key,
-        kinds=s.kinds.at[i].set(new_kinds),
-        items=s.items.at[i].set(new_items),
+        kinds=s.kinds.at[i].set(kinds_i),
+        items=s.items.at[i].set(items_i),
         op_idx=s.op_idx.at[i].set(0),
         pstate=P.begin(s.pstate, i),
         phase=s.phase.at[i].set(PH_READ),
@@ -468,195 +370,6 @@ def _begin_txn(cfg: EngCfg, s: EngState, i, fresh: jax.Array) -> EngState:
         cpu_free=cpu_free,
         next_time=s.next_time.at[i].set(done),
         next_kind=s.next_kind.at[i].set(EV_ATTEMPT))
-
-
-def _ev_attempt(cfg: EngCfg, s: EngState, i) -> EngState:
-    """CPU burst done (or waiter woken): run the protocol on current op."""
-    done_reading = s.op_idx[i] >= (s.kinds[i] >= 0).sum()
-    in_wc = (s.phase[i] == PH_WC_LOCK) | (s.phase[i] == PH_WC_PREC)
-
-    def read_phase(s: EngState) -> EngState:
-        x = s.items[i, s.op_idx[i]]
-        is_write = s.kinds[i, s.op_idx[i]] == 1
-        s2, verdict = _try_op(cfg, s, i, x, is_write)
-        proceed = verdict == P.PROCEED
-        block = verdict == P.BLOCK
-        key, k1, k2 = jax.random.split(s2.key, 3)
-        s2 = s2._replace(key=key)
-        # --- proceed ---
-        op2 = jnp.where(proceed, s.op_idx[i] + 1, s.op_idx[i])
-        was_last = op2 >= (s.kinds[i] >= 0).sum()
-        s2 = s2._replace(op_idx=s2.op_idx.at[i].set(op2),
-                         ops_done=s2.ops_done + proceed)
-        # reads pay a disk access; writes go straight to the next CPU burst
-        dur_io = _uniform(k1, cfg.io_mean, cfg.io_spread)
-        dur_cpu = _uniform(k2, cfg.cpu_mean, cfg.cpu_spread)
-
-        def do_proceed(s2: EngState) -> EngState:
-            def do_read(s3):
-                disk_free, done = _reserve(s3.disk_free, s3.now, dur_io)
-                return s3._replace(
-                    disk_free=disk_free,
-                    next_time=s3.next_time.at[i].set(done),
-                    next_kind=s3.next_kind.at[i].set(EV_DISK_DONE),
-                    phase=s3.phase.at[i].set(PH_READ))
-
-            def do_write(s3):
-                # last op: enter wait-to-commit immediately (no extra CPU
-                # burst), matching the oracle's transition
-                def sched_cpu(s4):
-                    cpu_free, done = _reserve(s4.cpu_free, s4.now, dur_cpu)
-                    return s4._replace(
-                        cpu_free=cpu_free,
-                        next_time=s4.next_time.at[i].set(done),
-                        next_kind=s4.next_kind.at[i].set(EV_ATTEMPT),
-                        phase=s4.phase.at[i].set(PH_READ))
-
-                def to_wc(s4):
-                    return s4._replace(
-                        next_time=s4.next_time.at[i].set(s4.now),
-                        next_kind=s4.next_kind.at[i].set(EV_ATTEMPT),
-                        phase=s4.phase.at[i].set(PH_READ))
-                return jax.lax.cond(was_last, to_wc, sched_cpu, s3)
-            return jax.lax.cond(is_write, do_write, do_read, s2)
-
-        def do_block(s2: EngState) -> EngState:
-            was_blocked = s.phase[i] == PH_BLOCKED
-            new_deadline = jnp.where(was_blocked, s.deadline[i],
-                                     s.now + cfg.block_timeout)
-            return s2._replace(
-                phase=s2.phase.at[i].set(PH_BLOCKED),
-                deadline=s2.deadline.at[i].set(new_deadline),
-                next_time=s2.next_time.at[i].set(new_deadline),
-                next_kind=s2.next_kind.at[i].set(EV_TIMEOUT),
-                blocks=s2.blocks + jnp.where(was_blocked, 0, 1))
-
-        def do_abort(s2: EngState) -> EngState:
-            return _abort(cfg, s2, i)
-
-        return jax.lax.cond(
-            proceed, do_proceed,
-            lambda s_: jax.lax.cond(block, do_block, do_abort, s_), s2)
-
-    def wc_phase(s: EngState) -> EngState:
-        s2, code = _read_done(cfg, s, i)
-
-        def flush(s3: EngState) -> EngState:
-            n_w = B.popcount(s3.pstate.write_set[i])
-            s3 = s3._replace(flush_left=s3.flush_left.at[i].set(n_w),
-                             phase=s3.phase.at[i].set(PH_FLUSH))
-            return jax.lax.cond(n_w > 0, _flush_one,
-                                lambda s4: _commit(cfg, s4, i), s3)
-
-        def wait_lock(s3: EngState) -> EngState:
-            first = s.phase[i] != PH_WC_LOCK
-            new_deadline = jnp.where(first, s3.now + cfg.block_timeout,
-                                     s3.deadline[i])
-            return s3._replace(
-                phase=s3.phase.at[i].set(PH_WC_LOCK),
-                deadline=s3.deadline.at[i].set(new_deadline),
-                next_time=s3.next_time.at[i].set(new_deadline),
-                next_kind=s3.next_kind.at[i].set(EV_TIMEOUT))
-
-        def wait_prec(s3: EngState) -> EngState:
-            return s3._replace(
-                phase=s3.phase.at[i].set(PH_WC_PREC),
-                next_time=s3.next_time.at[i].set(INF),
-                next_kind=s3.next_kind.at[i].set(EV_ATTEMPT))
-
-        def _flush_one(s3: EngState) -> EngState:
-            key, k1 = jax.random.split(s3.key)
-            disk_free, done = _reserve(
-                s3.disk_free, s3.now, _uniform(k1, cfg.io_mean,
-                                               cfg.io_spread))
-            return s3._replace(
-                key=key, disk_free=disk_free,
-                next_time=s3.next_time.at[i].set(done),
-                next_kind=s3.next_kind.at[i].set(EV_FLUSH_DONE))
-
-        return jax.lax.switch(
-            code, [flush, wait_lock, wait_prec,
-                   lambda s3: _abort(cfg, s3, i)], s2)
-
-    return jax.lax.cond(done_reading | in_wc, wc_phase, read_phase, s)
-
-
-def _ev_disk_done(cfg: EngCfg, s: EngState, i) -> EngState:
-    key, k1 = jax.random.split(s.key)
-    s = s._replace(key=key)
-    done_reading = s.op_idx[i] >= (s.kinds[i] >= 0).sum()
-
-    def to_wc(s2):                      # last read done -> wait-to-commit
-        return s2._replace(
-            next_time=s2.next_time.at[i].set(s2.now),
-            next_kind=s2.next_kind.at[i].set(EV_ATTEMPT))
-
-    def sched_cpu(s2):
-        cpu_free, done = _reserve(
-            s2.cpu_free, s2.now, _uniform(k1, cfg.cpu_mean,
-                                          cfg.cpu_spread))
-        return s2._replace(
-            cpu_free=cpu_free,
-            next_time=s2.next_time.at[i].set(done),
-            next_kind=s2.next_kind.at[i].set(EV_ATTEMPT))
-    return jax.lax.cond(done_reading, to_wc, sched_cpu, s)
-
-
-def _ev_flush_done(cfg: EngCfg, s: EngState, i) -> EngState:
-    left = s.flush_left[i] - 1
-    s = s._replace(flush_left=s.flush_left.at[i].set(left))
-
-    def more(s2):
-        key, k1 = jax.random.split(s2.key)
-        disk_free, done = _reserve(
-            s2.disk_free, s2.now, _uniform(k1, cfg.io_mean, cfg.io_spread))
-        return s2._replace(key=key, disk_free=disk_free,
-                           next_time=s2.next_time.at[i].set(done),
-                           next_kind=s2.next_kind.at[i].set(EV_FLUSH_DONE))
-    return jax.lax.cond(left > 0, more,
-                        lambda s2: _commit(cfg, s2, i), s)
-
-
-def _commit(cfg: EngCfg, s: EngState, i) -> EngState:
-    if cfg.protocol == "occ":
-        # close the Kung-Robinson overlap window: re-validate at commit
-        fail = B.overlap_rows(s.pstate.read_set[i], s.dirty[i])
-
-        def ok(s2):
-            return _commit_body(cfg, s2, i)
-        return jax.lax.cond(fail, lambda s2: _abort(cfg, s2, i), ok, s)
-    return _commit_body(cfg, s, i)
-
-
-def _commit_body(cfg: EngCfg, s: EngState, i) -> EngState:
-    s = _on_commit(cfg, s, i)
-    s = s._replace(commits=s.commits + 1)
-    s = _wake_waiters(s)
-    return _begin_txn(cfg, s, i, fresh=jnp.bool_(True))
-
-
-def _abort(cfg: EngCfg, s: EngState, i) -> EngState:
-    s = _on_abort(cfg, s, i)
-    key, k1 = jax.random.split(s.key)
-    delay = jax.random.uniform(k1, (), minval=0.5 * cfg.restart_mean,
-                               maxval=1.5 * cfg.restart_mean)
-    s = _wake_waiters(s._replace(key=key, aborts=s.aborts + 1))
-    return s._replace(
-        phase=s.phase.at[i].set(PH_RESTART),
-        next_time=s.next_time.at[i].set(s.now + delay),
-        next_kind=s.next_kind.at[i].set(EV_RESTART))
-
-
-def _ev_timeout(cfg: EngCfg, s: EngState, i) -> EngState:
-    still = (s.phase[i] == PH_BLOCKED) | (s.phase[i] == PH_WC_LOCK)
-    expired = s.now >= s.deadline[i]
-    return jax.lax.cond(still & expired,
-                        lambda s2: _abort(cfg, s2, i),
-                        lambda s2: _ev_attempt(cfg, s2, i), s)
-
-
-def _ev_restart(cfg: EngCfg, s: EngState, i) -> EngState:
-    return _begin_txn(cfg, s, i, fresh=jnp.bool_(False))
 
 
 # --------------------------------------------------------------------------
@@ -700,20 +413,18 @@ def _try_ops_cohort(cfg: EngCfg, ps: P.PPCCState, item: jax.Array,
                     is_write: jax.Array, ready: jax.Array
                     ) -> Tuple[P.PPCCState, jax.Array, jax.Array,
                                jax.Array]:
-    """Batched read-phase protocol step over a cohort of pending ops.
+    """Batched read-phase step of 2PL or OCC over a cohort of pending ops
+    (PPCC's is ``ppcc.cohort_step_fused``).
 
-    Selects a pairwise-independent subset of ``ready`` (protocol
-    dependent), resolves it in one vectorized step, and returns
-    (state, verdict[n], selected[n], block-reason[n]).  Deferred
-    (ready & ~selected) slots are retried next iteration.  Reason codes
-    are ``ppcc.R_LOCK`` / ``ppcc.R_RULE`` on BLOCK lanes (every 2PL
-    block is a lock wait; OCC never blocks).
+    Selects a pairwise-independent subset of ``ready``, resolves it in
+    one vectorized step, and returns (state, verdict[n], selected[n],
+    block-reason[n]).  Deferred (ready & ~selected) slots are retried
+    next iteration.  Every 2PL block is a lock wait (``ppcc.R_LOCK``);
+    OCC never blocks.
     """
     n = ps.n
     idx = jnp.arange(n, dtype=jnp.int32)
     eye = jnp.eye(n, dtype=bool)
-    if cfg.protocol == "ppcc":
-        return P.cohort_step(ps, item, is_write, ready)
     if cfg.protocol == "2pl":
         # lock-table ops only interact when they target the same item
         # with a write involved; keep the lowest ready claimant per item.
@@ -742,83 +453,28 @@ def _try_ops_cohort(cfg: EngCfg, ps: P.PPCCState, item: jax.Array,
 
 def _wc_cohort(cfg: EngCfg, ps: P.PPCCState, dirty: jax.Array,
                wc_m: jax.Array):
-    """Batched wait-to-commit step.  Returns
+    """Batched wait-to-commit step of 2PL or OCC.  Returns
     (state, flush_m, wait_lock_m, wait_prec_m, abort_m)."""
     n = ps.n
     zeros = jnp.zeros(n, bool)
-    if cfg.protocol == "ppcc":
-        ps2, won = P.wc_acquire_many(ps, wc_m, exact=False)
-        can = P.can_commit_many(ps2)
-        flush_m = wc_m & won & can
-        wait_prec_m = wc_m & won & ~can
-        wait_lock_m = wc_m & ~won
-        return ps2, flush_m, wait_lock_m, wait_prec_m, zeros
     if cfg.protocol == "2pl":
         return ps, wc_m, zeros, zeros, zeros
     fail = B.overlap_rows(ps.read_set, dirty)
     return ps, wc_m & ~fail, zeros, zeros, wc_m & fail
 
 
-def _rowslab_rows(cfg: EngCfg, ps, rel, item, is_write, slab, valid):
-    """Dispatch the (K, n) row-slab kernel: Pallas launch on the
-    megakernel path, bit-identical jnp twin otherwise."""
+def _relations(cfg: EngCfg, ps: P.PPCCState, dirty: jax.Array,
+               item: jax.Array, is_write: jax.Array, ready: jax.Array):
+    """The pairwise relations ``ppcc.cohort_step_fused`` consumes: one
+    launch of the Pallas megakernel on the TPU, the bit-identical jnp
+    twin elsewhere (``tests/test_megastep.py``)."""
     if cfg.megakernel:
         from ..kernels import ops as kops
-        return kops.rowslab_relations(
-            ps.read_set, ps.write_set, rel.writers_at, rel.readers_at,
-            item, is_write, ps.active, slab, valid)
-    from ..kernels import conflict as kconf
-    return kconf.rowslab(
-        ps.read_set, ps.write_set, rel.writers_at, rel.readers_at,
-        item, is_write, ps.active, slab, valid)
-
-
-def _delta_update(cfg: EngCfg, s: EngState, ps5, cur_item, cur_w,
-                  new_kinds, new_items, op_new) -> "P.Relations":
-    """Delta-maintain the carried relation tables for the next
-    iteration's cursor (DESIGN.md §3.2): find the slots whose packed
-    words or op cursor changed, recompute only those (K, n) rows via
-    the row-slab kernel, and scatter rows + mirrored columns back.
-
-    Non-fleet bodies guard exactness with a ``lax.cond`` full-recompute
-    fallback on slab overflow.  Fleet bodies run under vmap, where a
-    cond decays into both branches + select — they instead drain the
-    dirty set K ids at a time in a ``while_loop``; later chunks'
-    mirrored column writes repair the stale dirty×dirty cross entries,
-    so the loop converges to the full recompute exactly."""
-    n = cfg.n
-    idx = jnp.arange(n, dtype=jnp.int32)
-    nxt_i = jnp.minimum(op_new, cfg.max_ops - 1)
-    nxt_item = new_items[idx, nxt_i]
-    nxt_w = new_kinds[idx, nxt_i] == jnp.int8(1)
-    dirty_m = P.dirty_slots(s.pstate, ps5, cur_item, nxt_item,
-                            cur_w, nxt_w)
-    k = cfg.delta_k
-
-    def slab_rows(rel, slab, valid):
-        rows = _rowslab_rows(cfg, ps5, rel, nxt_item, nxt_w, slab, valid)
-        return P.scatter_relations(rel, *rows, slab, valid)
-
-    if cfg.fleet:
-        ids = jnp.nonzero(dirty_m, size=n, fill_value=n)[0] \
-            .astype(jnp.int32)
-        m = dirty_m.sum(dtype=jnp.int32)
-
-        def body(carry):
-            rel, c = carry
-            slab = jax.lax.dynamic_slice_in_dim(ids, c * k, k)
-            return slab_rows(rel, slab, slab < n), c + 1
-
-        rel, _ = jax.lax.while_loop(
-            lambda carry: carry[1] * k < m, body, (s.rel, jnp.int32(0)))
-        return rel
-
-    slab, valid, cnt = P.dirty_slab(dirty_m, k)
-    return jax.lax.cond(
-        cnt > k,
-        lambda rel: P.compute_relations(ps5, nxt_item, nxt_w),
-        lambda rel: slab_rows(rel, slab, valid),
-        s.rel)
+        return kops.megastep_relations(
+            ps.read_set, ps.write_set, dirty, item, is_write, ps.active,
+            ready, ps.haslocks)
+    return P.relations_inputs(P.compute_relations(ps, item, is_write),
+                              ready, ps.haslocks)
 
 
 def _cohort_body(cfg: EngCfg, s: EngState) -> EngState:
@@ -836,7 +492,7 @@ def _cohort_body(cfg: EngCfg, s: EngState) -> EngState:
         s = s._replace(now=t0, iters=s.iters + 1)
 
         # per-iteration randomness (vector draws; streams differ from the
-        # one-event engine — parity is statistical, as with the oracle)
+        # oracle's — parity is statistical)
         key, kc, kd, kr, kt = jax.random.split(s.key, 5)
         dur_cpu = jax.random.uniform(kc, (n,), minval=cfg.cpu_mean
                                      - cfg.cpu_spread,
@@ -871,30 +527,18 @@ def _cohort_body(cfg: EngCfg, s: EngState) -> EngState:
         op_i = jnp.minimum(s.op_idx, cfg.max_ops - 1)
         cur_item = s.items[idx, op_i]
         cur_w = s.kinds[idx, op_i] == jnp.int8(1)
-    rel = None
-    if cfg.protocol == "ppcc" and cfg.fused:
+    if cfg.protocol == "ppcc":
         with jax.named_scope("cohort.relations"):
-            if cfg.delta:
-                # the carried tables already equal this iteration's full
-                # recompute (the end-of-body delta pass maintains them for
-                # the NEXT cursor) — only the cheap O(n·w) reductions run
-                rel = P.relations_inputs(s.rel, read_m, s.pstate.haslocks)
-            elif cfg.megakernel:
-                from ..kernels import ops as kops
-                rel = kops.megastep_relations(
-                    s.pstate.read_set, s.pstate.write_set, s.dirty, cur_item,
-                    cur_w, s.pstate.active, read_m, s.pstate.haslocks)
+            rel = _relations(cfg, s.pstate, s.dirty, cur_item, cur_w, read_m)
     with jax.named_scope("cohort.step"):
-        if cfg.protocol == "ppcc" and cfg.fused:
-            # one fused pass over the packed words: conflict/party matrix →
-            # ordered selection → op verdicts + apply → lock winners →
-            # commit test.  read_m and wc_m are disjoint (a slot is in one
-            # phase), which is what licenses the fused step's pre-state
-            # write-write join (see cohort_step_fused).  Bit-identical to
-            # the multipass chain below under order="index".
+        if cfg.protocol == "ppcc":
+            # one fused pass: ordered selection → op verdicts + apply →
+            # lock winners → commit test.  read_m and wc_m are disjoint
+            # (a slot is in one phase), which is what licenses the fused
+            # step's pre-state write-write join (see cohort_step_fused).
             fs = P.cohort_step_fused(s.pstate, cur_item, cur_w, read_m, wc_m,
-                                     order=cfg.order, relations=rel)
-            ps1 = ps2 = fs.state
+                                     relations=rel)
+            ps2 = fs.state
             verdict, sel, reason = fs.verdict, fs.selected, fs.reason
             degree = fs.degree
             flush_m = wc_m & fs.won & fs.can_commit
@@ -906,23 +550,8 @@ def _cohort_body(cfg: EngCfg, s: EngState) -> EngState:
                                                         cur_item, cur_w,
                                                         read_m)
             degree = jnp.zeros(n, jnp.int32)
-            # The lax.cond gates in this body are pure perf guards: each
-            # branch is exact under an all-False mask.  Under vmap (fleet
-            # lanes) a cond decays into computing BOTH branches plus a
-            # full-state select, so fleet bodies run the masked computation
-            # directly instead.
-            if cfg.fleet:
-                ps2, flush_m, wait_lock_m, wait_prec_m, wc_abort = \
-                    _wc_cohort(cfg, ps1, s.dirty, wc_m)
-            else:
-                ps2, flush_m, wait_lock_m, wait_prec_m, wc_abort = \
-                    jax.lax.cond(
-                        wc_m.any(),
-                        lambda ps: _wc_cohort(cfg, ps, s.dirty, wc_m),
-                        lambda ps: (ps, jnp.zeros(n, bool),
-                                    jnp.zeros(n, bool), jnp.zeros(n, bool),
-                                    jnp.zeros(n, bool)),
-                        ps1)
+            ps2, flush_m, wait_lock_m, wait_prec_m, wc_abort = \
+                _wc_cohort(cfg, ps1, s.dirty, wc_m)
         deferred = read_m & ~sel
         proceed = sel & (verdict == P.PROCEED)
         v_block = sel & (verdict == P.BLOCK)
@@ -946,78 +575,48 @@ def _cohort_body(cfg: EngCfg, s: EngState) -> EngState:
         if cfg.protocol == "occ":
             # close the Kung-Robinson overlap window: re-validate at commit.
             # Same-iteration committers must also validate against each
-            # other (the event engine broadcasts each commit's writes before
-            # the next commit validates) — a slot-ordered pass over the
-            # accumulated writes of lower surviving committers, taken only
-            # on multi-commit iterations.
-            def occ_validate_multi(_):
-                def vstep(acc, i):
-                    fail_i = commit_pre[i] & \
-                        B.overlap_rows(ps2.read_set[i], s.dirty[i] | acc)
-                    acc = acc | jnp.where(commit_pre[i] & ~fail_i,
-                                          ps2.write_set[i], jnp.uint32(0))
-                    return acc, fail_i
-                _, fails = jax.lax.scan(
-                    vstep, jnp.zeros(ps2.words, jnp.uint32), idx)
-                return fails
-
-            if cfg.fleet:
-                occ_fail = occ_validate_multi(None)
-            else:
-                occ_fail = jax.lax.cond(
-                    commit_pre.sum() > 1, occ_validate_multi,
-                    lambda _: commit_pre & B.overlap_rows(ps2.read_set,
-                                                          s.dirty),
-                    None)
+            # other (the oracle broadcasts each commit's writes before the
+            # next commit validates) — a slot-ordered pass over the
+            # accumulated writes of lower surviving committers.
+            def vstep(acc, i):
+                fail_i = commit_pre[i] & \
+                    B.overlap_rows(ps2.read_set[i], s.dirty[i] | acc)
+                acc = acc | jnp.where(commit_pre[i] & ~fail_i,
+                                      ps2.write_set[i], jnp.uint32(0))
+                return acc, fail_i
+            _, occ_fail = jax.lax.scan(
+                vstep, jnp.zeros(ps2.words, jnp.uint32), idx)
         else:
             occ_fail = jnp.zeros(n, bool)
         commit_now = commit_pre & ~occ_fail
         abort_now = to_expired | v_abort | wc_abort | occ_fail
 
-    # ---------------- leave + re-begin (skipped on quiet iterations) ---
+    # ---------------- leave + re-begin ----------------
     with jax.named_scope("cohort.leave_begin"):
         begin_m = commit_now | is_rs
-
-        def leave_and_begin(ps):
-            dirty = s.dirty
-            if cfg.protocol == "occ":
-                union = B.or_reduce(
-                    jnp.where(commit_now[:, None], ps.write_set,
-                              jnp.uint32(0)), axis=0)
-                receivers = ps.active & ~commit_now & ~abort_now
-                dirty = jnp.where(receivers[:, None],
-                                  dirty | union[None, :], dirty)
-                dirty = B.clear_rows(dirty, commit_now | abort_now)
-            if cfg.protocol == "ppcc":
-                ps = P.commit_many(ps, commit_now)
-                ps = P.abort_many(ps, abort_now)
-                return P.begin_many(ps, begin_m), dirty
+        dirty = s.dirty
+        if cfg.protocol == "occ":
+            union = B.or_reduce(
+                jnp.where(commit_now[:, None], ps2.write_set,
+                          jnp.uint32(0)), axis=0)
+            receivers = ps2.active & ~commit_now & ~abort_now
+            dirty = jnp.where(receivers[:, None],
+                              dirty | union[None, :], dirty)
+            dirty = B.clear_rows(dirty, commit_now | abort_now)
+        if cfg.protocol == "ppcc":
+            ps5 = P.commit_many(ps2, commit_now)
+            ps5 = P.abort_many(ps5, abort_now)
+            ps5 = P.begin_many(ps5, begin_m)
+        else:
             # 2pl / occ never write prec, class bits or locks — leave/begin
             # reduce to the read/write-set and active-bit updates
             gone = commit_now | abort_now
-            return ps._replace(
-                read_set=B.clear_rows(ps.read_set, gone | begin_m),
-                write_set=B.clear_rows(ps.write_set, gone | begin_m),
-                active=(ps.active & ~gone) | begin_m,
-            ), dirty
-
-        if cfg.fleet:
-            ps5, dirty = leave_and_begin(ps2)
-        else:
-            ps5, dirty = jax.lax.cond(
-                (commit_now | abort_now | begin_m).any(),
-                leave_and_begin, lambda ps: (ps, s.dirty), ps2)
+            ps5 = ps2._replace(
+                read_set=B.clear_rows(ps2.read_set, gone | begin_m),
+                write_set=B.clear_rows(ps2.write_set, gone | begin_m),
+                active=(ps2.active & ~gone) | begin_m)
 
     with jax.named_scope("cohort.refill"):
-        # fresh workloads are only needed on commit iterations — gate the
-        # (vmapped) sampling behind a cond so quiet iterations skip it
-        def do_sample(k):
-            return sample_txns(k, cfg, s.rt, n)
-
-        def no_sample(k):
-            return (jnp.full((n, cfg.max_ops), -1, jnp.int8),
-                    jnp.zeros((n, cfg.max_ops), jnp.int32))
-
         pool_next = s.pool_next
         if cfg.pool:
             # pop pool rows instead of sampling in-loop: the c-th committing
@@ -1029,11 +628,8 @@ def _cohort_body(cfg: EngCfg, s: EngState) -> EngState:
             fresh_kinds = s.pool_kinds[take]
             fresh_items = s.pool_items[take]
             pool_next = (pool_next + commit_now.sum()) % cfg.pool
-        elif cfg.fleet:
-            fresh_kinds, fresh_items = do_sample(kt)
         else:
-            fresh_kinds, fresh_items = jax.lax.cond(
-                commit_now.any(), do_sample, no_sample, kt)
+            fresh_kinds, fresh_items = sample_txns(kt, cfg, s.rt, n)
         new_kinds = jnp.where(commit_now[:, None], fresh_kinds, s.kinds)
         new_items = jnp.where(commit_now[:, None], fresh_items, s.items)
 
@@ -1114,15 +710,6 @@ def _cohort_body(cfg: EngCfg, s: EngState) -> EngState:
         waiting = (ph == PH_BLOCKED) | (ph == PH_WC_LOCK) | (ph == PH_WC_PREC)
         nt = jnp.where(any_leave & waiting, jnp.minimum(nt, t0), nt)
 
-    # ---------------- delta relation maintenance ----------------------
-    with jax.named_scope("cohort.relations"):
-        if cfg.delta and cfg.protocol == "ppcc" and cfg.fused:
-            rel_c = _delta_update(cfg, s, ps5, cur_item, cur_w,
-                                  new_kinds, new_items, op_new)
-        else:
-            rel_c = s.rel
-
-    with jax.named_scope("cohort.transitions"):
         new_block = v_block & ~was_blocked
 
     # ---------------- telemetry (compiled out when cfg.telemetry off) --
@@ -1208,7 +795,7 @@ def _cohort_body(cfg: EngCfg, s: EngState) -> EngState:
     with jax.named_scope("cohort.transitions"):
         return s._replace(
             pstate=ps5, dirty=dirty, kinds=new_kinds, items=new_items,
-            rel=rel_c, op_idx=op_new, phase=ph, next_time=nt, next_kind=nk,
+            op_idx=op_new, phase=ph, next_time=nt, next_kind=nk,
             deadline=dl, flush_left=fl, cpu_free=cpu_free,
             disk_free=disk_free,
             commits=s.commits + commit_now.sum(),
@@ -1221,16 +808,15 @@ def _cohort_body(cfg: EngCfg, s: EngState) -> EngState:
 def default_cohort_dt(p: SimParams) -> float:
     """Half a mean read cycle (CPU burst + disk access): wide enough to
     batch many completions per quantum, narrow enough that protocol
-    decisions stay fresh — commit counts track the one-event engine
+    decisions stay fresh — commit counts track the ``pysim`` oracle
     within a few percent across the paper grid (DESIGN.md §2.3
     discusses the trade-off)."""
     return 0.5 * (p.cpu_burst_mean + p.io_time_mean)
 
 
 def make_engine(p: SimParams, protocol: str, max_iters: int = 400_000,
-                step_mode: str = "cohort", cohort_dt: float = None):
+                cohort_dt: float = None):
     init, cond, step = engine_parts(p, protocol, max_iters=max_iters,
-                                    step_mode=step_mode,
                                     cohort_dt=cohort_dt)
 
     @jax.jit
@@ -1241,11 +827,8 @@ def make_engine(p: SimParams, protocol: str, max_iters: int = 400_000,
 
 
 def make_padded_engine(p: SimParams, protocol: str, n_slots: int,
-                       max_iters: int = 400_000, step_mode: str = "cohort",
-                       cohort_dt: float = None, fleet: bool = False,
-                       pool: int = 0, fused: bool = True,
-                       order: str = "index", delta: bool = False,
-                       delta_k: int = 0, telemetry: bool = False,
+                       max_iters: int = 400_000, cohort_dt: float = None,
+                       pool: int = 0, telemetry: bool = False,
                        trace_every: int = 0, trace_len: int = 256):
     """An engine whose MPL is a RUNTIME parameter (DESIGN.md §2.4).
 
@@ -1260,11 +843,8 @@ def make_padded_engine(p: SimParams, protocol: str, n_slots: int,
     just buckets those values must fit inside (``check_rt``).
     """
     init, cond, step = engine_parts(p, protocol, max_iters=max_iters,
-                                    step_mode=step_mode,
                                     cohort_dt=cohort_dt, n_slots=n_slots,
-                                    fleet=fleet, pool=pool, fused=fused,
-                                    order=order, delta=delta,
-                                    delta_k=delta_k, telemetry=telemetry,
+                                    pool=pool, telemetry=telemetry,
                                     trace_every=trace_every,
                                     trace_len=trace_len)
 
@@ -1311,26 +891,20 @@ def check_rt(p: SimParams, rt: RtParams) -> None:
 
 
 def engine_parts(p: SimParams, protocol: str, max_iters: int = 400_000,
-                 step_mode: str = "cohort", cohort_dt: float = None,
-                 n_slots: int = None, fleet: bool = False, pool: int = 0,
-                 fused: bool = True, order: str = "index",
-                 megakernel: bool = None, delta: bool = False,
-                 delta_k: int = 0, telemetry: bool = False,
+                 cohort_dt: float = None, n_slots: int = None, pool: int = 0,
+                 megakernel: bool = None, telemetry: bool = False,
                  trace_every: int = 0, trace_len: int = 256):
-    """(init, cond, step) for single-stepping an engine from tests —
-    e.g. checking protocol invariants after every cohort step.
+    """(init, cond, step) of the cohort engine: ``make_engine``,
+    ``make_padded_engine`` and ``sweep.Fleet`` loop them, and tests
+    single-step them (e.g. to check the protocol invariants after every
+    cohort step).
 
     ``n_slots`` pads the slot axis beyond ``p.mpl`` (the padded-lane
     engine); ``init(seed, mpl=None)`` then takes the number of active
     slots as a runtime value (default ``p.mpl``).  ``megakernel=None``
-    auto-gates the Pallas cohort-step megakernel to the TPU, the one
-    backend it is compiled for (elsewhere the kernel would run in
-    interpret mode inside the loop, so the jnp twin inside
-    ``ppcc.cohort_step_fused`` is both the fast and the correct path)."""
-    if step_mode not in ("cohort", "event"):
-        raise ValueError(f"unknown step_mode: {step_mode!r}")
-    if telemetry and step_mode != "cohort":
-        raise ValueError("telemetry requires step_mode='cohort'")
+    selects the Pallas cohort-step megakernel on the TPU, the one
+    backend it is compiled for, and its jnp twin elsewhere (where the
+    kernel would run in interpret mode inside the loop)."""
     if megakernel is None:
         megakernel = jax.default_backend() == "tpu"
     if cohort_dt is None:
@@ -1339,18 +913,9 @@ def engine_parts(p: SimParams, protocol: str, max_iters: int = 400_000,
         n_slots = p.mpl
     if n_slots < p.mpl:
         raise ValueError(f"n_slots={n_slots} < mpl={p.mpl}")
-    if delta and delta_k <= 0:
-        # measured dirty-row occupancy sits well under n/4 per quantum
-        # (BENCH_sweep.json["delta_vs_full"]["occupancy"]); bucket to a
-        # lane multiple so the slab tiles cleanly
-        delta_k = B.bucket(max(1, n_slots // 4), 8)
-    carry_rel = delta and protocol == "ppcc" and fused and \
-        step_mode == "cohort"
     cfg = dataclasses.replace(_cfg(p, max_iters), protocol=protocol,
                               cohort_dt=float(cohort_dt), n=n_slots,
-                              fleet=fleet, pool=pool, fused=fused,
-                              order=order, megakernel=megakernel,
-                              delta=carry_rel, delta_k=delta_k,
+                              pool=pool, megakernel=megakernel,
                               telemetry=telemetry,
                               trace_every=trace_every,
                               trace_len=trace_len)
@@ -1392,55 +957,27 @@ def engine_parts(p: SimParams, protocol: str, max_iters: int = 400_000,
             iters=jnp.int32(0),
             pool_kinds=pool_kinds, pool_items=pool_items,
             pool_next=jnp.int32(0), rt=rt,
-            rel=P.empty_relations(cfg.n if cfg.delta else 0),
             tm=M.init_telemetry(
                 cfg.n if cfg.telemetry else 0,
                 cfg.trace_len if (cfg.telemetry and cfg.trace_every > 0)
                 else 0))
         # begin only the first `mpl` slots; the rest stay PH_OFF/INF so
         # every cohort mask derived from `ready` leaves them inert
-        s = jax.lax.fori_loop(
+        return jax.lax.fori_loop(
             0, cfg.n,
             lambda i, s_: jax.lax.cond(
-                i < mpl,
-                lambda s2: _begin_txn(cfg, s2, i, jnp.bool_(True)),
+                i < mpl, lambda s2: _begin_txn(cfg, s2, i),
                 lambda s2: s2, s_), s)
-        if cfg.delta:
-            # seed the carried-tables invariant: rel equals the full
-            # recompute at the first body's op cursor
-            idx0 = jnp.arange(cfg.n, dtype=jnp.int32)
-            op_i = jnp.minimum(s.op_idx, cfg.max_ops - 1)
-            s = s._replace(rel=P.compute_relations(
-                s.pstate, s.items[idx0, op_i],
-                s.kinds[idx0, op_i] == jnp.int8(1)))
-        return s
 
     def cond(s: EngState):
         return (s.now <= cfg.horizon) & (s.iters < cfg.max_iters) & \
             (s.next_time.min() < 0.5 * INF)
 
-    if step_mode == "cohort":
-        step = functools.partial(_cohort_body, cfg)
-    else:
-        def step(s: EngState) -> EngState:
-            i = jnp.argmin(s.next_time)
-            s = s._replace(now=s.next_time[i], iters=s.iters + 1,
-                           next_time=s.next_time.at[i].set(INF))
-            return jax.lax.switch(
-                s.next_kind[i].astype(jnp.int32),
-                [functools.partial(_ev_attempt, cfg),
-                 functools.partial(_ev_disk_done, cfg),
-                 functools.partial(_ev_flush_done, cfg),
-                 functools.partial(_ev_timeout, cfg),
-                 functools.partial(_ev_restart, cfg)],
-                s, i)
-
-    return init, jax.jit(cond), jax.jit(step)
+    return init, jax.jit(cond), jax.jit(functools.partial(_cohort_body, cfg))
 
 
-def simulate(p: SimParams, protocol: str,
-             step_mode: str = "cohort") -> SimResult:
-    run = make_engine(p, protocol, step_mode=step_mode)
+def simulate(p: SimParams, protocol: str) -> SimResult:
+    run = make_engine(p, protocol)
     s = run(jnp.int32(p.seed))
     res = SimResult(protocol=protocol, params=p)
     res.commits = int(s.commits)
@@ -1451,10 +988,9 @@ def simulate(p: SimParams, protocol: str,
     return res
 
 
-def simulate_sweep(p: SimParams, protocol: str, seeds,
-                   step_mode: str = "cohort") -> Any:
+def simulate_sweep(p: SimParams, protocol: str, seeds) -> Any:
     """vmap over seeds — one SPMD computation, shardable over `data`."""
-    run = make_engine(p, protocol, step_mode=step_mode)
+    run = make_engine(p, protocol)
     final = jax.vmap(run)(jnp.asarray(seeds, jnp.int32))
     return {"commits": final.commits, "aborts": final.aborts,
             "blocks": final.blocks}

@@ -455,20 +455,6 @@ def try_ops_batched(s: PPCCState, item: jax.Array, is_write: jax.Array,
     return s2, verdict
 
 
-def cohort_step(s: PPCCState, item: jax.Array, is_write: jax.Array,
-                ready: jax.Array
-                ) -> Tuple[PPCCState, jax.Array, jax.Array, jax.Array]:
-    """``cohort_select`` + ``try_ops_batched`` sharing one set of
-    gathers (the engine hot path).  Returns (state, verdict, selected,
-    block-reason codes — ``R_LOCK``/``R_RULE`` on BLOCK lanes).
-    """
-    writers_at, readers_at = _op_tables(s, item)
-    sel = _select(s, item, is_write, ready, writers_at, readers_at)
-    s2, verdict, reason = _try_ops(s, item, is_write, sel, writers_at,
-                                   readers_at)
-    return s2, verdict, sel, reason
-
-
 class FusedStep(NamedTuple):
     """Result of one fused cohort step (``cohort_step_fused``)."""
 
@@ -488,9 +474,10 @@ def cohort_step_fused(s: PPCCState, item: jax.Array, is_write: jax.Array,
     """One cohort step, fused end to end (DESIGN.md §3): conflict/party
     matrix → degree → ordered independence selection → op verdicts +
     apply → wait-to-commit feasibility/winners → commit test — a single
-    pass over the packed words, replacing the engine's former
-    ``cohort_step`` + ``wc_acquire_many`` + ``can_commit_many`` chain
-    (which re-gathered the op tables and re-joined the write words).
+    pass over the packed words, bit-identical under ``order="index"`` to
+    the multipass chain ``cohort_select`` → ``try_ops_batched`` →
+    ``wc_acquire_many(exact=False)`` → ``can_commit_many`` (which
+    re-gathers the op tables and re-joins the write words).
 
     ``ready`` marks read-phase ops, ``wc_mask`` the slots attempting
     wait-to-commit lock acquisition this quantum; the engine guarantees
@@ -510,13 +497,12 @@ def cohort_step_fused(s: PPCCState, item: jax.Array, is_write: jax.Array,
     one-step relaxation to the sequential-greedy scan
     (``wc_acquire_many(exact=True)`` semantics).
 
-    ``relations`` optionally supplies the pairwise relations from ONE
-    launch of the cohort-step megakernel — the tuple
-    ``kernels.ops.megastep_relations(...)`` returns (its trailing
-    ``dirty_hit`` is ignored here) — in place of the inline jnp joins;
-    both are bit-identical (``tests/test_megastep.py``).  The compiled
-    megakernel path is for the TPU; elsewhere the inline twin is the
-    fast path.
+    ``relations`` supplies the pairwise relations in place of the inline
+    jnp joins: the engine passes either ONE launch of the cohort-step
+    megakernel — the tuple ``kernels.ops.megastep_relations(...)``
+    returns (its trailing ``dirty_hit`` is ignored here) — or the
+    ``relations_inputs`` 6-tuple of its jnp twin; both are bit-identical
+    (``tests/test_megastep.py``).
     """
     n = s.n
     idx = jnp.arange(n, dtype=jnp.int32)
@@ -552,27 +538,11 @@ def cohort_step_fused(s: PPCCState, item: jax.Array, is_write: jax.Array,
                      reason)
 
 
-# --------------------------------------------------------------------------
-# delta-maintained relations (DESIGN.md §3.2)
-#
-# The four pairwise relations a fused cohort step consumes (``dep``,
-# ``ww``, ``writers_at``, ``readers_at``) are functions of (packed set
-# words, per-slot op cursor, active flags) only — the per-quantum
-# ``deg``/``lockhit`` vectors derive from them with the live
-# ``ready``/``haslocks`` masks.  A single step mutates few slots, so the
-# engine carries the matrices across iterations and recomputes only the
-# *dirty rows* — then mirrors them into the columns (``dep``/``ww`` are
-# symmetric; clean rows of the op tables are provably unchanged, see
-# ``dirty_slots``).
-# --------------------------------------------------------------------------
-
-
 class Relations(NamedTuple):
-    """Loop-carried pairwise relations of the fused cohort step.
-
-    Invariant (when the engine's delta path is on): equal to
-    ``compute_relations(state, item, is_write)`` for the state and op
-    cursor the NEXT ``cohort_step_fused`` call will see.
+    """The pairwise relations a fused cohort step consumes: functions of
+    the packed set words, the per-slot op cursor and the active flags
+    only (the per-quantum ``deg``/``lockhit`` vectors derive from them
+    with the live ``ready``/``haslocks`` masks, ``relations_inputs``).
     """
 
     dep: jax.Array           # bool[n, n] op dependence, diagonal False
@@ -581,17 +551,10 @@ class Relations(NamedTuple):
     readers_at: jax.Array    # bool[n, n] [i, k] = item_i in read_set[k]
 
 
-def empty_relations(n: int = 0) -> Relations:
-    """A shape-(n, n) Relations pytree; n=0 when the delta path is off
-    (keeps the engine-state tree structure constant)."""
-    z = jnp.zeros((n, n), jnp.bool_)
-    return Relations(z, z, z, z)
-
-
 def compute_relations(s: PPCCState, item: jax.Array, is_write: jax.Array
                       ) -> Relations:
-    """Full O(n²·w) recompute — the inline twin of the megakernel's
-    first four outputs, and the delta path's overflow fallback."""
+    """Full O(n²·w) recompute — the jnp twin of the megakernel's first
+    four outputs."""
     writers_at, readers_at = _op_tables(s, item)
     dep = _dep_matrix(s, item, is_write, writers_at, readers_at)
     ww = B.any_overlap(s.write_set, s.write_set) & \
@@ -601,73 +564,11 @@ def compute_relations(s: PPCCState, item: jax.Array, is_write: jax.Array
 
 def relations_inputs(rel: Relations, ready: jax.Array,
                      haslocks: jax.Array):
-    """Attach the per-quantum ``deg``/``lockhit`` vectors to carried
+    """Attach the per-quantum ``deg``/``lockhit`` vectors to the
     relations: the 6-tuple ``cohort_step_fused(relations=...)`` takes."""
     deg = (rel.dep & ready[None, :]).sum(axis=1, dtype=jnp.int32)
     lockhit = (rel.ww & haslocks[None, :]).any(axis=1)
     return (rel.dep, rel.ww, rel.writers_at, rel.readers_at, deg, lockhit)
-
-
-def dirty_slots(old: PPCCState, new: PPCCState, old_item: jax.Array,
-                new_item: jax.Array, old_isw: jax.Array,
-                new_isw: jax.Array) -> jax.Array:
-    """bool[n]: slots whose relation ROWS may differ between the old and
-    new (state, op cursor) pairs.
-
-    Three triggers:
-      * ``rowchange`` — any bit of the slot's own read/write words
-        changed (covers its ``ww`` row/column and its own membership in
-        other parties);
-      * ``cursor`` — the slot's pending (item, kind) changed (all four
-        of its rows are keyed on the cursor);
-      * ``member`` — the bit of the slot's item is in the UNION of all
-        slots' word deltas: some third slot joined or left this row's
-        party / op tables.
-    Active-flag flips need no trigger of their own: a flip co-occurs
-    with the flipping slot's words being cleared (commit/abort/begin),
-    so any row it participated in is caught by ``member``, and a slot
-    activating with empty words is in no party either way.
-    """
-    delta = (old.read_set ^ new.read_set) | (old.write_set ^ new.write_set)
-    rowchange = B.any_bit(delta)
-    cursor = (old_item != new_item) | (old_isw != new_isw)
-    union = B.or_reduce(delta, axis=0)                   # uint32[W]
-    w, b = B.word_bit(new_item)
-    member = ((union[w] >> b) & jnp.uint32(1)).astype(bool)
-    return rowchange | cursor | member
-
-
-def dirty_slab(dirty: jax.Array, k: int):
-    """Gather the dirty-row ids into a static K-slot slab.
-
-    Returns (slab int32[k] — ids ascending, padded with n; valid
-    bool[k]; count int32 — the TRUE dirty count, > k on overflow)."""
-    n = dirty.shape[0]
-    slab = jnp.nonzero(dirty, size=k, fill_value=n)[0].astype(jnp.int32)
-    return slab, slab < n, dirty.sum(dtype=jnp.int32)
-
-
-def scatter_relations(rel: Relations, dep_rows: jax.Array,
-                      ww_rows: jax.Array, wat_rows: jax.Array,
-                      rat_rows: jax.Array, slab: jax.Array,
-                      valid: jax.Array) -> Relations:
-    """Write a row-slab kernel's (K, n) row blocks back into the carried
-    matrices: rows for all four relations, PLUS mirrored columns for the
-    symmetric ``dep``/``ww`` (a dirty slot's column equals its row; the
-    op tables' clean rows are unchanged by the dirty-row rule, so they
-    need no column fix-up).  Invalid slab entries route to row n and
-    drop."""
-    n = rel.dep.shape[0]
-    tgt = jnp.where(valid, slab, n)
-    dep = rel.dep.at[tgt, :].set(dep_rows, mode="drop")
-    dep = dep.at[:, tgt].set(dep_rows.T, mode="drop")
-    ww = rel.ww.at[tgt, :].set(ww_rows, mode="drop")
-    ww = ww.at[:, tgt].set(ww_rows.T, mode="drop")
-    return Relations(
-        dep=dep, ww=ww,
-        writers_at=rel.writers_at.at[tgt, :].set(wat_rows, mode="drop"),
-        readers_at=rel.readers_at.at[tgt, :].set(rat_rows, mode="drop"),
-    )
 
 
 def wc_acquire_many(s: PPCCState, mask: jax.Array, exact: bool = True

@@ -13,8 +13,8 @@ protocols:
 
 This module is intentionally *pure Python* and event-driven: it is the
 semantics oracle that the tensorised JAX engine (``jaxsim.py``) and the
-batch scheduler (``repro.sched``) are validated against, and it produces
-the paper-figure reproductions in ``benchmarks/run.py``.
+batch scheduler (``repro.sched``) are validated against
+(``tests/test_figure_parity.py`` checks every paper figure's lanes).
 
 Transaction lifecycle (strict protocols, paper Section 2.3):
 

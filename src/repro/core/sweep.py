@@ -27,10 +27,8 @@ Protocol selection is a trace-time branch in the engine
 (``EngCfg.protocol``), so the fleet stacks one vmapped sub-sweep per
 protocol inside the single jitted call — still one executable, without
 paying the run-all-protocols select a traced ``lax.switch`` would cost
-under vmap.  Lane bodies use ``fleet=True`` engines: the
-quiet-iteration ``lax.cond`` gates of the cohort body are dropped
-because under vmap they decay into computing both branches plus a
-full-state select.
+under vmap.  Lane bodies draw fresh transactions from a pre-sampled
+pool (``EngCfg.pool``) instead of sampling in-loop.
 
 Lane bodies stream the packed ``uint32[n, ceil(d/32)]`` set words of
 ``repro.core.bitset`` (DESIGN.md §1.1) — the fleet's dominant memory
@@ -38,11 +36,11 @@ traffic is the set arrays, and packing cuts it ~8x at the paper's
 ``db_size=500``.
 
 Multi-device hosts shard the lane axis over the standard
-``("data", "model")`` mesh (``repro.parallel.sharding.host_mesh``) via
+``("data", "model")`` mesh (``repro.core.mesh.host_mesh``) via
 ``shard_map``: every device then runs its lane shard's while_loop
 independently — lanes on different devices are not even in lockstep.
 Multi-host runs extend the mesh with a leading pod axis
-(``sharding.pod_mesh`` after ``sharding.init_distributed``); lanes
+(``mesh.pod_mesh`` after ``mesh.init_distributed``); lanes
 then shard over ``("pod", "data")`` — hosts first, local devices
 second.
 """
@@ -56,6 +54,7 @@ import numpy as np
 
 from . import bitset as B
 from . import jaxsim
+from .mesh import data_axes, host_mesh, pod_mesh
 from .types import (GRID_FIGS, SimParams, grid_cover_params,
                     paper_figure_params)
 
@@ -75,12 +74,11 @@ def fleet_mesh(n_lanes: int, pods: Optional[bool] = None):
     an even lane split); None on single-device hosts.
 
     Single-process: the ``("data", "model")`` host mesh.  Multi-process
-    (``jax.process_count() > 1``, after ``sharding.init_distributed``)
+    (``jax.process_count() > 1``, after ``mesh.init_distributed``)
     — or ``pods=True`` to force the pod-axis path single-process — the
     ``("pod", "data", "model")`` mesh; lanes then shard over
     ``("pod", "data")``.
     """
-    from ..parallel.sharding import host_mesh, pod_mesh
     if pods is None:
         pods = jax.process_count() > 1
     if pods:
@@ -117,21 +115,12 @@ class Fleet:
     paper figures share the executable (``run_grid`` builds the
     figs 5–16 grid this way).  ``p`` then only fixes the static
     buckets every lane's values must fit inside.
-
-    ``fused=False`` runs the ppcc lanes through the legacy multipass
-    cohort chain instead of ``ppcc.cohort_step_fused`` — bit-identical
-    results, kept for the fused-vs-multipass benchmark comparison.
-    ``delta=True`` carries the ppcc relation tables across iterations
-    and updates only the dirty rows per quantum (DESIGN.md §3.2) —
-    also bit-identical; the delta-vs-full benchmark compares the two.
     """
 
     def __init__(self, p: SimParams, protocols: Sequence[str] = PROTOCOLS,
                  n_slots: Optional[int] = None, max_iters: int = 400_000,
                  cohort_dt: Optional[float] = None, mesh=None,
-                 pool: Optional[int] = None, fused: bool = True,
-                 order: str = "index", delta: bool = False,
-                 delta_k: int = 0, telemetry: bool = False,
+                 pool: Optional[int] = None, telemetry: bool = False,
                  trace_every: int = 0, trace_len: int = 256):
         if n_slots is None:
             n_slots = slot_bucket(p.mpl)
@@ -150,10 +139,8 @@ class Fleet:
         parts = {
             proto: jaxsim.engine_parts(
                 p, proto, max_iters=max_iters, cohort_dt=cohort_dt,
-                n_slots=n_slots, fleet=True, pool=pool, fused=fused,
-                order=order, delta=delta, delta_k=delta_k,
-                telemetry=telemetry, trace_every=trace_every,
-                trace_len=trace_len)
+                n_slots=n_slots, pool=pool, telemetry=telemetry,
+                trace_every=trace_every, trace_len=trace_len)
             for proto in self.protocols
         }
 
@@ -168,7 +155,6 @@ class Fleet:
             runner = jax.vmap(run_one)
             if mesh is not None:
                 from jax.sharding import PartitionSpec as P
-                from ..parallel.sharding import data_axes
                 lane = P(data_axes(mesh))
                 runner = jax.shard_map(
                     runner, mesh=mesh, in_specs=(lane, lane, lane),
@@ -243,9 +229,8 @@ class Fleet:
 def run_fleet(fig: int, mpl_grid: Sequence[int], seeds: Sequence[int],
               horizon: float, protocols: Sequence[str] = PROTOCOLS,
               n_slots: Optional[int] = None, max_iters: int = 400_000,
-              shard: bool = True, fused: bool = True, delta: bool = False,
-              telemetry: bool = False, trace_every: int = 0,
-              trace_len: int = 256,
+              shard: bool = True, telemetry: bool = False,
+              trace_every: int = 0, trace_len: int = 256,
               ) -> Tuple[Dict[str, Dict[str, np.ndarray]], Fleet]:
     """Run one paper figure's full grid as a single compiled call.
 
@@ -259,9 +244,8 @@ def run_fleet(fig: int, mpl_grid: Sequence[int], seeds: Sequence[int],
     n_lanes = len(mpl_grid) * len(seeds)
     mesh = fleet_mesh(n_lanes) if shard else None
     fleet = Fleet(p, protocols=protocols, n_slots=n_slots,
-                  max_iters=max_iters, mesh=mesh, fused=fused, delta=delta,
-                  telemetry=telemetry, trace_every=trace_every,
-                  trace_len=trace_len)
+                  max_iters=max_iters, mesh=mesh, telemetry=telemetry,
+                  trace_every=trace_every, trace_len=trace_len)
     out = fleet(list(mpl_grid), list(seeds))
     host = jax.tree.map(np.asarray, out)
     return host, fleet
@@ -288,9 +272,9 @@ def run_grid(figs: Sequence[int] = GRID_FIGS,
              seeds: Sequence[int] = (0, 1), horizon: float = 20_000.0,
              protocols: Sequence[str] = PROTOCOLS,
              n_slots: Optional[int] = None, max_iters: int = 400_000,
-             shard: bool = True, fused: bool = True, delta: bool = False,
-             fleet: Optional[Fleet] = None, telemetry: bool = False,
-             trace_every: int = 0, trace_len: int = 256,
+             shard: bool = True, fleet: Optional[Fleet] = None,
+             telemetry: bool = False, trace_every: int = 0,
+             trace_len: int = 256,
              ) -> Tuple[Dict[int, Dict[str, Dict[str, np.ndarray]]],
                         Fleet]:
     """EVERY paper figure's grid in one compiled fleet launch.
@@ -312,8 +296,7 @@ def run_grid(figs: Sequence[int] = GRID_FIGS,
             n_slots = slot_bucket(max(mpl_grid))
         mesh = fleet_mesh(n_lanes) if shard else None
         fleet = Fleet(cover, protocols=protocols, n_slots=n_slots,
-                      max_iters=max_iters, mesh=mesh, fused=fused,
-                      delta=delta, telemetry=telemetry,
+                      max_iters=max_iters, mesh=mesh, telemetry=telemetry,
                       trace_every=trace_every, trace_len=trace_len)
     seed_l, mpl_l, rt_l = grid_lanes(figs, mpl_grid, seeds)
     flat = fleet.run_lanes(seed_l, mpl_l, rt_l)

@@ -28,47 +28,7 @@ from jax.experimental import pallas as pl
 
 # the protocol-wide packer lives in core.bitset; re-exported here so
 # kernel callers keep their historical import path
-from ..core import bitset as B
 from ..core.bitset import pack as pack_bitsets  # noqa: F401
-
-
-def rowslab(read_bits: jax.Array, write_bits: jax.Array,
-            writers_at: jax.Array, readers_at: jax.Array,
-            item: jax.Array, is_write: jax.Array, active: jax.Array,
-            slab: jax.Array, valid: jax.Array):
-    """jnp twin of the (K, n) dirty-row slab kernel (DESIGN.md §3.2).
-
-    Recomputes only the K relation rows named by ``slab`` against the
-    full new state: fresh op-table rows come from the packed words, the
-    party matrix is rebuilt from the CARRIED ``writers_at``/
-    ``readers_at`` with the slab rows substituted (clean rows of the
-    carried tables are exact by the dirty-row rule), and the dep join
-    is a (K, nw) x (n, nw) packed overlap instead of the full
-    (n, nw) self-join.  Bit-identical to ``ref.rowslab_ref``.
-
-    Returns (dep_rows, ww_rows, wat_rows, rat_rows), each bool[K, n];
-    rows with ``~valid`` are zeroed (callers scatter with OOB drop).
-    """
-    n = read_bits.shape[0]
-    sl = jnp.clip(slab, 0, n - 1)
-    s_item = item[sl]
-    wat_rows = B.item_cols(write_bits, s_item)           # [K, n]
-    rat_rows = B.item_cols(read_bits, s_item)
-    tgt = jnp.where(valid, sl, n)                        # OOB drop pads
-    wat2 = writers_at.at[tgt].set(wat_rows, mode="drop")
-    rat2 = readers_at.at[tgt].set(rat_rows, mode="drop")
-    eye = jnp.eye(n, dtype=bool)
-    others = jnp.where(is_write[:, None], rat2, wat2)
-    party = (others & active[None, :] & ~eye) | eye      # [n, n]
-    pp = B.pack(party)                                   # [n, nw]
-    dep_rows = B.any_overlap(pp[sl], pp)                 # [K, n]
-    same_item = s_item[:, None] == item[None, :]
-    either_w = is_write[sl][:, None] | is_write[None, :]
-    eye_s = sl[:, None] == jnp.arange(n)[None, :]
-    dep_rows = (dep_rows | (same_item & either_w)) & ~eye_s
-    ww_rows = B.any_overlap(write_bits[sl], write_bits) & ~eye_s
-    v = valid[:, None]
-    return dep_rows & v, ww_rows & v, wat_rows & v, rat_rows & v
 
 
 def _overlap(a, bt, words: int):

@@ -167,80 +167,3 @@ def megastep(read_bits: jax.Array, write_bits: jax.Array,
       dirty_bits)
     dep, ww, wat, rat = (m != 0 for m in (dep, ww, wat, rat))
     return dep, ww, wat, rat, vec[:, 0], vec[:, 1] != 0, vec[:, 2] != 0
-
-
-def _rowslab_kernel(cs_ref, ca_ref, rows_ref, slr_ref, rt_ref, wt_ref,
-                    ws_ref, wat_ref, rat_ref,
-                    dep_ref, ww_ref, watr_ref, ratr_ref, *, words: int):
-    k, n = dep_ref.shape
-    rows = rows_ref[...]                             # int32[3, n]
-    item_r, isw_r = rows[0:1, :], rows[1:2, :]
-    act = rows[2:3, :] != 0
-    rt, wt = rt_ref[...], wt_ref[...]                # uint32[W, n]
-    cs = cs_ref[...]                                 # int32[k, 6] slab
-    sl_c, v = cs[:, 4:5], cs[:, 5:6] != 0
-
-    # fresh op tables and party rows of the slab slots
-    wat_s, rat_s = _op_tables(cs, rt, wt, words)     # [k, n]
-    self_s = sl_c == jax.lax.broadcasted_iota(jnp.int32, (k, n), 1)
-    p_s = _party(cs[:, 2:3], wat_s, rat_s, act, self_s)
-
-    # every slot's party row — carried tables with the slab rows
-    # substituted through a one-hot matmul (ids are unique, so each
-    # row has at most one hit; invalid entries carry id -1)
-    sel = jax.lax.broadcasted_iota(jnp.int32, (n, k), 0) == slr_ref[...]
-    hit = jnp.where(sel, 1, 0).max(axis=1, keepdims=True) != 0
-
-    def substitute(carried, fresh):
-        moved = jnp.dot(_bf16(sel), _bf16(fresh),
-                        preferred_element_type=jnp.float32) > 0
-        return (hit & moved) | (~hit & (carried.astype(jnp.int32) != 0))
-
-    wat_a = substitute(wat_ref[...], wat_s)          # [n, n]
-    rat_a = substitute(rat_ref[...], rat_s)
-    eye = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) == \
-        jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    p_a = _party(ca_ref[...], wat_a, rat_a, act, eye)
-
-    same_item = cs[:, 3:4] == item_r
-    either_w = (cs[:, 2:3] != 0) | (isw_r != 0)
-    dep_ref[...] = _i8((_join(p_s, p_a) | (same_item & either_w))
-                       & ~self_s & v)
-    ww_ref[...] = _i8(_ww_rows(ws_ref[...], wt, words) & ~self_s & v)
-    watr_ref[...] = _i8(wat_s & v)
-    ratr_ref[...] = _i8(rat_s & v)
-
-
-def rowslab(read_bits: jax.Array, write_bits: jax.Array,
-            writers_at: jax.Array, readers_at: jax.Array,
-            item: jax.Array, is_write: jax.Array, active: jax.Array,
-            slab: jax.Array, valid: jax.Array, *,
-            interpret: bool = False):
-    """Pallas variant of the (K, n) dirty-row slab kernel (DESIGN.md
-    §3.2): one program holds the packed words and the carried
-    ``writers_at``/``readers_at`` tables in VMEM and emits the four
-    (K, n) relation row blocks.  The K-entry slab gathers (op metadata
-    and write words of the slab slots) happen outside the kernel.
-    Bit-identical to ``ref.rowslab_ref`` / the ``conflict.rowslab`` jnp
-    twin."""
-    n, w = read_bits.shape
-    assert write_bits.shape == (n, w)
-    assert writers_at.shape == (n, n) and readers_at.shape == (n, n)
-    k = slab.shape[0]
-    sl = jnp.clip(slab, 0, n - 1).astype(jnp.int32)
-    item, is_write, active, valid = _i32(item, is_write, active, valid)
-    it_s = item[sl]
-    cs = jnp.stack([it_s >> 5, it_s & 31, is_write[sl], it_s, sl, valid],
-                   axis=1)
-    ca = is_write[:, None]
-    rows = jnp.stack([item, is_write, active])
-    slr = jnp.where(valid != 0, sl, -1)[None, :]
-    kernel = functools.partial(_rowslab_kernel, words=w)
-    out = jax.ShapeDtypeStruct((k, n), jnp.int8)
-    res = pl.pallas_call(
-        kernel,
-        out_shape=[out] * 4,
-        interpret=interpret,
-    )(cs, ca, rows, slr, read_bits.T, write_bits.T, write_bits[sl],
-      _i8(writers_at), _i8(readers_at))
-    return tuple(m != 0 for m in res)

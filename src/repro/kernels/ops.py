@@ -78,17 +78,6 @@ def megastep_relations(read_bits, write_bits, dirty_bits, item, is_write,
         haslocks, interpret=_interpret_default())
 
 
-@jax.jit
-def rowslab_relations(read_bits, write_bits, writers_at, readers_at,
-                      item, is_write, active, slab, valid):
-    """Dirty-row slab kernel: one launch -> (dep_rows, ww_rows,
-    wat_rows, rat_rows), each bool[K, n]; see kernels.megastep.rowslab.
-    Compiled on the TPU, interpret mode elsewhere."""
-    return _megastep.rowslab(
-        read_bits, write_bits, writers_at, readers_at, item, is_write,
-        active, slab, valid, interpret=_interpret_default())
-
-
 # the protocol-wide packer (repro.core.bitset.pack), jitted; conflict
 # re-exports it so the historical kernels import path keeps working
 pack_bitsets = jax.jit(_conflict.pack_bitsets)

@@ -110,48 +110,6 @@ def megastep_ref(read_bits: jax.Array, write_bits: jax.Array,
     return dep, ww, writers_at, readers_at, deg, lockhit, dirty_hit
 
 
-def rowslab_ref(read_bits: jax.Array, write_bits: jax.Array,
-                writers_at: jax.Array, readers_at: jax.Array,
-                item: jax.Array, is_write: jax.Array, active: jax.Array,
-                slab: jax.Array, valid: jax.Array):
-    """Oracle for the (K, n) row-slab kernel (delta relation
-    maintenance, DESIGN.md §3.2).
-
-    ``slab`` holds the K dirty slot ids (``valid`` marks real entries;
-    invalid ids may be arbitrary and their output rows are zeroed).
-    ``writers_at``/``readers_at`` are the CARRIED op tables; the fresh
-    slab rows are substituted before forming the party matrix, so the
-    dep rows are exactly the rows of a full recompute whenever every
-    non-slab row of the carried tables is still current.
-
-    Returns (dep_rows, ww_rows, wat_rows, rat_rows), each bool[K, n].
-    """
-    n = read_bits.shape[0]
-    sl = jnp.clip(slab, 0, n - 1)
-    s_item = item[sl]
-    w_idx, b_idx = s_item >> 5, (s_item & 31).astype(jnp.uint32)
-    wat_rows = ((write_bits[:, w_idx] >> b_idx[None, :])
-                & jnp.uint32(1)).astype(bool).T          # [K, n]
-    rat_rows = ((read_bits[:, w_idx] >> b_idx[None, :])
-                & jnp.uint32(1)).astype(bool).T
-    tgt = jnp.where(valid, sl, n)                        # OOB drop pads
-    wat2 = writers_at.at[tgt].set(wat_rows, mode="drop")
-    rat2 = readers_at.at[tgt].set(rat_rows, mode="drop")
-    eye = jnp.eye(n, dtype=bool)
-    others = jnp.where(is_write[:, None], rat2, wat2)
-    party = (others & active[None, :] & ~eye) | eye      # [n, n]
-    party_s = party[sl]                                  # [K, n]
-    dep_rows = (party_s[:, None, :] & party[None, :, :]).any(axis=-1)
-    same_item = s_item[:, None] == item[None, :]
-    either_w = is_write[sl][:, None] | is_write[None, :]
-    eye_s = sl[:, None] == jnp.arange(n)[None, :]
-    dep_rows = (dep_rows | (same_item & either_w)) & ~eye_s
-    ww_rows = ((write_bits[sl][:, None, :] & write_bits[None, :, :]) != 0
-               ).any(axis=-1) & ~eye_s
-    v = valid[:, None]
-    return dep_rows & v, ww_rows & v, wat_rows & v, rat_rows & v
-
-
 def wkv_ref(r: jax.Array, k: jax.Array, v: jax.Array, log_w: jax.Array,
             u: jax.Array, head_dim: int,
             state0: Optional[jax.Array] = None):

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..core.mesh import data_axes
 from ..models import LM
 from ..models.config import (ALL_SHAPES, ModelConfig, ShapeSpec)
 from ..parallel import sharding as shd
@@ -66,7 +67,7 @@ def cell_specs(cfg: ModelConfig, shape_name: str, mesh: Mesh
         return (batch,), (specs,)
     caches, token, pos = decode_args(cfg, sp)
     cache_sp = shd.cache_specs(cfg, sp, mesh, caches)
-    dax = shd.data_axes(mesh)
+    dax = data_axes(mesh)
     tok_sp = (P(dax, None)
               if sp.global_batch % shd._axis_size(mesh, dax) == 0
               else P(None, None))

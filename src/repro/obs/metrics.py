@@ -80,7 +80,7 @@ class Telemetry(NamedTuple):
     Per-slot stamps (f32/int32[n]) plus fixed-shape histograms; every
     leaf is shape-0 when ``EngCfg.telemetry`` is off, so the pytree
     structure — and therefore the compiled executable — is unchanged
-    by the flag (the ``rel``-placeholder pattern of DESIGN.md §3.2).
+    by the flag (DESIGN.md §8).
     """
 
     first_start: Any    # f32[n] first begin time of the slot's live txn

@@ -51,9 +51,9 @@ def test_bucketed_run_bit_identical(protocol):
     identical commits, aborts, iteration counts and final clock."""
     rt = jaxsim.rt_of(NATIVE)
     native = jaxsim.make_padded_engine(NATIVE, protocol, n_slots=16,
-                                       fleet=True, pool=512)
+                                       pool=512)
     bucket = jaxsim.make_padded_engine(BUCKET, protocol, n_slots=16,
-                                       fleet=True, pool=512)
+                                       pool=512)
     a = native(jnp.int32(0), jnp.int32(NATIVE.mpl))
     b = bucket(jnp.int32(0), jnp.int32(NATIVE.mpl), rt=rt)
     assert int(a.commits) > 0
@@ -67,8 +67,7 @@ def test_pad_axes_stay_inert():
     slots past the live length bound are -1, and pool entries past the
     live cpu/disk counts still read free_at >= INF."""
     rt = jaxsim.rt_of(NATIVE)
-    run = jaxsim.make_padded_engine(BUCKET, "ppcc", n_slots=16,
-                                    fleet=True, pool=512)
+    run = jaxsim.make_padded_engine(BUCKET, "ppcc", n_slots=16, pool=512)
     s = run(jnp.int32(1), jnp.int32(NATIVE.mpl), rt=rt)
     w_live = bitset.n_words(NATIVE.db_size)
     for bits in (s.pstate.read_set, s.pstate.write_set, s.dirty):

@@ -95,8 +95,7 @@ def test_engine_megakernel_path_bit_identical():
                   horizon=2_000.0, seed=3)
     states = []
     for mk in (False, True):
-        init, cond, step = jaxsim.engine_parts(
-            p, "ppcc", step_mode="cohort", fused=True, megakernel=mk)
+        init, cond, step = jaxsim.engine_parts(p, "ppcc", megakernel=mk)
         s = init(0)
         it = 0
         while bool(cond(s)) and it < 1500:

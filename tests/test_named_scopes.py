@@ -3,9 +3,9 @@
 ``op_name`` metadata of the PPCC loop, and nothing else — the program
 compiled without them is the same text once metadata is stripped.
 
-The tiny test fleet runs with ``delta=True`` so that the relations
-phase has work on the CPU too (``relations_inputs`` and the delta
-update; the megakernel that holds it on a TPU is not compiled here)."""
+On the CPU the relations phase holds the megakernel's jnp twin
+(``compute_relations`` and ``relations_inputs``); the megakernel that
+holds it on a TPU is not compiled here."""
 import contextlib
 import json
 import re
@@ -32,7 +32,7 @@ def compiled_text() -> str:
     cfg = json.loads(CONFIG.read_text())
     p = SimParams(**cfg["table1"], **cfg["figures"]["6"], mpl=20,
                   horizon=200.0)
-    fleet = sweep.Fleet(p, protocols=("ppcc",), delta=True)
+    fleet = sweep.Fleet(p, protocols=("ppcc",))
     rt = jax.tree.map(lambda x: jnp.broadcast_to(x, (2,)), jaxsim.rt_of(p))
     seeds = jnp.zeros(2, jnp.int32)
     return fleet._jit.lower(seeds, seeds + 20, rt).compile().as_text()

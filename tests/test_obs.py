@@ -34,7 +34,7 @@ PROTOCOLS = ("ppcc", "2pl", "occ")
 
 def _final_state(protocol, telemetry, trace_every=0, **kw):
     run = jaxsim.make_padded_engine(GRID, protocol, n_slots=24,
-                                    fleet=True, telemetry=telemetry,
+                                    telemetry=telemetry,
                                     trace_every=trace_every, **kw)
     import jax.numpy as jnp
     return run(jnp.int32(0), jnp.int32(GRID.mpl))
@@ -76,12 +76,6 @@ def test_telemetry_off_on_bit_identical_fleet():
     assert fleet.traces == 1
     fleet((6, 11, 17), (2, 3))                       # new runtime values
     assert fleet.traces == 1
-
-
-def test_telemetry_requires_cohort_mode():
-    with pytest.raises(ValueError, match="cohort"):
-        jaxsim.engine_parts(GRID, "ppcc", step_mode="event",
-                            telemetry=True)
 
 
 # --------------------------------------------------------------------------
@@ -172,7 +166,7 @@ def test_engine_vs_oracle_latency_parity(protocol):
 
 def _final_state_at(p, protocol):
     import jax.numpy as jnp
-    run = jaxsim.make_padded_engine(p, protocol, n_slots=24, fleet=True,
+    run = jaxsim.make_padded_engine(p, protocol, n_slots=24,
                                     telemetry=True, trace_every=8)
     return run(jnp.int32(0), jnp.int32(p.mpl))
 

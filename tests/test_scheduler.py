@@ -81,3 +81,32 @@ def test_txstore_serializable_outcome():
     for i in np.where(admitted)[0]:
         expect[np.asarray(w)[i]] += np.asarray(pay)[i][np.asarray(w)[i]]
     np.testing.assert_allclose(np.asarray(pages), expect, atol=1e-5)
+
+
+def test_scheduler_tick_carry_reuse_and_invalidation():
+    """``tick`` threads carried conflict state — reusing it on unchanged
+    inputs and recomputing (exactly) on changed ones."""
+    rng = np.random.default_rng(0)
+    n, d = 24, 64
+    r = jnp.asarray(rng.random((n, d)) < 0.1)
+    w = jnp.asarray(rng.random((n, d)) < 0.04) & r
+    v = jnp.asarray(rng.random(n) < 0.9)
+    for order in ("priority", "degree"):
+        base = scheduler.tick(r, w, v, policy="ppcc", order=order)
+        res1, c1 = scheduler.tick(r, w, v, policy="ppcc", order=order,
+                                  return_carry=True)
+        res2 = scheduler.tick(r, w, v, policy="ppcc", order=order,
+                              carry=c1)
+        for a, b, c in zip(jax.tree.leaves(base), jax.tree.leaves(res1),
+                           jax.tree.leaves(res2)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+        # changed words: the carry must be invalidated, not reused
+        r3 = r.at[0].set(~r[0])
+        fresh = scheduler.tick(r3, w, v, policy="ppcc", order=order)
+        res3 = scheduler.tick(r3, w, v, policy="ppcc", order=order,
+                              carry=c1)
+        for a, b in zip(jax.tree.leaves(fresh), jax.tree.leaves(res3)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    with pytest.raises(ValueError):
+        scheduler.tick(r, w, v, policy="2pl", return_carry=True)

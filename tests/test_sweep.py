@@ -10,9 +10,7 @@
 import subprocess
 import sys
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from repro.core import jaxsim, ppcc, sweep
@@ -60,8 +58,7 @@ def test_invariants_and_inertness_with_padded_lanes():
     fleet-body engine, and padded slots stay frozen throughout."""
     p = SimParams(db_size=50, txn_size_mean=8, write_prob=0.5, mpl=12,
                   horizon=1_500.0, seed=3)
-    init, cond, step = jaxsim.engine_parts(p, "ppcc", n_slots=32,
-                                           fleet=True)
+    init, cond, step = jaxsim.engine_parts(p, "ppcc", n_slots=32)
     s = init(0, 12)
     steps = 0
     while bool(cond(s)) and steps < 250:
@@ -77,20 +74,6 @@ def test_invariants_and_inertness_with_padded_lanes():
         assert bool((s.next_time[12:] > 1e29).all()), \
             f"padded slot scheduled an event at step {steps}"
     assert steps > 50 and int(s.commits) > 0
-
-
-def test_fleet_body_exact_vs_cond_gated_body():
-    """fleet=True only removes lax.cond perf gates whose branches are
-    exact under empty masks — results must be bit-identical."""
-    p = GRID.with_(horizon=2_000.0)
-    for proto in ("ppcc", "2pl", "occ"):
-        a = jaxsim.make_padded_engine(p, proto, n_slots=24)(
-            jnp.int32(1), jnp.int32(16))
-        b = jaxsim.make_padded_engine(p, proto, n_slots=24, fleet=True)(
-            jnp.int32(1), jnp.int32(16))
-        assert int(a.commits) == int(b.commits)
-        assert int(a.aborts) == int(b.aborts)
-        np.testing.assert_allclose(float(a.now), float(b.now))
 
 
 def test_fig7_grid_compiles_exactly_once():
@@ -116,8 +99,7 @@ def test_fleet_matches_padded_engine_lanes():
     p = GRID.with_(horizon=1_500.0)
     fleet = sweep.Fleet(p, protocols=("ppcc",), n_slots=32)
     out = fleet((8, 16), (0, 1))
-    run = jaxsim.make_padded_engine(p, "ppcc", n_slots=32, fleet=True,
-                                    pool=4096)
+    run = jaxsim.make_padded_engine(p, "ppcc", n_slots=32, pool=4096)
     for mi, mpl in enumerate((8, 16)):
         for si, seed in enumerate((0, 1)):
             s = run(jnp.int32(seed), jnp.int32(mpl))
@@ -154,15 +136,15 @@ print("SHARD_OK", np.asarray(a["ppcc"]["commits"]).tolist())
 
 _POD_SCRIPT = r"""
 import jax
-from repro.parallel import sharding
-ok = sharding.init_distributed(coordinator_address="localhost:12397",
+from repro.core import mesh as fleet_mesh
+ok = fleet_mesh.init_distributed(coordinator_address="localhost:12397",
                                num_processes=1, process_id=0)
 assert ok and jax.process_count() == 1
-assert not sharding.init_distributed()        # second call: no-op
-mesh = sharding.pod_mesh(n_data=4)
+assert not fleet_mesh.init_distributed()      # second call: no-op
+mesh = fleet_mesh.pod_mesh(n_data=4)
 assert mesh is not None, "pod mesh absent after init_distributed"
 assert dict(mesh.shape) == {"pod": 1, "data": 4, "model": 1}, mesh
-assert sharding.data_axes(mesh) == ("pod", "data")
+assert fleet_mesh.data_axes(mesh) == ("pod", "data")
 from repro.core import sweep
 from repro.core.types import paper_figure_params
 m2 = sweep.fleet_mesh(8, pods=True)

@@ -4,9 +4,8 @@ described, not attached (the TPU compiler ships with jaxlib).
 Interpret mode hides what Mosaic refuses — block shapes off the (8, 128)
 tiling, in-kernel gathers, dynamic slices of values, selects on bools —
 so each kernel is lowered here with ``interpret=False`` at the size the
-main path runs it: the cohort-step megakernel and the dirty-row slab
-kernel at the fleet's 160 slots x 16 words, the scheduler's conflict
-kernels at 256 transactions x 32 words and at a 1 024-row backlog (16
+main path runs it: the cohort-step megakernel at the fleet's 160 slots
+x 16 words, the scheduler's conflict kernels at 256 transactions x 32 words and at a 1 024-row backlog (16
 words, or 16-key lists), and PPCC's whole tick on those key lists.
 Nothing runs; a refusal raises.
 
@@ -28,7 +27,7 @@ from repro.kernels import megastep as MS
 from repro.kernels import ops as kops
 from repro.sched import scheduler
 
-N_SLOTS, SLOT_WORDS, LANES, SLAB = 160, 16, 4, 40
+N_SLOTS, SLOT_WORDS, LANES = 160, 16, 4
 N_TXN, TXN_WORDS = 256, 32
 BACKLOG, KEYS = 1024, 16
 
@@ -99,15 +98,6 @@ def test_megastep_compiles_vmapped_over_lanes(one_chip):
     fn = jax.vmap(lambda *a: MS.megastep(*a, interpret=False))
     hlo = _compile(fn, one_chip, *([((lanes, n, w), U32)] * 3),
                    ((lanes, n), I32), *([((lanes, n), BOOL)] * 4))
-    assert "tpu_custom_call" in hlo
-
-
-def test_rowslab_compiles(one_chip):
-    n, w, k = N_SLOTS, SLOT_WORDS, SLAB
-    fn = lambda *a: MS.rowslab(*a, interpret=False)  # noqa: E731
-    hlo = _compile(fn, one_chip, ((n, w), U32), ((n, w), U32),
-                   ((n, n), BOOL), ((n, n), BOOL), ((n,), I32),
-                   ((n,), BOOL), ((n,), BOOL), ((k,), I32), ((k,), BOOL))
     assert "tpu_custom_call" in hlo
 
 
