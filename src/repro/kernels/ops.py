@@ -13,6 +13,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from . import admit as _admit
 from . import conflict as _conflict
 from . import flash_attention as _flash
 from . import megastep as _megastep
@@ -65,6 +66,16 @@ def conflict_keys(read_keys, write_keys, *, block: int = 256):
     return _conflict.conflict_keys(
         read_keys, write_keys, block=block,
         interpret=_interpret_default())
+
+
+@functools.partial(jax.jit, static_argnames=("rule",))
+def ppcc_admit_scan(raw, valid, seq, *, rule):
+    """PPCC's admission scan in one launch -> (admitted, preceding,
+    preceded); see kernels.admit.  ``raw`` bool[n, n] (diagonal off),
+    ``valid`` bool[n], ``seq`` the visiting order, ``rule`` the
+    per-row admission test."""
+    return _admit.admit_scan(raw, valid, seq, rule=rule,
+                             interpret=_interpret_default())
 
 
 @jax.jit

@@ -1,7 +1,7 @@
 """Pure-jnp oracles for every Pallas kernel (the allclose targets)."""
 from __future__ import annotations
 
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +78,31 @@ def conflict_keys_ref(read_keys: jax.Array, write_keys: jax.Array):
 
     return _full(overlap(read_keys, write_keys),
                  overlap(write_keys, write_keys))
+
+
+def ppcc_admit_scan_ref(raw: jax.Array, valid: jax.Array, seq: jax.Array,
+                        *, rule: Callable):
+    """Oracle for ``ppcc_admit_scan``: the Prudent Precedence Rule as a
+    ``lax.scan``, one step per row in the order ``seq``, carrying only
+    the three ``bool[n]`` class vectors.  ``raw`` is ``bool[n, n]`` with
+    the diagonal off; ``rule(r_i, w_i, preceding, preceded)`` tests row
+    i's admitted RAW arcs out and WAR arcs in (it may keep its reduced
+    axes).  Returns ``(admitted, preceding, preceded)``."""
+    n = raw.shape[0]
+
+    def step(carry, i):
+        admitted, preceding, preceded = carry
+        r_i = raw[i] & admitted                      # i -> j arcs (RAW)
+        w_i = raw[:, i] & admitted                   # k -> i arcs (WAR)
+        ok = (valid[i] & rule(r_i, w_i, preceding, preceded)).reshape(())
+        admitted = admitted.at[i].set(ok)
+        preceding = preceding.at[i].set(ok & r_i.any()) | (w_i & ok)
+        preceded = preceded.at[i].set(ok & w_i.any()) | (r_i & ok)
+        return (admitted, preceding, preceded), ok
+
+    init = (jnp.zeros(n, bool), jnp.zeros(n, bool), jnp.zeros(n, bool))
+    out, _ = jax.lax.scan(step, init, seq)
+    return out
 
 
 def megastep_ref(read_bits: jax.Array, write_bits: jax.Array,

@@ -8,8 +8,10 @@ scheduler takes the pending transactions' bitsets and decides, under a
 chosen policy, which may proceed this tick and in which commit order:
 
 * ``ppcc``  — the Prudent Precedence Rule applied in priority order
-  (exact, via ``ppcc.admit_ops``'s lax.scan); conflicting-but-admissible
-  transactions proceed WITH a precedence that the commit pass respects.
+  (exact, one step per transaction in the ``ppcc_admit_scan`` Pallas
+  kernel, or in its oracle, ``ref.ppcc_admit_scan_ref``'s lax.scan);
+  conflicting-but-admissible transactions proceed WITH a precedence
+  that the commit pass respects.
 * ``2pl``   — conservative: a transaction is admitted only if it
   conflicts with no earlier-admitted transaction (blocking semantics).
 * ``occ``   — admit everything, validate afterwards: a transaction
@@ -48,7 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import bitset, ppcc
-from ..kernels import ops as kops
+from ..kernels import admit, ops as kops
 
 
 def _as_bits(sets: jax.Array, words: int = None) -> jax.Array:
@@ -137,9 +139,11 @@ def _prudent(r_i: jax.Array, w_i: jax.Array, preceding: jax.Array,
     admitted set, given its RAW arcs out (``r_i``) and WAR arcs in
     (``w_i``): it may not become both preceding and preceded, may not
     precede a preceding transaction, and may not follow a preceded
-    one (the rule's two class tests)."""
-    return (~(r_i.any() & w_i.any()) & ~(r_i & preceding).any()
-            & ~(w_i & preceded).any())
+    one (the rule's two class tests).  The verdict keeps the operands'
+    rank, so in the admission kernel it stays a vector."""
+    any_ = admit.any_kept
+    return (~(any_(r_i) & any_(w_i)) & ~any_(r_i & preceding)
+            & ~any_(w_i & preceded))
 
 
 def ppcc_tick(read_sets: jax.Array, write_sets: jax.Array,
@@ -181,10 +185,13 @@ def ppcc_tick(read_sets: jax.Array, write_sets: jax.Array,
     pairwise launch and the admission order), ``tick.scan`` (the
     rule, one step per transaction) and ``tick.commit_order``.
 
-    The scan carries only the three ``bool[n]`` vectors.  ``state.prec``
-    is formed once after it, as ``raw`` (diagonal off) restricted to
-    admitted pairs.  That is exact: each row is visited once, and an
-    arc is stored only between admitted rows, at the later one's step.
+    The scan is one ``ppcc_admit_scan`` launch (``kernels.admit``) when
+    ``use_kernel`` and its tables fit the kernel's VMEM budget
+    (n <= 2 048), else its oracle's ``lax.scan``; either carries only
+    the three ``bool[n]`` vectors.  ``state.prec`` is formed once after
+    it, as ``raw`` (diagonal off) restricted to admitted pairs.  That is
+    exact: each row is visited once, and an arc is stored only between
+    admitted rows, at the later one's step.
     """
     n = read_sets.shape[0]
     with jax.named_scope("tick.conflict"):
@@ -223,19 +230,11 @@ def ppcc_tick(read_sets: jax.Array, write_sets: jax.Array,
             seq = jnp.arange(n, dtype=jnp.int32)
         raw = raw & ~jnp.eye(n, dtype=bool)      # self-RAW is not a conflict
 
-    def step(carry, i):
-        admitted, preceding, preceded = carry
-        r_i = raw[i] & admitted                      # i -> j arcs (RAW)
-        w_i = raw[:, i] & admitted                   # k -> i arcs (WAR)
-        ok = valid[i] & _prudent(r_i, w_i, preceding, preceded)
-        admitted = admitted.at[i].set(ok)
-        preceding = preceding.at[i].set(ok & r_i.any()) | (w_i & ok)
-        preceded = preceded.at[i].set(ok & w_i.any()) | (r_i & ok)
-        return (admitted, preceding, preceded), ok
-
     with jax.named_scope("tick.scan"):
-        init = (jnp.zeros(n, bool), jnp.zeros(n, bool), jnp.zeros(n, bool))
-        (admitted, preceding, preceded), _ = jax.lax.scan(step, init, seq)
+        scan = (kops.ppcc_admit_scan
+                if use_kernel and admit.fits(n)
+                else kops.ref.ppcc_admit_scan_ref)
+        admitted, preceding, preceded = scan(raw, valid, seq, rule=_prudent)
         # exact: ``seq`` visits each row once, and a step stores an arc
         # only between its row, if admitted, and rows admitted before it
         prec = raw & admitted[:, None] & admitted[None, :]

@@ -131,8 +131,9 @@ def tick_text() -> str:
 
 def test_tick_scopes_change_only_metadata():
     """``ppcc_tick``'s three parts run under ``tick.conflict``,
-    ``tick.scan`` (the admission scan's loop) and ``tick.commit_order``;
-    without the scopes the program is the same."""
+    ``tick.scan`` (the admission scan's kernel, interpreted on the CPU)
+    and ``tick.commit_order``; without the scopes the program is the
+    same."""
     with_scopes = tick_text()
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
@@ -141,5 +142,6 @@ def test_tick_scopes_change_only_metadata():
     names = set(OP_NAME.findall(with_scopes))
     seen = {s for s in TICK_SCOPES if any(f"/{s}/" in n for n in names)}
     assert seen == TICK_SCOPES
-    assert any(n.endswith("tick.scan/while") for n in names)
+    assert any("tick.scan/jit(ppcc_admit_scan)/ppcc_admit_scan/" in n
+               for n in names)
     assert not any(s in without for s in TICK_SCOPES)

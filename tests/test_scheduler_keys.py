@@ -2,13 +2,16 @@
 jnp oracle, and ``tick(..., keys=True)`` against the same sets held as
 dense masks, for every policy, both orders, the carry and
 ``tick_stats``; PPCC's tick against its form that carried ``prec``
-through the scan.  Interpret mode on the CPU, at small sizes."""
+through the scan; the admission kernel against its ``lax.scan``
+oracle.  Interpret mode on the CPU, at small sizes."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import admit, ops, ref
 from repro.sched import scheduler
 
 
@@ -155,7 +158,8 @@ def carry_form_ppcc(rs, ws, valid, order, keys):
         admitted, preceding, preceded, prec = carry
         r_i = raw[i] & admitted
         w_i = raw[:, i] & admitted
-        ok = valid[i] & scheduler._prudent(r_i, w_i, preceding, preceded)
+        ok = (valid[i] & scheduler._prudent(r_i, w_i, preceding,
+                                             preceded)).reshape(())
         admitted = admitted.at[i].set(ok)
         preceding = preceding.at[i].set(ok & r_i.any()) | (w_i & ok)
         preceded = preceded.at[i].set(ok & w_i.any()) | (r_i & ok)
@@ -213,3 +217,89 @@ def test_prec_after_scan_equals_carried_prec(order, form, invalid, carry):
         same((res.admitted, res.commit_rank, s.prec, s.preceding,
               s.preceded, s.active), want + (want[0],))
     assert bool(want[2].any()) and 0 < int(want[0].sum()) < int(valid.sum())
+
+
+def scan_pair(n, permuted, invalid):
+    """The admission kernel and its oracle on one random RAW relation
+    (about three arcs a row, diagonal off), rows visited in index order
+    or permuted, all rows valid or about a fifth not."""
+    rng = np.random.default_rng([n, permuted, invalid])
+    raw = rng.random((n, n)) < 3.0 / n
+    np.fill_diagonal(raw, False)
+    valid = rng.random(n) < (0.8 if invalid else 1.0)
+    seq = rng.permutation(n) if permuted else np.arange(n)
+    args = (jnp.asarray(raw), jnp.asarray(valid),
+            jnp.asarray(seq, jnp.int32))
+    got = ops.ppcc_admit_scan(*args, rule=scheduler._prudent)
+    want = ref.ppcc_admit_scan_ref(*args, rule=scheduler._prudent)
+    admitted, preceding, preceded = (np.asarray(x) for x in want)
+    assert 0 < admitted.sum() < valid.sum()
+    assert preceding.any() and preceded.any() and not admitted[~valid].any()
+    return got, want
+
+
+def tick_pair(order, form, invalid, carry):
+    """``ppcc_tick`` with its kernels and with their oracles
+    (``use_kernel`` on and off) on a ``PREC_CASES`` case: the plain
+    tick, and with ``carry`` the tick that returns its carry and the
+    tick that reuses it."""
+    n, keys = 64, form == "keys"
+    rk, wk = key_batch(11 + invalid + 2 * carry, n)
+    rs, ws = ((jnp.asarray(rk), jnp.asarray(wk)) if keys else
+              (jnp.asarray(dense(rk)), jnp.asarray(dense(wk))))
+    valid = jnp.asarray(np.random.default_rng(n).random(n) < 0.8
+                        if invalid else np.ones(n, bool))
+
+    def ticks(use_kernel):
+        def tick(**kw):
+            return scheduler.ppcc_tick(rs, ws, valid, use_kernel=use_kernel,
+                                       order=order, keys=keys, **kw)
+        out = [tick()]
+        if carry:
+            first, c = tick(return_carry=True)
+            out += [first, tick(carry=c)]
+        return out
+
+    return ticks(True), ticks(False)
+
+
+ADMIT_CASES = [
+    pytest.param(functools.partial(scan_pair, n, permuted, invalid),
+                 id=f"scan-{n}-{'permuted' if permuted else 'index'}"
+                    f"{'-invalid' if invalid else ''}")
+    for n in (64, 200, 1024) for permuted in (False, True)
+    for invalid in (False, True)
+] + [
+    pytest.param(functools.partial(tick_pair, *case),
+                 id="tick-" + "-".join(map(str, case)))
+    for case in PREC_CASES
+]
+
+
+@pytest.mark.parametrize("case", ADMIT_CASES)
+def test_admission_kernel_equals_oracle(case):
+    """``ppcc_admit_scan`` (interpret mode) against its ``lax.scan``
+    oracle bit for bit: the kernel alone at n = 64, 200 (off the
+    ``(8, 128)`` tiling) and 1 024, and inside ``ppcc_tick`` for both
+    set forms, both orders, invalid rows and the carry."""
+    got, want = case()
+    same(got, want)
+
+
+def test_admission_kernel_above_budget_keeps_the_scan(monkeypatch):
+    """The one rule that picks the scan: a backlog whose tables exceed
+    the kernel's VMEM budget runs the oracle, with the same result."""
+    assert admit.fits(1024) and admit.fits(2048) and not admit.fits(2049)
+    rk, wk = (jnp.asarray(a) for a in key_batch(5, 64))
+    valid = jnp.ones(64, bool)
+
+    def traced():
+        fn = functools.partial(scheduler.ppcc_tick, keys=True)
+        return str(jax.make_jaxpr(fn)(rk, wk, valid)), fn(rk, wk, valid)
+
+    jaxpr, within = traced()
+    assert "ppcc_admit_scan" in jaxpr
+    monkeypatch.setattr(admit, "TABLE_BUDGET", 0)
+    jaxpr, above = traced()
+    assert "ppcc_admit_scan" not in jaxpr and "conflict_keys" in jaxpr
+    same(within, above)

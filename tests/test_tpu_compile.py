@@ -5,9 +5,10 @@ Interpret mode hides what Mosaic refuses — block shapes off the (8, 128)
 tiling, in-kernel gathers, dynamic slices of values, selects on bools —
 so each kernel is lowered here with ``interpret=False`` at the size the
 main path runs it: the cohort-step megakernel at the fleet's 160 slots
-x 16 words, the scheduler's conflict kernels at 256 transactions x 32 words and at a 1 024-row backlog (16
-words, or 16-key lists), and PPCC's whole tick on those key lists.
-Nothing runs; a refusal raises.
+x 16 words, the scheduler's conflict kernels at 256 transactions x 32
+words and at a 1 024-row backlog (16 words, or 16-key lists), and
+PPCC's whole tick on those key lists, with the admission-scan kernel
+``ppcc_admit_scan``.  Nothing runs; a refusal raises.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and every test
@@ -148,19 +149,29 @@ def test_reserve_scan_body_has_no_gather_or_scatter(one_chip):
 def test_tick_scan_body_has_no_square_copy_or_update(one_chip,
                                                      monkeypatch):
     """PPCC's admission scan (``ppcc_tick`` on 16-key lists over a
-    1 024-row backlog, the keyed kernel compiled as on the chip) carries
-    only ``bool[n]`` vectors: an n x n carry written by row and by
-    column is relaid out and updated whole, every step."""
+    1 024-row backlog, the kernels compiled as on the chip) is one
+    ``ppcc_admit_scan`` launch: under ``tick.scan`` the kernel's custom
+    call is there, no XLA ``while`` is left (a loop of about ten ops a
+    step), and no n x n ``pred`` is copied or updated in place (an
+    n x n carry written by row and by column is relaid out and updated
+    whole, every step)."""
     monkeypatch.setattr(kops, "_interpret_default", lambda: False)
     n = BACKLOG
     fn = lambda r, w, v: scheduler.ppcc_tick(r, w, v, keys=True)  # noqa: E731
-    hlo = _compile(fn, one_chip, ((n, KEYS), I32), ((n, KEYS), I32),
-                   ((n,), BOOL))
-    comps = _computations(hlo)
-    bodies = re.findall(r" while\(.*?body=%([\w.\-]+).*tick\.scan", hlo)
-    assert bodies, "no while loop under tick.scan"
+    # the jitted kernel wrappers keep their traces by shape: drop those
+    # an interpret-mode test made, and those made here for the chip
+    jax.clear_caches()
+    try:
+        hlo = _compile(fn, one_chip, ((n, KEYS), I32), ((n, KEYS), I32),
+                       ((n,), BOOL))
+    finally:
+        jax.clear_caches()
+    scan = [ln for ln in hlo.splitlines() if "tick.scan" in ln]
+    calls = [ln for ln in scan if "tpu_custom_call" in ln]
+    assert calls, "no kernel under tick.scan"
+    assert all(ln.strip().startswith("%ppcc_admit_scan") for ln in calls)
+    assert not [ln for ln in scan if " while(" in ln]
     square = f"pred[{n},{n}]"
-    for body in bodies:
-        bad = [ln for ln in _called(comps, body) if square in ln
-               and (" copy(" in ln or " dynamic-update-slice(" in ln)]
-        assert not bad, (body, bad)
+    bad = [ln for ln in scan if square in ln
+           and (" copy(" in ln or " dynamic-update-slice(" in ln)]
+    assert not bad, bad
