@@ -14,6 +14,7 @@ The topology is described inside a module fixture, never at import:
 only one process may load the TPU library at a time, and every test
 worker imports this file.
 """
+import functools
 import os
 import re
 
@@ -26,7 +27,7 @@ from repro.core import jaxsim
 from repro.kernels import conflict as KC
 from repro.kernels import megastep as MS
 from repro.kernels import ops as kops
-from repro.sched import scheduler
+from repro.sched import scheduler, tpcc, txstore
 
 N_SLOTS, SLOT_WORDS, LANES = 160, 16, 4
 N_TXN, TXN_WORDS = 256, 32
@@ -175,3 +176,51 @@ def test_tick_scan_body_has_no_square_copy_or_update(one_chip,
     bad = [ln for ln in scan if square in ln
            and (" copy(" in ln or " dynamic-update-slice(" in ln)]
     assert not bad, bad
+
+
+def test_keyed_pass_compiles_on_ragged_lists(one_chip):
+    """TPC-C's key lists: 33 read keys, 16 write keys, 1 024 rows."""
+    fn = lambda r, w: KC.conflict_keys(r, w, interpret=False)  # noqa: E731
+    hlo = _compile(fn, one_chip, ((BACKLOG, tpcc.READ_KEYS), I32),
+                   ((BACKLOG, tpcc.WRITE_KEYS), I32))
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert calls and all(ln.strip().startswith("%conflict_keys")
+                         for ln in calls)
+
+
+def test_tpcc_apply_relays_out_no_wide_column(one_chip):
+    """The keyed store's TPC-C apply at 4 warehouses, the store donated
+    as the benchmark's driver donates it: no copy or transpose of a
+    table's or log's byte column of 128 bytes or more (a gathered byte
+    column laid out transposed is relaid out whole, every tick), and
+    little scratch beside the store."""
+    scale = tpcc.Scale(4)
+    small, _ = tpcc.populate(tpcc.Scale(1, 10, 30, 100), 1)
+    rows = scale.rows()
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    tables = {t: {c: spec((rows[t],) + v.shape[1:], v.dtype)
+                  for c, v in cols.items()} for t, cols in small.items()}
+    cap = tpcc.log_capacity(2**20, 0.5)
+    logs = {t: {c: spec((cap[t],) + tuple(shape), dtype)
+                for c, (shape, dtype) in cols.items()}
+            for t, cols in tpcc.LOG_COLUMNS.items()}
+    store = txstore.KeyedStore(tables, logs, {t: spec((), I32)
+                                              for t in tpcc.LOG_COLUMNS})
+    txn = {f: spec((BACKLOG, tpcc.MAX_LINES) if f in ("i_id", "supply_w",
+                                                     "qty")
+                   else (BACKLOG,), I32) for f in tpcc.TXN_FIELDS}
+    fn = jax.jit(functools.partial(tpcc.apply_tick, scale=scale),
+                 donate_argnums=0)
+    compiled = fn.lower(store, txn, spec((BACKLOG,), BOOL),
+                        spec((BACKLOG,), I32), spec((), I32)).compile()
+    wide = [f"u8[{rows[t]},{v.shape[1]}" for t, cols in small.items()
+            for v in cols.values() if v.dtype == jnp.uint8
+            and v.ndim == 2 and v.shape[1] >= 128]
+    assert wide
+    bad = [ln for ln in compiled.as_text().splitlines()
+           if any(w in ln for w in wide)
+           and re.search(r" (copy|copy-start|transpose)\(", ln)]
+    assert not bad, bad[:3]
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**28
